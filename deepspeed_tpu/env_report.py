@@ -24,15 +24,17 @@ def op_report():
         builder = builder_cls()
         status = OKAY if builder.is_compatible() else NO
         lines.append(f"{name} {'.' * (48 - len(name))} {status}")
-    # kernel paths
-    try:
-        import jax
+    # kernel paths: compiled on a TPU backend, interpreted on the CPU
+    # backend; a backend that failed to start is reported as that
+    import jax
 
-        on_tpu = any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        on_tpu = False
-    pallas = OKAY if on_tpu else \
-        f"{YELLOW}[interpret-mode (no TPU visible)]{END}"
+    try:
+        backend = jax.default_backend()
+    except RuntimeError as e:
+        pallas = f"{NO} backend failed to start: {e}"
+    else:
+        pallas = OKAY if backend == "tpu" else \
+            f"{YELLOW}[interpret-mode (backend is {backend})]{END}"
     lines.append(f"pallas_flash_attention {'.' * 26} {pallas}")
     lines.append("-" * 74)
     return "\n".join(lines)
@@ -64,13 +66,14 @@ def version_report():
         f"{deepspeed_tpu.__reference_version__}")
     try:
         devices = jax.devices()
+    except RuntimeError as e:
+        lines.append(f"devices ....................... unavailable ({e})")
+    else:
         plats = {}
         for d in devices:
             plats[d.platform] = plats.get(d.platform, 0) + 1
         desc = ", ".join(f"{n}x {p}" for p, n in plats.items())
         lines.append(f"devices ....................... {desc}")
-    except Exception as e:  # pragma: no cover
-        lines.append(f"devices ....................... unavailable ({e})")
     return "\n".join(lines)
 
 

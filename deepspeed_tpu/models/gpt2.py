@@ -154,7 +154,7 @@ class CausalSelfAttention(nn.Module):
             # all_to_all) so the attention kernel sees the whole sequence.
             # Every dim names its axes — a partial spec would pin the
             # batch's 'data' and the heads' 'model' sharding to replicated.
-            head_sp = P("data", ("model", "seq"), None, None)
+            head_sp = mesh_lib.HEAD_SHARDED
             q = mesh_lib.constrain(q, head_sp)
             k = mesh_lib.constrain(k, head_sp)
             v = mesh_lib.constrain(v, head_sp)

@@ -2,22 +2,21 @@
 
 Reference behavior: op_builder/builder.py:78-286 (JIT ninja compile via
 torch cpp_extension, AVX capability autodetect, compatibility checks).
-Here: direct g++ -shared compile of C sources into a cached .so loaded with
-ctypes (no pybind11/torch in the loop), with the same per-op builder-class
-shape so `ds_report` can enumerate ops and their compatibility.
+Here: direct g++ -shared compile of C sources into a .so under the
+checkout's `.op_build/`, named by a hash of the sources and flags and loaded
+with ctypes (no pybind11/torch in the loop), with the same per-op
+builder-class shape so `ds_report` can enumerate ops and their compatibility.
 """
 import ctypes
+import hashlib
 import os
 import subprocess
-import tempfile
 
 from deepspeed_tpu.utils.logging import logger
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-_CACHE_DIR = os.environ.get(
-    "DSTPU_OPS_CACHE",
-    os.path.join(os.path.expanduser("~"), ".cache", "deepspeed_tpu", "ops"))
+_BUILD_DIR = os.path.join(_REPO_ROOT, ".op_build")
 
 
 class OpBuilder:
@@ -38,41 +37,57 @@ class OpBuilder:
         except (OSError, subprocess.CalledProcessError):
             return False
 
-    def cpu_arch_flags(self):
-        """March autodetect (reference op_builder/cpu_adam.py:24-40)."""
-        flags = ["-march=native"]
+    @staticmethod
+    def _cpu_features():
+        """The first `flags` line of /proc/cpuinfo ("" where unreadable)."""
         try:
             with open("/proc/cpuinfo") as f:
-                info = f.read()
-            if "avx512f" not in info and "avx2" not in info:
-                flags = []
+                for line in f:
+                    if line.startswith("flags"):
+                        return line
         except OSError:
             pass
-        return flags
+        return ""
+
+    def cpu_arch_flags(self):
+        """March autodetect (reference op_builder/cpu_adam.py:24-40)."""
+        feats = self._cpu_features().split()
+        if "avx512f" in feats or "avx2" in feats:
+            return ["-march=native"]
+        return []
+
+    def compile_flags(self):
+        return (["-O3", "-shared", "-fPIC", "-fopenmp"]
+                + self.cpu_arch_flags() + self.EXTRA_FLAGS)
 
     def so_path(self):
-        return os.path.join(_CACHE_DIR, f"{self.NAME}.so")
+        """The library is named by the content of its sources, its flags
+        and (as -march=native means this CPU) the CPU's features, so one
+        built from other sources or for another CPU is never loaded."""
+        h = hashlib.sha256(
+            (" ".join(self.compile_flags()) + self._cpu_features()).encode())
+        for s in self.absolute_sources():
+            with open(s, "rb") as f:
+                h.update(f.read())
+        return os.path.join(_BUILD_DIR,
+                            f"{self.NAME}-{h.hexdigest()[:16]}.so")
 
     def jit_load(self):
-        """Compile (if stale) and dlopen. Returns a ctypes.CDLL or None on
-        failure (callers fall back to the numpy path)."""
-        sources = self.absolute_sources()
-        so = self.so_path()
+        """Compile (if not built yet) and dlopen. Returns a ctypes.CDLL or
+        None on failure (callers fall back to the numpy path)."""
         if not self.is_compatible():
             logger.warning(f"op '{self.NAME}': no compatible toolchain; "
                            f"using fallback implementation")
             return None
-        stale = not os.path.exists(so) or any(
-            os.path.getmtime(s) > os.path.getmtime(so) for s in sources)
-        if stale:
-            os.makedirs(_CACHE_DIR, exist_ok=True)
-            # unique temp per process: concurrent builders (multi-host NFS
-            # home, parallel pytest) must not interleave writes; os.replace
-            # promotes atomically, last writer wins
+        sources = self.absolute_sources()
+        so = self.so_path()
+        if not os.path.exists(so):
+            os.makedirs(_BUILD_DIR, exist_ok=True)
+            # unique temp per process: concurrent builders (parallel
+            # pytest, hosts sharing a checkout) must not interleave
+            # writes; os.replace promotes atomically, last writer wins
             tmp = f"{so}.tmp.{os.getpid()}"
-            cmd = (["g++", "-O3", "-shared", "-fPIC", "-fopenmp"]
-                   + self.cpu_arch_flags() + self.EXTRA_FLAGS
-                   + sources + ["-o", tmp])
+            cmd = ["g++"] + self.compile_flags() + sources + ["-o", tmp]
             try:
                 subprocess.run(cmd, capture_output=True, check=True, text=True)
                 os.replace(tmp, so)

@@ -30,13 +30,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# scalar memory per TensorCore on TPU v4 and later (jax's own
+# pallas/mosaic/tpu_info.py table); the scalar-prefetched LUT lives there
+SMEM_BYTES = 1 << 20
 
 
 def _interpret_default() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    return jax.default_backend() == "cpu"
+
+
+def _check_lut_fits_smem(which, block, *luts):
+    """Fail at trace time when a kernel's scalar-prefetched LUT cannot fit
+    SMEM — the compiler's own RESOURCE_EXHAUSTED names neither the layout
+    nor the block size."""
+    need = sum(4 * int(np.asarray(a).size) for a in luts)
+    if need > SMEM_BYTES:
+        raise ValueError(
+            f"block-sparse attention ({which}): the layout's lookup table "
+            f"needs {need} bytes of scalar memory (SMEM) at block size "
+            f"{block}, the chip has {SMEM_BYTES}; use a larger block (the "
+            f"table shrinks with the square of the block size)")
+
+
+def _key_bias_blocks(key_bias, block):
+    """(B, S) per-key bias -> (B * S/block, 1, block), so a (1, 1, block)
+    BlockSpec spans whole trailing dims — the TPU lowering refuses a
+    (1, block) block of a 2-D (B, S) array at any block size."""
+    B, S = key_bias.shape
+    return key_bias.reshape(B * (S // block), 1, block)
 
 
 def build_luts(layout):
@@ -95,7 +116,7 @@ def _fwd_kernel(cols_ref, nnz_ref, *refs, scale, heads, max_nnz, nq,
                                 preferred_element_type=jnp.float32) * scale
         if kb_ref is not None:
             # per-key additive bias (key padding): (1, block) row broadcast
-            s = s + kb_ref[...]
+            s = s + kb_ref[0]
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -124,6 +145,7 @@ def _sparse_fwd(q, k, v, cols, nnz, *, scale, block, heads, interpret,
     bh, S, d = q.shape
     nq = S // block
     max_nnz = cols.shape[-1]
+    _check_lut_fits_smem("forward", block, cols, nnz)
     cols_flat = jnp.asarray(np.asarray(cols).reshape(-1), jnp.int32)
     nnz_flat = jnp.asarray(np.asarray(nnz).reshape(-1), jnp.int32)
 
@@ -135,11 +157,12 @@ def _sparse_fwd(q, k, v, cols, nnz, *, scale, block, heads, interpret,
     def kb_index(b, qi, ai, cols_ref, nnz_ref):
         h = jax.lax.rem(b, heads)
         kb = cols_ref[(h * nq + qi) * max_nnz + ai]
-        return (b // heads, kb)
+        return ((b // heads) * nq + kb, 0, 0)
 
-    bias_ops = [] if key_bias is None else [key_bias]
+    bias_ops = [] if key_bias is None else \
+        [_key_bias_blocks(key_bias, block)]
     bias_specs = [] if key_bias is None else \
-        [pl.BlockSpec((1, block), kb_index)]
+        [pl.BlockSpec((1, 1, block), kb_index)]
     kernel = functools.partial(_fwd_kernel, scale=scale, heads=heads,
                                max_nnz=max_nnz, nq=nq,
                                has_bias=key_bias is not None)
@@ -207,7 +230,7 @@ def _bwd_dq_kernel(cols_ref, nnz_ref, *refs, scale, heads, max_nnz, nq,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if kb_ref is not None:
-            s = s + kb_ref[...]
+            s = s + kb_ref[0]
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -255,7 +278,7 @@ def _bwd_dkdv_kernel(rows_ref, nnzt_ref, *refs, scale, heads, max_nnz_t, nk,
         if kb_ref is not None:
             # this kernel's s is (q_rows, k_rows) with k fixed to block ki:
             # the bias row for block ki broadcasts over q rows
-            s = s + kb_ref[...]
+            s = s + kb_ref[0]
         p = jnp.exp(s - lse)
         dv_scr[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -279,6 +302,8 @@ def _sparse_bwd(res, do, *, scale, block, heads, interpret):
     nq = S // block
     max_nnz = cols.shape[-1]
     max_nnz_t = rows_t.shape[-1]
+    _check_lut_fits_smem("backward dq", block, cols, nnz)
+    _check_lut_fits_smem("backward dk/dv", block, rows_t, nnz_t)
     cols_flat = jnp.asarray(np.asarray(cols).reshape(-1), jnp.int32)
     nnz_flat = jnp.asarray(np.asarray(nnz).reshape(-1), jnp.int32)
     rows_flat = jnp.asarray(np.asarray(rows_t).reshape(-1), jnp.int32)
@@ -299,11 +324,13 @@ def _sparse_bwd(res, do, *, scale, block, heads, interpret):
 
     def kb_from_cols(b, qi, ai, cols_ref, nnz_ref):
         h = jax.lax.rem(b, heads)
-        return (b // heads, cols_ref[(h * nq + qi) * max_nnz + ai])
+        kb = cols_ref[(h * nq + qi) * max_nnz + ai]
+        return ((b // heads) * nq + kb, 0, 0)
 
-    bias_ops = [] if key_bias is None else [key_bias]
+    bias_ops = [] if key_bias is None else \
+        [_key_bias_blocks(key_bias, block)]
     dq_bias_specs = [] if key_bias is None else \
-        [pl.BlockSpec((1, block), kb_from_cols)]
+        [pl.BlockSpec((1, 1, block), kb_from_cols)]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, heads=heads,
                           max_nnz=max_nnz, nq=nq,
@@ -335,7 +362,8 @@ def _sparse_bwd(res, do, *, scale, block, heads, interpret):
         return (b, ki, 0)
 
     dkdv_bias_specs = [] if key_bias is None else \
-        [pl.BlockSpec((1, block), lambda b, ki, ai, *r: (b // heads, ki))]
+        [pl.BlockSpec((1, 1, block),
+                      lambda b, ki, ai, *r: ((b // heads) * nq + ki, 0, 0))]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, scale=scale, heads=heads,
                           max_nnz_t=max_nnz_t, nk=nq,

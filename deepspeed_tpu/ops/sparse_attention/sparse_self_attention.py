@@ -77,8 +77,23 @@ def block_sparse_attention(q, k, v, layout, block: int,
                 raise ValueError(
                     f"unknown key_padding_mask_mode "
                     f"{key_padding_mask_mode!r}")
-        return pallas_block_sparse_attention(q, k, v, layout, block,
-                                             scale=scale, key_bias=key_bias)
+        from jax.sharding import PartitionSpec as P
+
+        from deepspeed_tpu.parallel.mesh import per_shard
+
+        # a Mosaic kernel must run per shard: batch over 'data' only — the
+        # kernel indexes its per-head LUT by local head, so heads stay whole
+        batch_sp = P("data", None, None, None)
+        operands, specs = [q, k, v], [batch_sp] * 3
+        if key_bias is not None:
+            operands.append(key_bias)
+            specs.append(P("data", None))
+
+        def kernel(q, k, v, key_bias=None):
+            return pallas_block_sparse_attention(
+                q, k, v, layout, block, scale=scale, key_bias=key_bias)
+
+        return per_shard(kernel, specs, batch_sp)(*operands)
 
     B, H, S, D = q.shape
     nb = S // block

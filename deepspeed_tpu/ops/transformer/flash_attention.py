@@ -39,16 +39,22 @@ _BWD_BLOCK_K = int(_os.environ.get("DSTPU_FLASH_BWD_BLOCK_K", "0"))
 # lse/delta wire format: by default they travel 128-lane broadcast
 # ((bh, s_q, 128), 127/128 of the bytes redundant — ~0.4 GB/tensor/layer at
 # the gpt2-350m bench shapes). DSTPU_FLASH_LSE2D=1 switches to compact
-# (bh, s_q) tiles with an in-kernel (1, bq) -> (bq, 1) relayout; flagged
-# (not default) until the on-chip sweep proves the Mosaic relayout cheap.
-_LSE_2D = _os.environ.get("DSTPU_FLASH_LSE2D", "0") == "1"
+# (bh, 1, s_q) rows with an in-kernel (1, bq) -> (bq, 1) relayout; read
+# when a call is traced, and not the default until a chip measurement
+# says the Mosaic relayout is cheap (chip_smoke.py checks its results).
+
+
+def _lse_2d():
+    return _os.environ.get("DSTPU_FLASH_LSE2D", "0") == "1"
+
+
 NEG_INF = -1e30
 
 
 def _col(ref):
-    """Per-row statistic from its wire block: (1, bq) compact row ->
+    """Per-row statistic from its wire block: (1, 1, bq) compact row ->
     (bq, 1) column, or the legacy 128-lane block's first lane."""
-    if _LSE_2D:
+    if _lse_2d():
         return ref[...].reshape(-1, 1)
     return ref[0][:, 0:1]
 
@@ -78,10 +84,7 @@ def _fit_block(block, seq):
 
 
 def _interpret_default() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    return jax.default_backend() == "cpu"
 
 
 def _dropout_keep(seed_ref, bh, q_start, k_start, block_q, block_k, s_k,
@@ -113,14 +116,12 @@ def _dropout_keep(seed_ref, bh, q_start, k_start, block_q, block_k, s_k,
 def _apply_bias(s, bias_ref, bias_kind):
     """Additive attention bias inside a kernel block.
 
-    bias_kind 'key': bias_ref block is (1, block_k) — the HF extended-mask
-    (B, 1, 1, S_k) case, broadcast over query rows; 'full': (1, block_q,
-    block_k) per-(batch*head) scores bias."""
-    if bias_kind == "key":
-        return s + bias_ref[...]
-    if bias_kind == "full":
-        return s + bias_ref[0]
-    return s
+    bias_kind 'key': bias_ref block is (1, 1, block_k) — the HF
+    extended-mask (B, 1, 1, S_k) case, broadcast over query rows; 'full':
+    (1, block_q, block_k) per-(batch*head) scores bias."""
+    if bias_kind == "none":
+        return s
+    return s + bias_ref[0]
 
 
 def _bias_specs(bias, bias_kind, num_heads, block_q, block_k, qmap, kmap):
@@ -129,9 +130,12 @@ def _bias_specs(bias, bias_kind, num_heads, block_q, block_k, qmap, kmap):
     if bias_kind == "none":
         return [], []
     if bias_kind == "key":
+        # (B, 1, S_k): the unit middle dim makes the block's last two dims
+        # (1, block_k) = (whole dim, lane-aligned), which the TPU lowering
+        # takes; a 2-D (B, S_k) array blocked (1, block_k) is refused
         spec = pl.BlockSpec(
-            (1, block_k),
-            lambda b, i, j: (b // num_heads, kmap(i, j)))
+            (1, 1, block_k),
+            lambda b, i, j: (b // num_heads, 0, kmap(i, j)))
         return [bias], [spec]
     spec = pl.BlockSpec(
         (1, block_q, block_k),
@@ -210,7 +214,7 @@ def _fwd_kernel(*refs, scale, causal, bias_kind, dropout_rate, s_k_total,
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
         lse = m_scr[:, 0:1] + jnp.log(l_safe)
-        if _LSE_2D:
+        if _lse_2d():
             lse_ref[...] = lse.reshape(lse_ref.shape)
         else:
             lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
@@ -251,13 +255,14 @@ def _flash_fwd(q, k, v, bias, seed, *, scale, causal, bias_kind, num_heads,
         ] + bias_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)) if _LSE_2D
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+            if _lse_2d()
             else pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_q) if _LSE_2D else (bh, s_q, 128),
-                                 jnp.float32),
+            jax.ShapeDtypeStruct(
+                (bh, 1, s_q) if _lse_2d() else (bh, s_q, 128), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -268,7 +273,7 @@ def _flash_fwd(q, k, v, bias, seed, *, scale, causal, bias_kind, num_heads,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*seed_ops, q, k, v, *bias_ops)
-    return out, (lse if _LSE_2D else lse[:, :, 0])
+    return out, (lse[:, 0, :] if _lse_2d() else lse[:, :, 0])
 
 
 # ---------------------------------------------------------------------------
@@ -418,9 +423,9 @@ def _flash_bwd(res, g, *, scale, causal, bias_kind, num_heads, dropout_rate,
 
     # delta_i = rowsum(dO_i * O_i) — standard flash backward precompute
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    if _LSE_2D:
-        lse_w = lse.astype(jnp.float32)                      # (bh, s_q)
-        delta_w = delta
+    if _lse_2d():
+        lse_w = lse.astype(jnp.float32)[:, None, :]          # (bh, 1, s_q)
+        delta_w = delta[:, None, :]
     else:
         lse_w = jnp.broadcast_to(
             lse[:, :, None], (bh, s_q, 128)).astype(jnp.float32)
@@ -429,9 +434,9 @@ def _flash_bwd(res, g, *, scale, causal, bias_kind, num_heads, dropout_rate,
     def stat_spec(index_q):
         """BlockSpec for the lse/delta operands; index_q maps grid ids to
         the q-block index."""
-        if _LSE_2D:
-            return pl.BlockSpec((1, block_q),
-                                lambda b, x, y: (b, index_q(x, y)))
+        if _lse_2d():
+            return pl.BlockSpec((1, 1, block_q),
+                                lambda b, x, y: (b, 0, index_q(x, y)))
         return pl.BlockSpec((1, block_q, 128),
                             lambda b, x, y: (b, index_q(x, y), 0))
 
@@ -556,7 +561,7 @@ def flash_attention(q, k, v, *, bias=None, causal: bool = False,
     bias: optional ADDITIVE attention bias — (B, 1, 1, S_k) HF extended
     mask / key-padding form, or any shape broadcastable to (B, H, S_q, S_k).
     Treated as a constant (no bias gradient). Differentiable in q/k/v
-    (custom VJP with blockwise recomputation). On non-TPU backends runs in
+    (custom VJP with blockwise recomputation). On the CPU backend runs in
     Pallas interpreter mode (slow; tests only).
 
     dropout_rate/dropout_seed: in-kernel attention dropout. The seed (int
@@ -592,7 +597,7 @@ def flash_attention(q, k, v, *, bias=None, causal: bool = False,
             # key-padding bias: one row per batch, broadcast over heads/rows
             bias_kind = "key"
             bias3 = jnp.broadcast_to(
-                bias[:, 0, 0, :], (b, s_k)).astype(jnp.float32)
+                bias[:, 0, :, :], (b, 1, s_k)).astype(jnp.float32)
         else:
             bias_kind = "full"
             bias3 = jnp.broadcast_to(

@@ -150,7 +150,7 @@ class _EncoderBody(nn.Module):
 
         from deepspeed_tpu.parallel import mesh as mesh_lib
 
-        head_sp = P("data", ("model", "seq"), None, None)
+        head_sp = mesh_lib.HEAD_SHARDED
         qh = mesh_lib.constrain(heads(q), head_sp)
         kh = mesh_lib.constrain(heads(k), head_sp)
         vh = mesh_lib.constrain(heads(v), head_sp)

@@ -102,41 +102,81 @@ def constrain(x, spec):
     with_sharding_constraint rejects specs naming manual axes."""
     import jax
 
-    from jax.sharding import PartitionSpec as P
-
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return x
-    # with_sharding_constraint accepts only Auto axes: under shard_map the
-    # mapped axes are Manual and the rest become Explicit, so both must be
-    # dropped here (checked up front — genuine spec errors like rank
-    # mismatch still surface from with_sharding_constraint itself)
-    auto = getattr(mesh, "auto_axes", None)
-    if auto is None:  # pragma: no cover - older jax
-        manual = set(getattr(mesh, "manual_axes", ()) or ())
-        auto = tuple(a for a in mesh.shape if a not in manual)
-    # old jax's abstract mesh knows nothing about the legacy shard_map
-    # wrapping this trace — its manual axes are tracked by the compat shim
-    # and must be dropped too (empty set on new jax)
-    from deepspeed_tpu.utils.jax_compat import current_manual_axes
+    cleaned = _bound_spec(mesh, spec)
+    if all(a is None for a in cleaned):
+        return x
+    return jax.lax.with_sharding_constraint(x, cleaned)
 
-    compat_manual = current_manual_axes()
+
+def _bound_spec(mesh, spec):
+    """``spec`` without the axes that cannot bind in ``mesh``: absent from
+    it, or not Auto — under shard_map the mapped axes are Manual and the
+    rest become Explicit, and both with_sharding_constraint and a nested
+    shard_map accept only Auto axes
+    (checked up front — genuine spec errors like rank mismatch still
+    surface from the caller's own jax call)."""
+    from jax.sharding import PartitionSpec as P
+
+    auto = mesh.auto_axes
 
     def keep(axis):
         if axis is None:
             return None
         axes = axis if isinstance(axis, tuple) else (axis,)
-        kept = tuple(a for a in axes
-                     if a in mesh.shape and a in auto
-                     and a not in compat_manual)
+        kept = tuple(a for a in axes if a in mesh.shape and a in auto)
         if not kept:
             return None
         return kept if len(kept) > 1 else kept[0]
 
-    cleaned = P(*(keep(a) for a in spec))
-    if all(a is None for a in cleaned):
-        return x
-    return jax.lax.with_sharding_constraint(x, cleaned)
+    return P(*(keep(a) for a in spec))
+
+
+# The layout attention runs in, as a spec for constrain() / per_shard():
+# batch over 'data', heads over 'model' and 'seq', whole sequences.  The
+# models constrain q/k/v to it and the kernel dispatch maps its kernels
+# over it — one definition, or a changed layout reshards at every call.
+HEAD_SHARDED = (DATA_AXIS, (MODEL_AXIS, SEQ_AXIS), None, None)
+
+
+def shards_evenly(shape, spec) -> bool:
+    """Whether every dim of ``shape`` splits evenly over the axes ``spec``
+    binds in the active mesh — what a shard_map over that spec needs."""
+    import math
+
+    import jax
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return True
+    for n, axis in zip(shape, _bound_spec(mesh, spec)):
+        axes = () if axis is None else \
+            axis if isinstance(axis, tuple) else (axis,)
+        if n % math.prod(mesh.shape[a] for a in axes):
+            return False
+    return True
+
+
+def per_shard(fn, in_specs, out_spec):
+    """``fn`` wrapped to run once per shard of the active mesh — for Mosaic
+    (Pallas TPU) kernels, which GSPMD cannot partition: on a mesh of more
+    than one device the lowering refuses them ("Mosaic kernels cannot be
+    automatically partitioned") unless EVERY mesh axis is manual.  So all
+    axes still Auto are mapped; the specs name the operands' full layout
+    the way :func:`constrain` specs do, and an operand is whole along the
+    axes its spec leaves out.  With no mesh, one device, or every axis
+    manual already, ``fn`` is returned as it is."""
+    import jax
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1 or not mesh.auto_axes:
+        return fn
+    return jax.shard_map(
+        fn, in_specs=tuple(_bound_spec(mesh, s) for s in in_specs),
+        out_specs=_bound_spec(mesh, out_spec),
+        axis_names=set(mesh.auto_axes), check_vma=False)
 
 
 def data_sharding(mesh, *, extra_dims: int = 1):

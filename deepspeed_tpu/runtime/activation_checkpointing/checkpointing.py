@@ -113,13 +113,8 @@ def _policy():
     if _CONFIG["checkpoint_in_cpu"]:
         # save matmul outputs but offload them to host memory — the TPU
         # analog of cpu_checkpointing's activation host placement
-        try:
-            return jax.checkpoint_policies.offload_dot_products_with_no_batch_dims(
-                "device", "pinned_host")
-        except AttributeError:  # older jax
-            logger.warning("checkpoint_in_cpu: offload policy unavailable "
-                           "in this jax; falling back to full recompute")
-            return jax.checkpoint_policies.nothing_saveable
+        return jax.checkpoint_policies.offload_dot_products_with_no_batch_dims(
+            "device", "pinned_host")
     if _CONFIG["partition_activations"]:
         return jax.checkpoint_policies.nothing_saveable
     # default matches torch checkpointing: save boundaries, recompute body

@@ -41,11 +41,8 @@ from deepspeed_tpu.runtime.fp16.loss_scaler import (LossScaleState,
                                                     update_loss_scale)
 from deepspeed_tpu.runtime.lr_schedules import SCHEDULER_REGISTRY
 from deepspeed_tpu.runtime.progressive_layer_drop import ProgressiveLayerDrop
-from deepspeed_tpu.utils.jax_compat import ensure_compat
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
-
-ensure_compat()
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500000000
 
@@ -483,7 +480,7 @@ class DeepSpeedEngine:
                 params.setdefault("axis_size", dp)
             elif dp > 1:
                 # compression silently no-oping would defeat the user's
-                # intent — name the blocking condition loudly (VERDICT r4 §6)
+                # intent — name the blocking condition loudly
                 blockers = []
                 if self.zero_optimization_stage() != 0:
                     blockers.append(
@@ -1479,18 +1476,9 @@ class DeepSpeedEngine:
             scaler = make_loss_scale_state(self._host_scaler.cur_scale)
         self._host_skipped = 0
 
-        # scalars must carry the mesh's replicated sharding (not
-        # SingleDeviceSharding): multi-process checkpointing can only
-        # serialize globally-addressable arrays
-        rep = mesh_lib.replicated(self.mesh)
-        put_rep = lambda x: jax.device_put(x, rep)
-        if scaler is not None:
-            scaler = jax.tree_util.tree_map(put_rep, scaler)
         self.state = TrainState(
-            step=put_rep(jnp.int32(0)), micro_step=put_rep(jnp.int32(0)),
             params=params, opt_state=(), master=None, accum=(),
-            scaler=scaler, skipped_steps=put_rep(jnp.int32(0)),
-            rng=put_rep(state_rng))
+            **self._replicated_scalars(scaler, state_rng))
         n_params = sum(l.size for l in self._host_master_flat)
         log_dist(
             f"Initialized ZeRO-Offload state: {n_params/1e6:.1f}M params "
@@ -1563,12 +1551,29 @@ class DeepSpeedEngine:
                     delayed_shift=args.get("delayed_shift", 1))
 
         self.state = TrainState(
-            step=jnp.int32(0), micro_step=jnp.int32(0), params=params,
-            opt_state=opt_state, master=master, accum=accum, scaler=scaler,
-            skipped_steps=jnp.int32(0), rng=state_rng)
+            params=params, opt_state=opt_state, master=master, accum=accum,
+            **self._replicated_scalars(scaler, state_rng))
         n_params = sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
         log_dist(f"Initialized model state: {n_params/1e6:.1f}M params "
                  f"in {time.time()-t0:.1f}s", ranks=[0])
+
+    def _replicated_scalars(self, scaler, state_rng):
+        """The scalar fields of a fresh TrainState, committed to the mesh's
+        replicated sharding like every jit output.  Uncommitted, they give
+        the first dispatch an input type no later one has (the whole step
+        program then compiles twice), and multi-process checkpointing can
+        only serialize globally-addressable arrays."""
+        import jax
+        import jax.numpy as jnp
+
+        rep = mesh_lib.replicated(self.mesh)
+
+        def put(x):     # a buffer each: the step jit donates the state
+            return jax.device_put(x, rep)
+
+        return dict(step=put(jnp.int32(0)), micro_step=put(jnp.int32(0)),
+                    scaler=put(scaler), skipped_steps=put(jnp.int32(0)),
+                    rng=put(state_rng))
 
     def _shard_batch(self, batch):
         """Host batch -> device arrays with dim0 sharded over 'data'."""

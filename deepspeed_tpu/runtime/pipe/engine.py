@@ -43,6 +43,8 @@ from deepspeed_tpu.runtime.pipe import schedule as sched_lib
 from deepspeed_tpu.runtime.pipe.module import PipelineModule
 from deepspeed_tpu.runtime.pipe.topology import (PipelineParallelGrid,
                                                  PipeModelDataParallelTopology)
+from deepspeed_tpu.utils.compile_cache import \
+    disable_persistent_compile_cache
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 
@@ -51,6 +53,17 @@ class StageState(NamedTuple):
     master: object      # fp32 master (None in fp32 mode)
     opt_state: object   # optimizer state over master
     accum: object       # fp32 grad accumulator
+
+
+def _cached_stage_programs_halt(submeshes):
+    """A program over more than one TPU whose devices leave out device 0
+    halts its cores once it is read back from the persistent compile cache
+    ("Invalid logical z: enhanced-barrier"; jax 0.9.0 and its libtpu, any
+    such jit — tools/compile_cache_probe.py).  Every stage after the first
+    is such a program, so a PipelineEngine with one compiles in the
+    process."""
+    return any(m.size > 1 and m.devices.flat[0].platform == "tpu"
+               for m in submeshes[1:])
 
 
 class _MfuJitProxy:
@@ -175,6 +188,11 @@ class PipelineEngine(DeepSpeedEngine):
             self._submeshes.append(
                 jax.sharding.Mesh(self.mesh.devices[s],
                                   ("data", "seq", "model")))
+
+        if _cached_stage_programs_halt(self._submeshes):
+            disable_persistent_compile_cache(
+                "PipelineEngine stages of more than one TPU halt their "
+                "cores when read back from the cache (ROADMAP.md S4)")
 
         self.stage_states = None          # list[StageState] per CHUNK, lazy
         self._stage_shardings = None

@@ -69,7 +69,7 @@ from deepspeed_tpu.runtime.resilience.watchdog import (GracefulPreemption,
                                                        WatchdogAlarm)
 from deepspeed_tpu.utils.logging import log_dist, logger
 
-# incident kinds (the failure taxonomy; docs/tutorials/fault_tolerance.md)
+# incident kinds (the failure classes; docs/tutorials/fault_tolerance.md)
 KIND_TRANSIENT = "transient"       # step fault, live state intact
 KIND_WATCHDOG = "watchdog"         # NaN/overflow streak / stall escalation
 KIND_CRASH = "crash"               # exception/interrupt escaping a step

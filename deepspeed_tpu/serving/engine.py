@@ -68,10 +68,7 @@ from deepspeed_tpu.serving.scheduler import (Request, RequestState,
                                              Scheduler)
 from deepspeed_tpu.serving.sparse_context import (SparseContext,
                                                   _policy_layout)
-from deepspeed_tpu.utils.jax_compat import ensure_compat
 from deepspeed_tpu.utils.logging import logger
-
-ensure_compat()
 
 _MIN_BUCKET = 4
 
@@ -214,7 +211,7 @@ def _shard_wrap(core, mesh, axis_name, n_pool, in_streams, n_out_streams):
         P(axis_name) if s else P() for s in in_streams)
     out_specs = (pool_spec,) * n_pool + (P(axis_name),) * n_out_streams
     sm = jax.shard_map(core, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(sm, donate_argnums=donate)
 
 

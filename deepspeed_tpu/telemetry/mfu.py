@@ -26,16 +26,15 @@ and never runs device code, but it IS a compile, so it stays off the
 hot path and outside any recompile-guard window.
 
 Peak FLOPS resolution: an explicit ``peak_tflops_per_device`` config
-wins; otherwise the device-kind table below (the bench.py table, bf16
-peaks); unknown kinds (CPU meshes) report achieved FLOPS with
+wins; otherwise the device-kind table below (bf16 peaks); unknown kinds (CPU meshes) report achieved FLOPS with
 ``mfu``/``hfu`` = None rather than a ratio against a guessed peak.
 """
 import threading
 
 import numpy as np
 
-# bf16 peak TFLOPS per chip by device-kind substring (bench.py's table —
-# kept in sync by tests/unit/test_telemetry.py)
+# bf16 peak TFLOPS per chip by device-kind substring — the one table;
+# bench.py reads it too (v5e: Google Cloud documentation, "TPU v5e")
 PEAK_TFLOPS_TABLE = [
     ("v6e", 918.0), ("v6", 918.0),
     ("v5p", 459.0), ("v5e", 197.0), ("v5lite", 197.0), ("v5", 459.0),

@@ -122,6 +122,10 @@ class ThroughputTimer:
         self.local_step_count = 0
 
     def _init_timer(self):
+        if not self.initialized:
+            # compile the barrier's scalar program with the first step,
+            # where compilation is expected, not at start_step
+            _device_sync()
         self.initialized = True
 
     def start(self):

@@ -284,10 +284,128 @@ def test_lse_compact_wire_format_matches(monkeypatch):
                 interpret=True).astype(jnp.float32).sum()
         return f(q, k, v), jax.grad(f, argnums=(0, 1, 2))(q, k, v)
 
-    monkeypatch.setattr(fa, "_LSE_2D", False)
+    monkeypatch.delenv("DSTPU_FLASH_LSE2D", raising=False)
     base_loss, base_g = run()
-    monkeypatch.setattr(fa, "_LSE_2D", True)
+    monkeypatch.setenv("DSTPU_FLASH_LSE2D", "1")
     new_loss, new_g = run()
     np.testing.assert_array_equal(np.asarray(base_loss), np.asarray(new_loss))
     for a, b in zip(base_g, new_g):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_auto_dispatch_chooses_by_lowering_platform():
+    """With use_pallas unset and shapes the kernel takes, the choice waits
+    for the platform the program is lowered for: on the CPU that is the
+    jnp path, bit for bit, and the kernel's branch is never lowered (not
+    in interpret mode, it could not be)."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(4), 1, 2, 128, 64)
+    ref = scaled_dot_product_attention(q, k, v, causal=True,
+                                       use_pallas=False)
+    auto = jax.jit(lambda q, k, v: scaled_dot_product_attention(
+        q, k, v, causal=True))
+    np.testing.assert_array_equal(np.asarray(auto(q, k, v)),
+                                  np.asarray(ref))
+    jaxpr = str(jax.make_jaxpr(auto)(q, k, v))
+    assert "platform_index" in jaxpr and "pallas_call" in jaxpr
+
+
+def _mesh_2x2():
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                ("data", "model"))
+
+
+@pytest.mark.parametrize("keypad", [False, True])
+def test_pallas_dispatch_runs_per_shard_under_a_mesh(keypad):
+    """A Mosaic kernel cannot be partitioned by GSPMD, so under a mesh of
+    more than one device the dispatch maps the kernel over every mesh axis
+    (batch over 'data', heads over 'model'); results and gradients equal
+    the unsharded call's."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 2, 2, 128, 64)
+    mask = None
+    if keypad:
+        mask = (jnp.arange(128)[None, :]
+                < jnp.asarray([128, 70])[:, None])[:, None, None, :]
+
+    def loss(q, k, v):
+        out = scaled_dot_product_attention(q, k, v, mask=mask,
+                                           causal=not keypad,
+                                           use_pallas=True)
+        return out.sum(), out
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+    (_, ref), ref_g = grad(q, k, v)
+    with jax.set_mesh(_mesh_2x2()):
+        jitted = jax.jit(grad)
+        assert "shard_map" in str(jax.make_jaxpr(grad)(q, k, v))
+        (_, out), out_g = jitted(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-6, rtol=1e-6)
+    for a, b in zip(out_g, ref_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_per_shard_dropout_masks_differ_between_shards():
+    """Every shard sees the same seed and the same local (batch*head)
+    indices; without the shard index folded into the seed the two batch
+    rows below (one per 'data' shard, identical inputs) would drop the
+    same positions."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(6), 1, 2, 128, 64)
+    q, k, v = (jnp.concatenate([t, t]) for t in (q, k, v))
+
+    def f(q, k, v):
+        return scaled_dot_product_attention(
+            q, k, v, causal=True, dropout_rate=0.5,
+            dropout_rng=jax.random.PRNGKey(3), use_pallas=True)
+
+    with jax.set_mesh(_mesh_2x2()):
+        out = jax.jit(f)(q, k, v)
+    assert not np.allclose(np.asarray(out[0]), np.asarray(out[1]))
+
+
+def test_auto_dispatch_leaves_what_the_mesh_does_not_divide_to_jnp():
+    """The kernel is mapped over the mesh, which needs the batch to split
+    over 'data' and the heads over 'model': where they do not, the auto
+    path takes the jnp path instead of failing inside shard_map."""
+    from deepspeed_tpu.ops.transformer.functional import _pallas_attention_ok
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    fits = _rand_qkv(jax.random.PRNGKey(7), 2, 2, 128, 64)
+    odd_batch = _rand_qkv(jax.random.PRNGKey(7), 3, 2, 128, 64)
+    odd_heads = _rand_qkv(jax.random.PRNGKey(7), 2, 3, 128, 64)
+    for qkv in (fits, odd_batch, odd_heads):
+        assert _pallas_attention_ok(*qkv, None, None, 0.0)
+    with jax.set_mesh(_mesh_2x2()):
+        assert _pallas_attention_ok(*fits, None, None, 0.0)
+        assert not _pallas_attention_ok(*odd_batch, None, None, 0.0)
+        assert not _pallas_attention_ok(*odd_heads, None, None, 0.0)
+        assert mesh_lib.shards_evenly((4, 6), ("data", None))
+        assert not mesh_lib.shards_evenly((4, 6), (None, ("model", "data")))
+        out = jax.jit(lambda q, k, v: scaled_dot_product_attention(
+            q, k, v, causal=True))(*odd_batch)
+    ref = scaled_dot_product_attention(*odd_batch, causal=True,
+                                       use_pallas=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-6, rtol=1e-6)
+
+
+def test_dropout_seed_folds_only_the_axes_that_split_the_operands():
+    """Along 'data' every shard draws its own mask; along 'pipe' the
+    operands are replicas (the kernel is mapped over every mesh axis, the
+    layout names only data/model/seq) and must draw the same one."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from deepspeed_tpu.ops.transformer.functional import _fold_shard_index
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                ("pipe", "data"))
+    with jax.set_mesh(mesh):
+        seeds = jax.jit(mesh_lib.per_shard(
+            _fold_shard_index, [P()], P(("pipe", "data"))))(
+                jnp.full((1,), 11, jnp.int32))
+    seeds = np.asarray(seeds).reshape(2, 2)          # [pipe, data]
+    assert (seeds[0] == seeds[1]).all()
+    assert seeds[0, 0] != seeds[0, 1] and seeds[0, 0] == 11

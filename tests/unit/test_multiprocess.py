@@ -11,19 +11,10 @@ import socket
 import subprocess
 import sys
 
-import jax
 import pytest
 
 WORKER = os.path.join(os.path.dirname(__file__), "multiproc_worker.py")
 WORLD = 2
-
-# jax < 0.5 CPU backend: "Multiprocess computations aren't implemented on
-# the CPU backend" — the workers inherit the host platform, so these can
-# only run there against real accelerators
-_old_jax = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-pytestmark = pytest.mark.skipif(
-    _old_jax and os.environ.get("JAX_PLATFORMS", "").startswith("cpu"),
-    reason="jax<0.5 CPU backend has no multi-process collectives")
 
 
 def _free_port():

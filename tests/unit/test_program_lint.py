@@ -31,10 +31,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from deepspeed_tpu.utils.jax_compat import ensure_compat  # noqa: E402
-
-ensure_compat()  # jax.set_mesh on older jax — register_program uses it
-
 from deepspeed_tpu.telemetry.programs import (CONTRACT_KEYS,  # noqa: E402
                                               ProgramRegistry,
                                               register_program)

@@ -171,3 +171,24 @@ def test_env_report_runs(capsys):
     out = capsys.readouterr().out
     assert "cpu_adam" in out
     assert "jax version" in out
+
+
+def test_env_report_names_the_backend(capsys):
+    """The Pallas line says which backend answered — interpret mode only
+    because the backend IS the CPU, never as the word for 'no TPU seen'."""
+    from deepspeed_tpu.env_report import main
+
+    main()
+    assert "interpret-mode (backend is cpu)" in capsys.readouterr().out
+
+
+def test_parents_of_chip_processes_stay_off_jax():
+    """A chip belongs to one process: bench.py's parent and the launchers
+    start the child that needs it, so importing them must not touch JAX."""
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    code = ("import sys, bench, deepspeed_tpu.launcher.launch, "
+            "deepspeed_tpu.launcher.runner; "
+            "sys.exit('jax' in sys.modules)")
+    assert subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          timeout=120).returncode == 0

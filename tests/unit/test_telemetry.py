@@ -242,8 +242,10 @@ def test_mfu_peak_table_matches_bench():
     for kind, expect in (("TPU v5 lite", 197.0), ("TPU v4", 275.0)):
         got, known = peak_flops_per_device(kind)
         assert known and got == pytest.approx(expect * 1e12)
-        assert bench._peak_tflops(kind) == (expect, True)
+        assert bench._peak_tflops(kind) == pytest.approx(expect)
     assert peak_flops_per_device("weird-cpu") == (None, False)
+    with pytest.raises(ValueError, match="weird-cpu"):
+        bench._peak_tflops("weird-cpu")
     assert model_flops_per_step(10, 5) == 300.0
     assert model_flops_per_step(10, 5, fwd_only=True) == 100.0
 

@@ -1,0 +1,187 @@
+"""Compile-only rehearsals: every Pallas variant the models dispatch, lowered
+with interpret=False and compiled by the TPU compiler for a described (not
+attached) v5e chip at real widths.  Nothing runs, so this says nothing about
+results or times — it catches what interpret mode cannot: block shapes the
+Mosaic lowering refuses and memory a kernel may not use."""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import deepspeed_tpu.ops.transformer.flash_attention as fa
+from deepspeed_tpu.ops.sparse_attention import FixedSparsityConfig
+from deepspeed_tpu.ops.sparse_attention.block_sparse_kernel import (
+    SMEM_BYTES, pallas_block_sparse_attention)
+
+GPT2_350M_ATTN = (8, 16, 1024, 64)      # micro-batch 8, 16 heads, seq 1024
+BERT_LARGE_ATTN = (8, 16, 512, 64)
+SPARSE_ATTN = (2, 12, 4096, 64)
+
+
+@pytest.fixture(scope="module")
+def v5e_host():
+    """The four chips of a described v5e:2x2 host, compile cache off: an
+    entry written from here cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever this installation raises without libtpu
+        pytest.skip(f"no TPU compiler here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def v5e(v5e_host):
+    return SingleDeviceSharding(v5e_host[0])
+
+
+def _kernels_in(fn, args):
+    """Number of Pallas kernels in ``fn`` compiled for the described chip."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _grad(attn):
+    return jax.grad(lambda *a: attn(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+def _qkv(shape, sharding):
+    return (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding),) * 3
+
+
+FLASH_CASES = {
+    "causal-fwd": (GPT2_350M_ATTN, {}, False, 1),
+    "causal-fwd+bwd": (GPT2_350M_ATTN, {}, True, 3),
+    "causal-dropout-fwd+bwd": (GPT2_350M_ATTN, {"dropout": 0.1}, True, 3),
+    "key-bias-fwd+bwd": (BERT_LARGE_ATTN, {"key_bias": True}, True, 3),
+    "causal-compact-lse-fwd+bwd": (GPT2_350M_ATTN, {"lse_2d": True}, True,
+                                   3),
+}
+
+
+@pytest.mark.parametrize("case", FLASH_CASES)
+def test_flash_attention_compiles_for_v5e(case, v5e, monkeypatch):
+    shape, opts, backward, n_kernels = FLASH_CASES[case]
+    monkeypatch.setenv("DSTPU_FLASH_LSE2D", "1" if opts.get("lse_2d") else "0")
+    args = _qkv(shape, v5e)
+    kw = {"interpret": False}
+    if opts.get("key_bias"):
+        # the BERT path: an HF extended mask, (B, 1, 1, S_k)
+        args += (jax.ShapeDtypeStruct((shape[0], 1, 1, shape[2]),
+                                      jnp.float32, sharding=v5e),)
+    else:
+        kw["causal"] = True
+    if opts.get("dropout"):
+        args += (jax.ShapeDtypeStruct((1,), jnp.int32, sharding=v5e),)
+        kw["dropout_rate"] = opts["dropout"]
+
+    def attn(q, k, v, *extra):
+        if opts.get("key_bias"):
+            return fa.flash_attention(q, k, v, bias=extra[0], **kw)
+        if opts.get("dropout"):
+            return fa.flash_attention(q, k, v, dropout_seed=extra[0], **kw)
+        return fa.flash_attention(q, k, v, **kw)
+
+    assert _kernels_in(_grad(attn) if backward else attn, args) == n_kernels
+
+
+@pytest.mark.parametrize("key_bias", [False, True],
+                         ids=["no-bias", "key-bias"])
+@pytest.mark.parametrize("block", [64, 128])
+def test_block_sparse_attention_compiles_for_v5e(block, key_bias, v5e):
+    B, H, S, _ = SPARSE_ATTN
+    layout = np.asarray(
+        FixedSparsityConfig(num_heads=H, block=block).make_layout(S))
+    args = _qkv(SPARSE_ATTN, v5e)
+    if key_bias:
+        args += (jax.ShapeDtypeStruct((B, S), jnp.float32, sharding=v5e),)
+
+    def attn(q, k, v, *kb):
+        return pallas_block_sparse_attention(
+            q, k, v, layout, block, key_bias=kb[0] if kb else None,
+            interpret=False)
+
+    assert _kernels_in(_grad(attn), args) == 3
+
+
+@pytest.mark.parametrize("kernel", ["flash", "flash-dropout-data2-model2",
+                                    "block-sparse-key-bias"])
+def test_kernels_compile_inside_a_four_chip_program(kernel, v5e_host,
+                                                    monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: inside a program over four
+    chips the dispatch has to map it over the mesh, or the lowering refuses
+    the whole step ("Mosaic kernels cannot be automatically partitioned").
+    Attention's dispatch chooses by the platform it is lowered for; the
+    sparse one reads the default backend, so the test answers 'tpu'."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops.sparse_attention import block_sparse_attention
+    from deepspeed_tpu.ops.transformer.functional import (
+        scaled_dot_product_attention)
+    from deepspeed_tpu.parallel.mesh import AXIS_ORDER
+
+    shape = (1, 2, 1, 2) if kernel.endswith("data2-model2") else (1, 4, 1, 1)
+    mesh = Mesh(np.asarray(v5e_host).reshape(shape), AXIS_ORDER)
+    batch_sharded = NamedSharding(mesh, P("data"))
+    if kernel == "flash":
+        args = _qkv((32,) + GPT2_350M_ATTN[1:], batch_sharded)
+
+        def attn(q, k, v):
+            return scaled_dot_product_attention(q, k, v, causal=True)
+    elif kernel == "flash-dropout-data2-model2":
+        # heads over 'model' as well, the shard's index folded into the seed
+        args = _qkv((16,) + GPT2_350M_ATTN[1:], batch_sharded)
+
+        def attn(q, k, v):
+            return scaled_dot_product_attention(
+                q, k, v, causal=True, dropout_rate=0.1,
+                dropout_rng=jax.random.PRNGKey(0))
+    else:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        B, H, S, D = 4, 12, 4096, 64
+        layout = np.asarray(
+            FixedSparsityConfig(num_heads=H, block=64).make_layout(S))
+        args = _qkv((B, H, S, D), batch_sharded) + (
+            jax.ShapeDtypeStruct((B, S), jnp.float32,
+                                 sharding=batch_sharded),)
+
+        def attn(q, k, v, kpm):
+            return block_sparse_attention(q, k, v, layout, 64,
+                                          key_padding_mask=kpm,
+                                          use_pallas=True)
+
+    with jax.set_mesh(mesh):
+        assert _kernels_in(_grad(attn), args) == 3
+
+
+def test_lut_beyond_smem_fails_at_trace_time():
+    """The reference's default block 16 at S 4096: the transpose LUT of the
+    dk/dv sweep needs 3 MiB of scalar memory.  That must surface while
+    tracing, naming the limit and the block — not inside the compiler."""
+    B, H, S, D = SPARSE_ATTN
+    layout = np.asarray(
+        FixedSparsityConfig(num_heads=H, block=16).make_layout(S))
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16)
+
+    def attn(q, k, v):
+        return pallas_block_sparse_attention(q, k, v, layout, 16,
+                                             interpret=False)
+
+    with pytest.raises(ValueError, match=rf"block size 16.*{SMEM_BYTES}"):
+        jax.eval_shape(_grad(attn), q, q, q)
+    # the forward's own table is smaller and fits
+    assert jax.eval_shape(attn, q, q, q).shape == (B, H, S, D)

@@ -3,8 +3,8 @@
 Usage:  python tools/profile_step.py [model] [batch] [seq] [steps]
 Writes a TensorBoard-loadable trace under <repo>/profile_out/ and prints
 the top-level step timing. The trace shows per-op device time (MXU vs VPU
-vs HBM stalls) — the ground truth for the bench tuning loop (VERDICT
-round-3 item 1: profile before tuning).
+vs HBM stalls) — the ground truth for the bench tuning loop: profile
+before tuning.
 """
 import os
 import sys
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2Model, gpt2_config
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 MODEL = sys.argv[1] if len(sys.argv) > 1 else "gpt2-350m"
 BS = int(sys.argv[2]) if len(sys.argv) > 2 else 48
@@ -29,6 +30,7 @@ OUT = os.path.normpath(
 
 
 def main():
+    enable_compile_cache()
     cfg = gpt2_config(MODEL, n_positions=SEQ, dtype=jnp.bfloat16,
                       remat=True, scan_layers=True)
     model = GPT2Model(cfg)

@@ -97,9 +97,6 @@ def build_toy(n_embd, n_layer, vocab):
     import jax.numpy as jnp
 
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
-    from deepspeed_tpu.utils.jax_compat import ensure_compat
-
-    ensure_compat()
     cfg = GPT2Config(vocab_size=vocab, n_positions=128, n_embd=n_embd,
                      n_layer=n_layer, n_head=max(2, n_embd // 16),
                      dtype=jnp.float32, loss_chunk_tokens=0)
@@ -673,9 +670,6 @@ def build_long_context_toy(vocab, *, n_positions, n_embd=16, n_layer=1):
     import jax.numpy as jnp
 
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
-    from deepspeed_tpu.utils.jax_compat import ensure_compat
-
-    ensure_compat()
     cfg = GPT2Config(vocab_size=vocab, n_positions=n_positions,
                      n_embd=n_embd, n_layer=n_layer, n_head=2,
                      dtype=jnp.float32, loss_chunk_tokens=0)
@@ -875,4 +869,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -1140,13 +1140,13 @@ class DeepSpeedEngine:
         """The armed Telemetry session, or None."""
         return self._telemetry
 
-    def export_trace(self, path, complete_events=True):
+    def export_trace(self, path):
         """Write the retained trace as Chrome-trace-event JSON (loadable
         in chrome://tracing / Perfetto); None when tracing is disarmed."""
         tr = self._tracer
         if tr is None:
             return None
-        return tr.export_chrome_trace(path, complete_events=complete_events)
+        return tr.export_chrome_trace(path)
 
     @property
     def program_registry(self):

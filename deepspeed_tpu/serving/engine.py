@@ -731,6 +731,13 @@ class InferenceEngine:
         self._tracer = None
         self._owns_telemetry = False
         self._lane_serve = 0
+        # kept only while a tracer is armed (_dispatch / _fetch): the
+        # open run_* span while a program of ours is unfetched, else the
+        # open host_gap (None before the first dispatch), and whether a
+        # step() found the engine empty during that gap
+        self._run = None
+        self._gap = None
+        self._gap_idle = False
         self._memacct = None
         if spec is None:
             return
@@ -759,6 +766,10 @@ class InferenceEngine:
             self._tracer.intern("serving_step", args=("step",))
             self._tracer.intern("decode_step", args=("lanes",))
             self._tracer.intern("admit", args=("rid",))
+            self._tracer.intern("host_gap", args=("engine_busy",))
+            self._tracer.intern("run_decode", args=("lanes",))
+            self._tracer.intern("run_prefill", args=("bucket",))
+            self._tracer.intern("run_prefill_decode", args=("lanes",))
         # measured HBM accounting (ISSUE 15): per-jit memory_analysis()
         # registered capture-by-shape alongside MFU, sharing its lazy
         # compile cache — one compile per jit, zero on the decode path
@@ -767,13 +778,13 @@ class InferenceEngine:
 
         self._memacct = MemoryAccounting(shared=tel.mfu)
 
-    def export_trace(self, path, complete_events=True):
+    def export_trace(self, path):
         """Chrome-trace JSON of the retained events (None when tracing
         is disarmed)."""
         tr = self._tracer
         if tr is None:
             return None
-        return tr.export_chrome_trace(path, complete_events=complete_events)
+        return tr.export_chrome_trace(path)
 
     def close_telemetry(self):
         """Close the metrics-stream file handle of a telemetry session
@@ -919,7 +930,8 @@ class InferenceEngine:
         fetch, and the journal's step-boundary commit."""
         self._step_idx += 1
         tr = self._tracer
-        _t0 = tr.begin() if tr is not None else 0.0
+        _step = tr.span("serving_step", self._lane_serve) \
+            if tr is not None else None
         slow = chaos.serving_slow_step_s(self._step_idx) \
             + chaos.fleet_slow_replica_s(self._replica_index,
                                          self._step_idx)
@@ -938,27 +950,21 @@ class InferenceEngine:
         rid = self.scheduler.chaos_cancel()
         if rid is not None and self.cancel(rid):
             events["cancelled"].append(rid)
+        self._enforce_deadlines(events)
         if tr is None:
-            self._enforce_deadlines(events)
             self._prefill_tick(events)
             decoded = self._decode_tick(events)
         else:
-            _t = tr.begin()
-            self._enforce_deadlines(events)
-            tr.complete("deadline_sweep", self._lane_serve, _t)
-            _t = tr.begin()
+            _tick = tr.span("prefill_tick", self._lane_serve)
             self._prefill_tick(events)
-            tr.complete("prefill_tick", self._lane_serve, _t)
-            _t = tr.begin()
+            _tick.end()
+            _tick = tr.span("decode_step", self._lane_serve)
             decoded = self._decode_tick(events)
-            tr.complete("decode_step", self._lane_serve, _t, a0=decoded)
+            _tick.end(a0=decoded)
             for rid_ in events["admitted"]:
                 tr.instant("admit", self._lane_serve, a0=rid_)
         self.scheduler.on_drained()
         self.reliability.on_step_end()
-        if tr is not None and self.reliability.journal is not None:
-            tr.instant("journal_commit", self._lane_serve,
-                       a0=self.reliability.journal_depth())
         occ = self.pool.occupancy()
         frag = self.pool.fragmentation()
         qd = self.scheduler.queue_depth()
@@ -994,8 +1000,11 @@ class InferenceEngine:
             "short_ttft_p95": self.metrics.class_ttft_p95("short"),
         }
         if tr is not None:
-            tr.complete("serving_step", self._lane_serve, _t0,
-                        a0=self._step_idx)
+            if self._gap is not None and qd == 0 \
+                    and not self.scheduler.in_flight():
+                # this gap is want of demand, not the host's doing
+                self._gap_idle = True
+            _step.end(a0=self._step_idx)
         if self.telemetry is not None and not self._warming:
             self.telemetry.on_step(self._step_idx, self._last_metrics)
         return events
@@ -1575,6 +1584,41 @@ class InferenceEngine:
             self._seeds[slot] = req.seed
             self._active[slot] = True
 
+    def _dispatch(self, span, fn, args):
+        """Every serving program goes to the device through here, and
+        every result comes back through :meth:`_fetch`: between them a
+        traced engine knows whether anything of its own is unfetched.
+        The stretch from the first dispatch after a drain to the fetch
+        that drains the device again is ONE span, named at its start by
+        what opens it (``span``: ``run_decode``, ``run_prefill`` for a
+        final chunk, ``run_prefill_decode`` for a non-final chunk, which
+        runs on under whatever is dispatched next); the rest of the
+        serve thread's time is ``host_gap``.  Disarmed this is the call
+        alone."""
+        tr = self._tracer
+        if tr is not None and self._run is None:
+            now = tr.clock()
+            if self._gap is not None:
+                self._gap.end(a0=0 if self._gap_idle else 1, at=now)
+                self._gap = None
+            self._run = tr.span(span, self._lane_serve, t0=now)
+        return fn(*args)
+
+    def _fetch(self, arrays, *, lanes=0, bucket=0):
+        """The step's ONE batched fetch.  It waits for every program
+        still in flight, so it ends the open ``run_*`` span (a0: the
+        bucket of a final chunk that ran alone, else the lanes decoded
+        under it) and opens the next ``host_gap`` at the same instant."""
+        fetched = jax.device_get(arrays)
+        tr = self._tracer
+        if tr is not None:
+            run, self._run = self._run, None
+            now = run.end(a0=bucket if run.name == "run_prefill"
+                          else lanes)
+            self._gap = tr.span("host_gap", self._lane_serve, t0=now)
+            self._gap_idle = False
+        return fetched
+
     def _prefill_args(self, req, n):
         rows = np.full((self.shards, self.W), TRASH_BLOCK, np.int32)
         nv = np.zeros(self.shards, np.int32)
@@ -1664,13 +1708,14 @@ class InferenceEngine:
 
             register_by_shape(self.telemetry.mfu, pf_name, fn, pf_args)
             mem_acc.register_by_shape(self._memacct, pf_name, fn, pf_args)
-        out = fn(*pf_args)
+        out = self._dispatch(
+            "run_prefill" if final else "run_prefill_decode", fn, pf_args)
         req.work_done += n
         self.metrics.record_prefill(n)
         if final:
             # ONE batched fetch: the sampled token and the non-finite-
             # logits detector travel together (no extra host sync)
-            fetched = jax.device_get((out[-2], out[-1]))
+            fetched = self._fetch((out[-2], out[-1]), bucket=bucket)
             self._rebind(out[:-2])
             first = int(np.asarray(fetched[0]).reshape(-1)[req.shard])
             ok = bool(np.asarray(fetched[1]).reshape(-1)[req.shard])
@@ -1776,15 +1821,13 @@ class InferenceEngine:
                 self._memacct, "spec_verify", self._spec, spec_args,
                 expect_label="serving draft-verify step: donated "
                 "in-place KV block pool + argmax continuations")
-        out = self._spec(self.params, *self.pool.tensors.arrays,
-                         self._tables, self._pos, toks_in, nvalid,
-                         self._active, self._poison)
+        out = self._dispatch("run_decode", self._spec, spec_args)
         self._rebind(out[:-2])
         chaos.serving_kill_step(self._step_idx)
         chaos.fleet_kill_replica_step(self._replica_index, self._step_idx)
         # ONE batched fetch per step: K+1 argmax tokens per lane + the
         # per-lane finiteness detector travel together
-        outs, fins = jax.device_get((out[-2], out[-1]))
+        outs, fins = self._fetch((out[-2], out[-1]), lanes=len(running))
         outs = np.asarray(outs)
         fins = np.asarray(fins)
         self._poison[:] = 0.0
@@ -1886,7 +1929,7 @@ class InferenceEngine:
                 decode_args,
                 expect_label="serving decode step: donated-in-place KV "
                 "block pool + sampled tokens")
-        out = self._decode(*decode_args)
+        out = self._dispatch("run_decode", self._decode, decode_args)
         self._rebind(out[:-2])
         # kill-mid-decode chaos: the dispatch happened, NO host
         # bookkeeping has — the journal holds the last committed step
@@ -1894,7 +1937,7 @@ class InferenceEngine:
         chaos.fleet_kill_replica_step(self._replica_index, self._step_idx)
         # ONE batched fetch per step: sampled tokens + per-lane
         # finiteness (the poison detector) travel together
-        toks, fins = jax.device_get((out[-2], out[-1]))
+        toks, fins = self._fetch((out[-2], out[-1]), lanes=lanes)
         toks = np.asarray(toks)
         fins = np.asarray(fins)
         # one-step injection, reset only AFTER the fetch: the CPU

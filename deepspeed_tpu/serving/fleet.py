@@ -1081,9 +1081,8 @@ class FleetRouter:
             rep["trace"] = tel.tracer.summary()
         return rep
 
-    def export_trace(self, path, complete_events=True):
+    def export_trace(self, path):
         tr = self._tracer
         if tr is None:
             return None
-        return tr.export_chrome_trace(path,
-                                      complete_events=complete_events)
+        return tr.export_chrome_trace(path)

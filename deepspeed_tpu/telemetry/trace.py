@@ -1,13 +1,20 @@
 """Host-side span tracer: preallocated ring buffer, Chrome-trace export.
 
-The hot path is numpy/stdlib only (the graftlint host-sync bar): one
-clock read at ``begin()``, one clock read plus a handful of scalar array
-writes at ``complete()``/``instant()``.  Nothing here ever touches a
-device, forces a transfer, or allocates per event — the event payload is
-five preallocated numpy columns (timestamp, duration, interned name id,
-lane id, two integer args) written at a wrapping ring index under a
-lock (the async checkpoint-commit thread and the training thread share
-one tracer).
+The hot path never touches a device or forces a transfer (the graftlint
+host-sync bar): one clock read at ``begin()``, one clock read plus a
+handful of scalar array writes at ``complete()``/``instant()``.  The
+event payload is five preallocated numpy columns (timestamp, duration,
+interned name id, lane id, two integer args) written at a wrapping ring
+index under a lock (the async checkpoint-commit thread and the training
+thread share one tracer).
+
+``span()`` opens a span that is two things at once: the ring event, and
+a ``jax.profiler.TraceAnnotation`` named ``dstpu:<lane>/<name>``, begun
+together and ended together by ``Span.end()``.  Inside a profiler
+session that puts the program's span on the device trace's clock, on
+the calling thread's line above the device's op line; outside one the
+annotation is a flag test and one small object.  It is host-side too: a
+``TraceMe``, no device call.
 
 Disarmed is exactly free: engines hold ``self._tracer = None`` and every
 instrumentation site is a single attribute-load-and-``is None`` branch —
@@ -20,8 +27,7 @@ logical actor — the training engine emits on ``train``/``ckpt`` lanes,
 the PipelineEngine interpreter on one ``stage<N>`` lane per physical
 stage (so an exported trace *renders* the 1F1B/interleaved/ZB schedule),
 the serving engine on ``serve``.  Spans export as complete ``"X"``
-events by default or as matched ``"B"``/``"E"`` pairs
-(``complete_events=False``); instants as ``"i"``.
+events, instants as ``"i"``.
 
 ``lane_utilization(events)`` computes measured per-lane busy/idle
 fractions from an event list — the wall-clock side of the
@@ -41,6 +47,32 @@ _PH_INSTANT = 1
 
 DEFAULT_CAPACITY = 65536
 MIN_CAPACITY = 256
+ANNOTATION_PREFIX = "dstpu:"
+
+
+class Span:
+    """One open span of :meth:`Tracer.span`.  ``t0`` is its start on the
+    tracer's clock; ``end()`` records the ring event, leaves the
+    profiler annotation and returns the end time, so that the next span
+    can begin at the very same instant."""
+
+    __slots__ = ("_tracer", "name", "lane", "t0", "_annotation")
+
+    def __init__(self, tracer, name, lane, t0, annotation):
+        self._tracer = tracer
+        self.name = name
+        self.lane = lane
+        self.t0 = t0
+        self._annotation = annotation
+
+    def end(self, a0=-1, a1=-1, at=None):
+        tr = self._tracer
+        if at is None:
+            at = tr.clock()
+        tr._record(_PH_SPAN, self.name, self.lane, self.t0, at - self.t0,
+                   a0, a1)
+        self._annotation.__exit__(None, None, None)
+        return at
 
 
 class Tracer:
@@ -69,6 +101,10 @@ class Tracer:
         self._lanes = []                # id -> lane name
         self._lane_ids = {}             # lane name -> id
         self._lock = threading.Lock()
+        # resolved when a tracer is armed, never on a disarmed path
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
 
     # -- interning ------------------------------------------------------
     def lane(self, name):
@@ -103,6 +139,16 @@ class Tracer:
     def complete(self, name, lane, t0, a0=-1, a1=-1):
         """Record one finished span [t0, now] on ``lane``."""
         self._record(_PH_SPAN, name, lane, t0, self.clock() - t0, a0, a1)
+
+    def span(self, name, lane, t0=None):
+        """Open a span in the ring AND in the profiler's trace (module
+        docstring); ``t0`` hands over the instant a previous span ended
+        at.  Close it with :meth:`Span.end`, also from another call."""
+        # a TraceAnnotation begins when it is made: __enter__ adds nothing
+        annotation = self._annotate(
+            f"{ANNOTATION_PREFIX}{self._lanes[lane]}/{name}")
+        return Span(self, name, lane, self.clock() if t0 is None else t0,
+                    annotation)
 
     def instant(self, name, lane, a0=-1, a1=-1):
         """Record a zero-duration marker event."""
@@ -179,14 +225,13 @@ class Tracer:
             args[labels[1] if len(labels) > 1 else "a1"] = int(a1)
         return args
 
-    def export_chrome_trace(self, path, pid=0, complete_events=True,
+    def export_chrome_trace(self, path, pid=0,
                             process_name="deepspeed_tpu"):
         """Write the retained events as Chrome-trace-event JSON (loadable
         in chrome://tracing and Perfetto).  Spans become complete ``X``
-        events, or matched ``B``/``E`` pairs with
-        ``complete_events=False``; instants become ``i`` with thread
-        scope.  The write is atomic (temp file + rename) so a crash
-        mid-export never leaves a torn trace.  Returns ``path``."""
+        events, instants ``i`` with thread scope.  The write is atomic
+        (temp file + rename) so a crash mid-export never leaves a torn
+        trace.  Returns ``path``."""
         with self._lock:
             n = min(self._n, self.capacity)
             start = self._n - n
@@ -212,16 +257,10 @@ class Tracer:
                 if self._ph[i] == _PH_INSTANT:
                     trace_events.append(dict(base, ph="i", s="t",
                                              ts=round(ts_us, 3)))
-                elif complete_events:
+                else:
                     trace_events.append(dict(
                         base, ph="X", ts=round(ts_us, 3),
                         dur=round(self._dur[i] * 1e6, 3)))
-                else:
-                    trace_events.append(dict(base, ph="B",
-                                             ts=round(ts_us, 3)))
-                    trace_events.append({
-                        "ph": "E", "pid": pid, "tid": int(self._lane[i]),
-                        "ts": round(ts_us + self._dur[i] * 1e6, 3)})
             payload = {"traceEvents": trace_events,
                        "displayTimeUnit": "ms",
                        "otherData": {"dropped_events": self.dropped}}

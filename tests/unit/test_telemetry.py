@@ -95,24 +95,7 @@ def test_chrome_export_schema_x_events(tmp_path):
     inst = [e for e in evs if e["ph"] == "i"]
     assert len(inst) == 1 and inst[0]["s"] == "t"
     for e in evs:
-        assert {"ph", "name", "pid", "tid"} <= set(e) or e["ph"] == "E"
-
-
-def test_chrome_export_matched_be_pairs(tmp_path):
-    tr = Tracer(capacity=256)
-    lane = tr.lane("l")
-    for _ in range(5):
-        t0 = tr.begin()
-        tr.complete("op", lane, t0)
-    path = tr.export_chrome_trace(str(tmp_path / "be.json"),
-                                  complete_events=False)
-    with open(path) as f:
-        evs = json.load(f)["traceEvents"]
-    b = [e for e in evs if e["ph"] == "B"]
-    e_ = [e for e in evs if e["ph"] == "E"]
-    assert len(b) == len(e_) == 5       # matched B/E spans
-    for bb, ee in zip(b, e_):
-        assert ee["ts"] >= bb["ts"] and ee["tid"] == bb["tid"]
+        assert {"ph", "name", "pid", "tid"} <= set(e)
 
 
 def test_lane_utilization_measured_idle():
@@ -546,8 +529,8 @@ def test_serving_telemetry_report_and_zero_recompiles(serving_toy,
     assert rep["mfu"]["per_jit"]["decode_step"]["flops"] > 0
     assert rep["mfu"]["mfu"] is not None and rep["mfu"]["mfu"] > 0
     names = {e["name"] for e in eng.telemetry.tracer.events()}
-    assert {"serving_step", "decode_step", "prefill_tick",
-            "deadline_sweep", "admit"} <= names
+    assert {"serving_step", "decode_step", "prefill_tick", "admit",
+            "host_gap", "run_decode", "run_prefill"} <= names
     rows = MetricsStream.replay(path)
     assert rows and all("queue_depth" in r for r in rows)
     assert eng.export_trace(str(tmp_path / "s.json"))

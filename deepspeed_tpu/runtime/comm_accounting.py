@@ -602,7 +602,8 @@ def serving_kv_handoff_collectives(
     replica's pool to a decode replica's.  The payload is exactly the
     request's allocated blocks in the pool layout that already
     round-trips through checkpoints — ``blocks`` blocks of
-    ``(n_layer, n_head, block_size, head_dim)`` rows for K and V each
+    ``(n_layer, block_size, n_head * head_dim)`` rows for K and V each
+    (``serving.kv_cache.pool_shapes``)
     (the fixed-width page-table padding is an implementation detail of
     the fixed-shape gather, not wire payload).  int8 pools move int8
     payloads plus the per-(token, head) f32 scale rows, matching

@@ -245,10 +245,10 @@ def kv_pool_bytes(n_layer: int, num_blocks: int, n_head: int,
                   kv_dtype="bfloat16", quantized: bool = False,
                   shards: int = 1, shared_blocks: int = 0,
                   shared_refs: int = 1) -> int:
-    """Per-shard device bytes of the serving paged KV pool: k + v of
-    ``(L, num_blocks/shards, H, block_size, D)`` (int8 when quantized,
-    else ``kv_dtype``) plus the two fp32 per-(token, head)-row scale
-    tensors int8 storage carries.  THE builder both
+    """Per-shard device bytes of the serving paged KV pool: k + v in the
+    shapes of ``serving.kv_cache.pool_shapes`` with ``num_blocks/shards``
+    blocks (int8 when quantized, else ``kv_dtype``) plus the two fp32
+    per-(token, head) scale tensors int8 storage carries.  THE builder both
     ``PagedKVPool.stats()`` and the serving ``memory_report()`` price
     the pool through — byte-exact against the allocated arrays.
 

@@ -81,37 +81,41 @@ def _slot_key(seed, pos):
 
 
 def _pool_write(pool, scales, l, blk, off, rows, quantized):
-    """Scatter one K or V row per (lane, head) into the block pool.
-    rows: (N, H, D); blk/off: (N,) local block id / in-block offset.
-    Masked lanes arrive with blk == TRASH_BLOCK and land in the trash
-    block — the scatter itself is always dense."""
+    """Scatter one token row per lane into the block pool
+    (``kv_cache.pool_shapes``: index dims major, the row minor).
+    rows: (N, H, D), stored as (N, H*D) at ``[l, blk, off]``; blk/off:
+    (N,) local block id / in-block offset.  Quantized: one scale per
+    (token, head), the (N, H) scale rows land at the same index.  Masked
+    lanes arrive with blk == TRASH_BLOCK and land in the trash block —
+    the scatter itself is always dense."""
     N, H, D = rows.shape
+    rows = rows.reshape(N, H * D)
     if quantized:
-        q, s = quantize_rows(rows.reshape(N * H, D), block_size=D)
-        pool = pool.at[l, blk, :, off, :].set(q.reshape(N, H, D))
-        scales = scales.at[l, blk, :, off].set(
-            s.reshape(N, H).astype(jnp.float32))
+        q, s = quantize_rows(rows, block_size=D)         # (N, H*D), (N, H)
+        pool = pool.at[l, blk, off].set(q)
+        scales = scales.at[l, blk, off].set(s.astype(jnp.float32))
     else:
-        pool = pool.at[l, blk, :, off, :].set(rows.astype(pool.dtype))
+        pool = pool.at[l, blk, off].set(rows.astype(pool.dtype))
     return pool, scales
 
 
-def _pool_view(pool, scales, l, tables, quantized, out_dtype):
+def _pool_view(pool, scales, l, tables, n_head, quantized, out_dtype):
     """Gather per-sequence page views back to contiguous position order:
-    (B, W) tables over (L, NB, H, bs, D) pool -> (B, H, W*bs, D).  View
-    position j IS absolute sequence position j, so the attention mask of
-    the contiguous cache applies unchanged."""
+    (B, W) tables over the (L, NB, bs, H*D) pool -> (B, H, W*bs, D).
+    Only the GATHERED pages are reshaped and transposed, never the pool.
+    View position j IS absolute sequence position j, so the attention
+    mask of the contiguous cache applies unchanged.  (``pool[l]`` is
+    still sliced out before the gather; one gather ``pool[l, tables]``
+    is measured and waiting: ROADMAP.md S3d.)"""
     B, W = tables.shape
-    _, _, H, bs, D = pool.shape
-    g = pool[l][tables.reshape(-1)]
-    g = g.reshape(B, W, H, bs, D).transpose(0, 2, 1, 3, 4) \
-         .reshape(B, H, W * bs, D)
-    if not quantized:
-        return g
-    s = scales[l][tables.reshape(-1)].reshape(B, W, H, bs) \
-        .transpose(0, 2, 1, 3).reshape(B * H * W * bs, 1)
-    return dequantize_rows(g.reshape(B * H * W * bs, D), s, D,
-                           out_dtype).reshape(B, H, W * bs, D)
+    _, _, bs, HD = pool.shape
+    H, D = n_head, HD // n_head
+    g = pool[l][tables.reshape(-1)]                      # (B*W, bs, H*D)
+    if quantized:
+        s = scales[l][tables.reshape(-1)]                # (B*W, bs, H)
+        g = dequantize_rows(g.reshape(B * W * bs, HD),
+                            s.reshape(B * W * bs, H), HD, out_dtype)
+    return g.reshape(B, W * bs, H, D).transpose(0, 2, 1, 3)
 
 
 def _paged_forward(params, cfg, pools, tables, pos, maxpos, blk, off, x,
@@ -148,7 +152,7 @@ def _paged_forward(params, cfg, pools, tables, pos, maxpos, blk, off, x,
     B, T, _ = x.shape
     H, D = cfg.n_head, cfg.head_dim
     W = tables.shape[1]
-    bs = pk.shape[3]
+    bs = pk.shape[2]
     if sparse is None:
         gtables = tables
         validj = (jnp.arange(W * bs)[None, :]
@@ -175,8 +179,8 @@ def _paged_forward(params, cfg, pools, tables, pos, maxpos, blk, off, x,
         vt = v.reshape(B * T, H, D)
         pk, ksc = _pool_write(pk, ksc, l, blk, off, kt, quantized)
         pv, vsc = _pool_write(pv, vsc, l, blk, off, vt, quantized)
-        kview = _pool_view(pk, ksc, l, gtables, quantized, x.dtype)
-        vview = _pool_view(pv, vsc, l, gtables, quantized, x.dtype)
+        kview = _pool_view(pk, ksc, l, gtables, H, quantized, x.dtype)
+        vview = _pool_view(pv, vsc, l, gtables, H, quantized, x.dtype)
         kview = jnp.where(validk, kview, 0)
         vview = jnp.where(validk, vview, 0)
         a = _attn_core(q, kview, vview, validj, bp["attn"], x.dtype)
@@ -1139,9 +1143,10 @@ class InferenceEngine:
         row = self.pool.global_table_row(rid, self.W)
         n_blocks = len(self.pool._blocks[rid])
         n_positions = self.pool._positions[rid]
-        # one fixed-shape gather + ONE batched fetch: (L, W, H, bs, D)
-        # per pool tensor, trash-padded rows included (their content is
-        # garbage by contract; the value mask keeps it inert)
+        # one fixed-shape gather + ONE batched fetch: per pool tensor its
+        # ``kv_cache.pool_shapes`` shape with W blocks on the block axis,
+        # trash-padded rows included (their content is garbage by
+        # contract; the value mask keeps it inert)
         kv = jax.device_get(tuple(
             a[:, row] for a in self.pool.tensors.arrays))
         slot = req.slot

@@ -10,8 +10,14 @@ ordered page table of block ids, and the pool arrays are DONATED into
 the decode jit and updated in place — steady-state decode allocates no
 device memory at all.
 
-Layout: ``k``/``v`` are ``(L, num_blocks, H, block_size, D)``; the
-gathered per-sequence view reassembles ``(H, W*block_size, D)`` in
+Layout (:func:`pool_shapes`, the one place that states it): ``k``/``v``
+are ``(L, num_blocks, block_size, H*D)`` — the dims a write indexes
+(layer, block, in-block offset) are major and one token's row, its H
+heads of D side by side, is minor.  That is the layout the TPU compiler
+runs the per-layer scatter and page gather in, so the donated pool is
+updated in place and no program relays it (H*D = 1024 fills 8 lane
+tiles; a minor dim of D = 64 would pad every row to 128).  The gathered
+per-sequence view reassembles ``(H, W*block_size, D)`` in
 absolute-position order, so the attention math (shared
 ``generation._attn_core``) is bit-identical to the contiguous cache.
 
@@ -22,7 +28,8 @@ scatter in the jit fully dense — no branches, no recompiles.
 Optional int8 storage (``quantize_kv=True``) stores one symmetric scale
 per (token, head) row via runtime/quantization.py's row quantizers —
 per-row layout = ``block_layout(D, D)`` so the scale tensor is exactly
-``(L, num_blocks, H, block_size)`` f32.  Arming follows the repo's
+``(L, num_blocks, block_size, H)`` f32 (the same rule: index dims major,
+one token's H scales minor).  Arming follows the repo's
 DISARMED discipline: when the configuration cannot profit (scale
 overhead >= byte savings, or an unsupported pool dtype) the pool warns
 loudly naming the blocker and serves full-precision instead.
@@ -78,6 +85,18 @@ def _cow_copy_rows(arrs, src, dst):
     ONE compiled program per pool shape — block churn never recompiles —
     and the donated input keeps the copy allocation-free on the pool."""
     return tuple(a.at[:, dst].set(a[:, src]) for a in arrs)
+
+
+def pool_shapes(cfg, num_blocks, block_size, quantized):
+    """The pool's four shapes ``(k, v, k_scale, v_scale)``; the scales
+    are None unless ``quantized``.  One rule for all four: the dims a
+    write indexes — layer, block, offset in the block — are major, one
+    token's row is minor (its ``n_head * head_dim`` values; its
+    ``n_head`` scales).  Every reader of the layout asks here."""
+    L, H, D = cfg.n_layer, cfg.n_head, cfg.head_dim
+    kv = (L, int(num_blocks), int(block_size), H * D)
+    scale = (L, int(num_blocks), int(block_size), H) if quantized else None
+    return kv, kv, scale, scale
 
 
 class PoolTensors(NamedTuple):
@@ -150,27 +169,20 @@ class PagedKVPool:
         # report to; None (standalone pools) skips registration
         self.programs = None
 
-        L, H, D = cfg.n_layer, cfg.n_head, cfg.head_dim
-        bs = self.block_size
-        kv_shape = (L, self.num_blocks, H, bs, D)
         store = jnp.int8 if self.quantized else self.dtype
-        k = jnp.zeros(kv_shape, store)
-        v = jnp.zeros(kv_shape, store)
-        sk = sv = None
-        if self.quantized:
-            sk = jnp.zeros((L, self.num_blocks, H, bs), jnp.float32)
-            sv = jnp.zeros((L, self.num_blocks, H, bs), jnp.float32)
+        tensors = [
+            None if shape is None else jnp.zeros(shape, dtype)
+            for shape, dtype in zip(
+                pool_shapes(cfg, self.num_blocks, self.block_size,
+                            self.quantized),
+                (store, store, jnp.float32, jnp.float32))]
         if mesh is not None and shards > 1:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            put = lambda t, spec: jax.device_put(
-                t, NamedSharding(mesh, spec))
-            k = put(k, P(None, axis_name))
-            v = put(v, P(None, axis_name))
-            if self.quantized:
-                sk = put(sk, P(None, axis_name))
-                sv = put(sv, P(None, axis_name))
-        self.tensors = PoolTensors(k, v, sk, sv)
+            split = NamedSharding(mesh, P(None, axis_name))   # block axis
+            tensors = [None if t is None else jax.device_put(t, split)
+                       for t in tensors]
+        self.tensors = PoolTensors(*tensors)
 
         # host-side allocator: per-shard sorted free lists (popping the
         # smallest id keeps runs deterministic), local block ids — the

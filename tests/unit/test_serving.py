@@ -22,7 +22,7 @@ from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.runtime.resilience import chaos
 from deepspeed_tpu.runtime.resilience.watchdog import TrainingWatchdog
 from deepspeed_tpu.serving.engine import InferenceEngine
-from deepspeed_tpu.serving.kv_cache import PagedKVPool
+from deepspeed_tpu.serving.kv_cache import PagedKVPool, pool_shapes
 from deepspeed_tpu.serving.metrics import CompilationCounter, ServingMetrics
 from deepspeed_tpu.serving.scheduler import Request, Scheduler
 
@@ -327,6 +327,110 @@ def test_int8_kv_disarms_when_unprofitable(caplog):
     assert not pool.quantized
     assert any("DISARMED" in r.message for r in caplog.records)
     assert pool.tensors.k.dtype == jnp.bfloat16
+
+
+# ---------------------------------------------------------------------------
+# the pool's layout (kv_cache.pool_shapes: index dims major, token row minor)
+# ---------------------------------------------------------------------------
+
+_LAYOUT_CFG = GPT2Config(vocab_size=32, n_positions=32, n_embd=32, n_layer=2,
+                         n_head=4, dtype=jnp.bfloat16, loss_chunk_tokens=0)
+
+
+def _layout_round_trip(quantized):
+    """``_pool_write`` then ``_pool_view`` against a plain dictionary
+    {(layer, block, offset): the token's (H, D) row}."""
+    from deepspeed_tpu.serving.engine import _pool_view, _pool_write
+
+    cfg = _LAYOUT_CFG
+    L, H, D, NB, bs = cfg.n_layer, cfg.n_head, cfg.head_dim, 7, 4
+    pool = PagedKVPool(cfg, num_blocks=NB, block_size=bs,
+                       quantize_kv=quantized)
+    assert pool.quantized == quantized
+    assert tuple(t.shape for t in pool.tensors.arrays) == tuple(
+        sh for sh in pool_shapes(cfg, NB, bs, quantized) if sh is not None)
+    rng = np.random.default_rng(5)
+    k, scales = pool.tensors.k, pool.tensors.k_scale
+    written = {}
+    for l in range(L):
+        # five tokens a layer, scattered over blocks and offsets
+        cells = rng.permutation(NB * bs)[:5]
+        blk, off = cells // bs, cells % bs
+        rows = rng.normal(size=(5, H, D)).astype(np.float32)
+        k, scales = _pool_write(k, scales, l, jnp.asarray(blk),
+                                jnp.asarray(off), jnp.asarray(rows),
+                                quantized)
+        for b, o, row in zip(blk, off, rows):
+            written[l, int(b), int(o)] = row
+    tables = jnp.asarray(rng.permutation(NB)[:6].reshape(2, 3))   # (B, W)
+    for l in range(L):
+        view = np.asarray(_pool_view(k, scales, l, tables, H, quantized,
+                                     jnp.float32), np.float32)
+        assert view.shape == (2, H, 3 * bs, D)
+        for b, w, o in np.ndindex(2, 3, bs):
+            got = view[b, :, w * bs + o, :]                       # (H, D)
+            row = written.get((l, int(tables[b, w]), o))
+            if row is None:
+                assert not got.any()
+            elif quantized:
+                # one symmetric scale per (token, head), as stored
+                scale = np.abs(row).max(axis=-1, keepdims=True) / 127.0
+                np.testing.assert_array_equal(
+                    np.asarray(scales[l, int(tables[b, w]), o]),
+                    scale[:, 0])
+                np.testing.assert_allclose(
+                    got, np.round(row / scale) * scale, rtol=1e-6)
+            else:
+                np.testing.assert_array_equal(
+                    got, np.asarray(jnp.asarray(row, jnp.bfloat16),
+                                    np.float32))
+
+
+def _layout_handoff(toy):
+    """A payload exported from one engine holds each pool tensor's W
+    gathered blocks in the pool's own layout, and the engine that imports
+    it goes on to the same tokens."""
+    model, params, ref = toy
+    eng_a, eng_b = _engine(model, params), _engine(model, params)
+    prompt = _prompts(31, (9,))[0]
+    rid = eng_a.submit(prompt, max_new_tokens=10)
+    for _ in range(4):
+        eng_a.step()
+    entry = eng_a.export_request(rid)
+    assert tuple(part.shape for part in entry["kv"]) == pool_shapes(
+        model.config, eng_a.W, eng_a.bs, False)[:2]
+    assert eng_b.import_request(entry) == "adopted"
+    res = eng_b.serve(max_steps=200)
+    np.testing.assert_array_equal(res[rid]["tokens"], ref(prompt, 10))
+
+
+def _layout_cow_split():
+    """The COW split copies one block of every pool tensor, scales
+    included, and only that block."""
+    pool = PagedKVPool(_LAYOUT_CFG, num_blocks=6, block_size=4,
+                       quantize_kv=True)
+    rng = np.random.default_rng(6)
+    before = [rng.integers(-100, 100, t.shape).astype(t.dtype)
+              for t in pool.tensors.arrays]
+    pool.tensors = type(pool.tensors)(*(jnp.asarray(b) for b in before))
+    pool._cow_copy(0, 2, 4)
+    for was, now in zip(before, pool.tensors.arrays):
+        want = was.copy()
+        want[:, 4] = was[:, 2]
+        np.testing.assert_array_equal(np.asarray(now), want)
+
+
+LAYOUT_CASES = {
+    "round-trip-bf16": lambda toy: _layout_round_trip(quantized=False),
+    "round-trip-int8": lambda toy: _layout_round_trip(quantized=True),
+    "handoff": _layout_handoff,
+    "cow-split": lambda toy: _layout_cow_split(),
+}
+
+
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_pool_layout(case, toy):
+    LAYOUT_CASES[case](toy)
 
 
 # ---------------------------------------------------------------------------

@@ -168,6 +168,87 @@ def test_kernels_compile_inside_a_four_chip_program(kernel, v5e_host,
         assert _kernels_in(_grad(attn), args) == 3
 
 
+SERVE_CHAT = dict(slots=28, pages=64, block=16, chunk=256)   # the chat cell
+
+
+@pytest.mark.parametrize("pool", [
+    "bf16",
+    pytest.param("int8", marks=pytest.mark.xfail(
+        strict=True,
+        reason="the int8 K and V are updated in place, but both f32 scale "
+               "tensors (minor dim 16 heads, padded to 128 lanes) are "
+               "relaid on the way in and out: PERF.md section 7"))])
+@pytest.mark.parametrize("program", ["decode", "prefill256",
+                                     "prefill256-final"])
+def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
+    """The KV pool's layout (``kv_cache.pool_shapes``) is the one the
+    compiler runs the per-layer scatter and page gather in: at the chat
+    cell's sizes and gpt2-350m widths no program relays a pool tensor (no
+    ``copy`` of a pool tensor's shape), the temporaries stay under ONE
+    pool tensor and the donated pool comes back in the same buffers.  The
+    bf16 pool is compiled at the cell's 24 layers, because the temporaries
+    hold the weights' bf16 casts and so grow with depth as the pool does;
+    the int8 pool, which no cell runs, at 2 layers and for the copies
+    alone (they sit at a program's edge, whatever the depth)."""
+    import re
+
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving.kv_cache import pool_shapes
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs, C = (SERVE_CHAT[k] for k in ("slots", "pages", "block",
+                                           "chunk"))
+    quantized = pool == "int8"
+    model = GPT2Model(GPT2Config(
+        vocab_size=50257, n_positions=1024, n_embd=1024,
+        n_layer=2 if quantized else 24, n_head=16, dtype=jnp.bfloat16,
+        scan_layers=True))
+    cfg = model.config
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ids = np.zeros((1, 8), np.int32)
+    params = jax.tree_util.tree_map(
+        lambda l: struct(l.shape, l.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       {"input_ids": ids, "labels": ids}))
+    store = jnp.int8 if quantized else cfg.dtype
+    tensors = [struct(shape, dtype) for shape, dtype in
+               zip(pool_shapes(cfg, 1 + S * W, bs, quantized),
+                   (store, store, jnp.float32, jnp.float32))
+               if shape is not None]
+    if program == "decode":
+        jitted = serving._make_decode_step(cfg, W, bs, quantized, 0.0, 0,
+                                           0.0, None, "data")
+        streams = [struct((S, W), jnp.int32), struct((S,), jnp.int32),
+                   struct((S,), jnp.int32), struct((S,), jnp.bool_),
+                   struct((S,), jnp.int32), struct((S,), jnp.float32)]
+    else:
+        jitted = serving._make_prefill_chunk(
+            cfg, C, W, bs, quantized, program.endswith("final"), 0.0, 0,
+            0.0, None, "data")
+        streams = [struct((1, W), jnp.int32), struct((C,), jnp.int32),
+                   struct((), jnp.int32), struct((1,), jnp.int32),
+                   struct((), jnp.int32)]
+    compiled = jitted.lower(params, *tensors, *streams).compile()
+    text = compiled.as_text()
+
+    shapes = {",".join(str(d) for d in t.shape) for t in tensors}
+    relays = [line.strip()[:120] for line in text.splitlines()
+              if any(re.search(rf"= \w+\[{dims}\]\S* copy\(", line)
+                     for dims in shapes)]
+    assert not relays, f"{program} relays the {pool} pool: {relays}"
+    if quantized:
+        return
+    one_pool_tensor = tensors[0].size * tensors[0].dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool_tensor
+    # the pool tensors are the leading outputs; parameter numbers shift
+    # with the weights a program leaves unused, output numbers do not
+    assert hc.aliased_outputs(text) >= set(range(len(tensors)))
+
+
 def test_lut_beyond_smem_fails_at_trace_time():
     """The reference's default block 16 at S 4096: the transpose LUT of the
     dk/dv sweep needs 3 MiB of scalar memory.  That must surface while

@@ -1,7 +1,7 @@
 """GPT-2 for the benchmark: how to build the program's model from a
-configuration file, the plain reference the program is held to, and the
-arithmetic (operations per token, bytes per decode step) the utilisation
-metrics divide by.
+configuration file, the plain reference the program is held to, the rule
+its served tokens are held by, and the arithmetic (operations per token,
+bytes per decode step) the utilisation metrics divide by.
 
 The reference follows the published description (Radford et al. 2019 and
 the ``openai-community/gpt2*`` config.json keys): learned token and position
@@ -90,42 +90,81 @@ def _gelu_new(x):
         np.sqrt(2.0 / np.pi) * (x + 0.044715 * x ** 3)))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _ref_block(x, p, n_head, eps):
+def _kept(bits):
+    """What the control does to every activation and weight a matmul reads
+    or writes: round it to ``bits`` significand bits.  None: nothing, the
+    reference itself."""
+    if bits is None:
+        return lambda x: x
+
+    def keep(x):
+        m, e = jnp.frexp(x)
+        return jnp.ldexp(jnp.round(m * 2.0 ** bits) / 2.0 ** bits, e)
+    return keep
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _ref_block(x, p, n_head, eps, bits):
     """One pre-LN block over (B, S, E) float32."""
+    keep = _kept(bits)
+
+    def dense(h, layer):
+        return keep(keep(h) @ keep(layer["kernel"]) + layer["bias"])
+
     with jax.default_matmul_precision("highest"):
         B, S, E = x.shape
         D = E // n_head
-        h = _layer_norm(x, p["ln_1"], eps)
-        qkv = h @ p["attn"]["c_attn"]["kernel"] + p["attn"]["c_attn"]["bias"]
+        qkv = dense(_layer_norm(x, p["ln_1"], eps), p["attn"]["c_attn"])
         q, k, v = (t.reshape(B, S, n_head, D).transpose(0, 2, 1, 3)
                    for t in jnp.split(qkv, 3, axis=-1))
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(D)
+        scores = keep(jnp.einsum("bhqd,bhkd->bhqk", q, k)) / np.sqrt(D)
         causal = jnp.tril(jnp.ones((S, S), bool))
         scores = jnp.where(causal, scores, -jnp.inf)
-        a = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, axis=-1), v)
+        a = keep(jnp.einsum("bhqk,bhkd->bhqd",
+                            keep(jax.nn.softmax(scores, axis=-1)), v))
         a = a.transpose(0, 2, 1, 3).reshape(B, S, E)
-        x = x + a @ p["attn"]["c_proj"]["kernel"] + p["attn"]["c_proj"]["bias"]
-        h = _layer_norm(x, p["ln_2"], eps)
-        h = _gelu_new(h @ p["mlp"]["c_fc"]["kernel"] + p["mlp"]["c_fc"]["bias"])
-        return x + h @ p["mlp"]["c_proj"]["kernel"] + p["mlp"]["c_proj"]["bias"]
+        x = x + dense(a, p["attn"]["c_proj"])
+        h = _gelu_new(dense(_layer_norm(x, p["ln_2"], eps), p["mlp"]["c_fc"]))
+        return x + dense(h, p["mlp"]["c_proj"])
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _ref_head(x, ln_f, wte, eps):
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _ref_head(x, ln_f, wte, eps, bits):
+    keep = _kept(bits)
     with jax.default_matmul_precision("highest"):
-        return _layer_norm(x, ln_f, eps) @ wte.T
+        return keep(_layer_norm(x, ln_f, eps)) @ keep(wte).T
 
 
-def reference_logits(weights, config, ids):
-    """(B, S) token ids -> (B, S, vocab_size) float32 logits."""
+# rows of the head computed in one call: one compiled shape whatever number
+# of rows a request asks for, and 128 x vocab_size logits at a time
+_HEAD_ROWS = 128
+
+
+def reference_logits(weights, config, ids, rows=None, control_bits=None):
+    """(B, S) token ids -> float32 logits: (B, S, vocab_size) with
+    ``rows=None``, else (B, len(rows), vocab_size), the head applied to the
+    positions ``rows`` and to no others, ``_HEAD_ROWS`` of them at a time.
+    ``control_bits``: not the reference but its control, every matmul's
+    inputs and result rounded to that many significand bits (4: about fp8,
+    the nearest precision under the bf16 the configuration states), which
+    the rule of ``served_check`` has to refuse."""
     ids = jnp.asarray(ids, jnp.int32)
     x = weights["wte"][ids] + weights["wpe"][None, :ids.shape[1]]
     for l in range(config["n_layer"]):
         x = _ref_block(x, weights["layer"](l), config["n_head"],
-                       config["layer_norm_epsilon"])
-    return _ref_head(x, weights["ln_f"], weights["wte"],
-                     config["layer_norm_epsilon"])
+                       config["layer_norm_epsilon"], control_bits)
+
+    def head(x):
+        return _ref_head(x, weights["ln_f"], weights["wte"],
+                         config["layer_norm_epsilon"], control_bits)
+
+    if rows is None:
+        return head(x)
+    rows = np.asarray(rows)
+    padded = np.resize(rows, -(-len(rows) // _HEAD_ROWS) * _HEAD_ROWS)
+    blocks = [head(x[:, padded[i:i + _HEAD_ROWS]])
+              for i in range(0, len(padded), _HEAD_ROWS)]
+    return jnp.concatenate(blocks, axis=1)[:, :len(rows)]
 
 
 def reference_loss(weights, config, ids):
@@ -135,6 +174,46 @@ def reference_loss(weights, config, ids):
     nll = -jnp.take_along_axis(
         logp, jnp.asarray(ids, jnp.int32)[:, 1:, None], axis=-1)
     return float(jnp.mean(nll))
+
+
+# ---------------------------------------------------------------------------
+# the rule for served tokens
+# ---------------------------------------------------------------------------
+# After chip_smoke.py's rule.  The served path computes in bf16 (8
+# significand bits), the reference in f32, and with random weights the two
+# best logits of a row are often one bf16 spacing apart, so tokens cannot be
+# compared for equality.  Instead: under the reference's teacher-forced
+# forward of the served sequence, every served token's logit lies within
+# ``near_best_spacings`` spacings of bf16 (at the magnitude of the row's best
+# logit: 2^-6 near 2.0) of the best logit of its row.  chip_smoke.py allows
+# 2 against the program's own bf16 forward; against f32 the served logit and
+# its rival each carry the error of 24 layers of bf16 activations as well,
+# about one spacing each: the worst of some 5,600 served tokens over seven
+# runs on the chip lay 2.12 under (PERF.md, section 6).  A wrong cache row,
+# mask or position moves a logit by tenths, tens of spacings; a token picked
+# blindly lies ~170 under; the control (``control_bits=4``) 10-20.
+def served_check(config):
+    """What the serving driver's check takes from this architecture: the
+    numbers of ``drive_serve.judge_rows``' rule with the reason for each,
+    and ``width(longest)``, the padded length at which a checked request of
+    ``longest`` tokens is run through the reference."""
+    return {
+        "rule": {"near_best_spacings": 4.0, "share": 1.0,
+                 "every_row_sigma": None},
+        "why": {
+            "near_best_spacings": "worst of ~5,600 served tokens on the "
+                                  "chip 2.12, the control's least 10.5 "
+                                  "(PERF.md section 6)",
+            "share": "dense: no layer chooses discretely, so no row has a "
+                     "reason to lie further out than the others",
+            "every_row_sigma": "idle beside share 1.0: 4 spacings are 0.1 "
+                               "of a row's logit sigma at these weights",
+        },
+        # learned positions: one padded shape for every request, the
+        # published context; causal attention keeps the padding out of the
+        # rows that count
+        "width": lambda longest: config["n_positions"],
+    }
 
 
 # ---------------------------------------------------------------------------

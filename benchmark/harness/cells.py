@@ -60,10 +60,19 @@ class Cell:
     def _reports(self, metric):
         return self.name in metric.get("workloads", [self.name])
 
+    def _own_or_shared(self, folder, name, what):
+        """``<folder>/<name>.py`` under the cell's own root, else under
+        the benchmark's: a test cell brings a stand-in that way."""
+        for root in (self.root, BENCH_DIR):
+            path = os.path.join(root, folder, name + ".py")
+            if os.path.isfile(path):
+                return load_module(path, f"bench_{what}_{name}")
+        raise FileNotFoundError(f"{what} {name!r} has no file "
+                                f"{folder}/{name}.py")
+
     def architecture(self):
-        name = self.config["architecture"]
-        return load_module(os.path.join(
-            BENCH_DIR, "architectures", name + ".py"), f"bench_arch_{name}")
+        return self._own_or_shared("architectures",
+                                   self.config["architecture"], "arch")
 
     def driver(self):
         kind = self.traffic["driver"]
@@ -71,12 +80,8 @@ class Cell:
             BENCH_DIR, "harness", f"drive_{kind}.py"), f"bench_drive_{kind}")
 
     def reader(self, metric_name):
-        for root in (self.root, BENCH_DIR):
-            path = os.path.join(root, "layer_metrics", metric_name + ".py")
-            if os.path.isfile(path):
-                return load_module(path, f"bench_metric_{metric_name}").read
-        raise FileNotFoundError(f"per-layer metric {metric_name!r} has no "
-                                f"reader layer_metrics/{metric_name}.py")
+        return self._own_or_shared("layer_metrics", metric_name,
+                                   "metric").read
 
 
 def load_benchmark(path=None):

@@ -40,9 +40,16 @@ def memory_peak_bytes(devices):
                for d in devices)
 
 
-def describe(devices):
-    """The ``device`` key of a result line, as JAX reports it."""
+def bytes_in_use(devices):
+    """Bytes in use now on the fullest of ``devices``."""
+    return max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+               for d in devices)
+
+
+def describe(devices, peak_bytes):
+    """The ``device`` key of a result line, as JAX reports it;
+    ``peak_bytes`` as the driver read it when the window had closed,
+    before the reference ran."""
     d0 = devices[0]
     return {"platform": d0.platform, "kind": d0.device_kind,
-            "count": len(devices),
-            "memory_peak_bytes": memory_peak_bytes(devices)}
+            "count": len(devices), "memory_peak_bytes": peak_bytes}

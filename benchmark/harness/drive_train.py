@@ -138,6 +138,9 @@ def run(cell, devices, *, seed, seconds, trace, process_start, log):
         "correct": all(checks.values()),
         "attempted": sum(p["steps"] for p in phases),
         "failed": non_finite,
+        "compared": {"loss_rel_err": [loss_rel_err, LOSS_RTOL],
+                     "losses_not_finite": [non_finite, 0],
+                     "compiles_in_window": [compiles.count, 0]},
         "end_to_end": {"train_tokens_per_s": tokens_per_s,
                        "setup_s": setup_s},
         "observed": {

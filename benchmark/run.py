@@ -4,8 +4,10 @@
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
-``--trace 0``, its per-layer metrics with ``--trace 1``), ``device`` and,
-traced, ``breakdown``.  Earlier lines are JSON too, and are information.
+``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``,
+traced, ``breakdown``, and last ``compared``: each number the verdict
+compared with its limit (also the last lines of standard error).  Earlier
+lines are JSON too, and are information.
 The run measures on the TPUs of the machine it is started on and on
 nothing else: without them, or without the program, it prints no result
 and exits with a code other than 0.
@@ -62,6 +64,8 @@ def result_line(cell, run, device, trace):
         line["device"]["window_s"] = reduction["window_s"]
         line["breakdown"] = {"device_ops": reduction["device_ops"],
                              "idle_gaps": reduction["idle_gaps"]}
+    # last: each number the verdict compared, [as read, its limit]
+    line["compared"] = run["compared"]
     return line
 
 
@@ -98,7 +102,10 @@ def main(argv=None):
     if run["observed"].get("trace"):
         log({"trace": {k: v for k, v in run["observed"]["trace"].items()
                        if k not in ("device_ops", "idle_gaps")}})
-    log(result_line(cell, run, device_lib.describe(devices), args.trace))
+    for name, (value, limit) in run["compared"].items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
+    log(result_line(cell, run, device_lib.describe(
+        devices, run["observed"]["memory_peak_bytes"]), args.trace))
     return 0
 
 

@@ -129,6 +129,7 @@ def rehearse_train(cell, devices):
 
 def rehearse_serve(cell, devices):
     from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving import kv_cache
 
     arch, config, mix = cell.architecture(), cell.config, cell.traffic
     model = arch.build_model(config, mix["model_overrides"])
@@ -146,8 +147,8 @@ def rehearse_serve(cell, devices):
     e = mix["engine"]
     S, bs, W, C = e["max_slots"], e["kv_block_size"], \
         e["max_blocks_per_seq"], e["prefill_chunk"]
-    blocks = 1 + S * W
-    pool = struct((cfg.n_layer, blocks, cfg.n_head, bs, cfg.head_dim),
+    # the engine's default pool: every slot's pages and the trash block
+    pool = struct(kv_cache.pool_shapes(cfg, 1 + S * W, bs, False)[0],
                   cfg.dtype)
     decode = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0,
                                        None, "data")

@@ -447,7 +447,7 @@ def _refuses_device_metrics(cell, run, devices):
     with pytest.raises(device_lib.NoDevice):
         device_lib.require_tpu(1)
     with pytest.raises(device_lib.NoDevice):
-        runner.result_line(cell, run, device_lib.describe(devices), 0)
+        runner.result_line(cell, run, device_lib.describe(devices, 0), 0)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -477,7 +477,8 @@ def test_rehearsal_serving_cell(devices, name, trace):
                                   trace=trace)
     assert run["correct"], logged
     assert run["attempted"] > 5 and run["failed"] == 0
-    assert logged["reference"]["requests_checked"] == 4
+    # the seeded sample of four, and the longest where the draw left it out
+    assert logged["reference"]["requests_checked"] in (4, 5)
     assert logged["generator_lateness_s"]["max"] >= 0
     read = {m["name"]: cell.reader(m["name"])(run["observed"])
             for m in cell.per_layer}

@@ -106,8 +106,9 @@ def test_the_parents_result_line_leaves_the_new_metrics_out(observed):
         for shaped, expected in ((_parent_shaped(traced), set()),
                                  (traced, set(new))):
             run = {"correct": True, "attempted": 1, "failed": 0,
-                   "observed": shaped}
+                   "observed": shaped, "compared": {"x": [0, 0]}}
             line = runner.result_line(cell, run, tpu, 1)
+            assert list(line)[-1] == "compared"
             assert set(line["metrics"]) & set(NEW_METRICS) == expected
             assert {"compiles_in_window", KEPT_METRIC[cell_name]} \
                 <= set(line["metrics"])

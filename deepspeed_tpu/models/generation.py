@@ -128,6 +128,57 @@ def _ffn(x, bp, cfg):
     return _dense(h, mp["c_proj"])
 
 
+class GPT2Decoder:
+    """GPT-2 under the serving engine's decoder-block contract
+    (``serving/decoder.py``), made of the functions above: learned
+    positions in the embedding, ``c_attn`` split into equal Q, K and V
+    heads, the shared masked core over the gathered page view, ``c_proj``,
+    the GELU MLP, LayerNorm and the tied head."""
+
+    stat_names = ()             # no block reports counters
+    weights_dtype = None        # held as given (f32), cast in the program
+    scan_layers = False         # the engine's loop; the programs as they were
+
+    def __init__(self, cfg):
+        assert not getattr(cfg, "moe_num_experts", 0), \
+            "InferenceEngine serves GPT-2's dense blocks only: chunked " \
+            "prefill changes its MoE capacity-gating semantics " \
+            "(generation._moe_ffn gates whole prompts); use " \
+            "models.generation.generate for a GPT-2 MoE"
+        self.cfg = cfg
+        self.dtype = cfg.dtype
+        self.n_layer = cfg.n_layer
+
+    def embed(self, params, tokens, positions):
+        return params["wte"].astype(self.dtype)[tokens] \
+            + params["wpe"].astype(self.dtype)[positions]
+
+    def block(self, params, l, x, cache):
+        cfg = self.cfg
+        bp = jax.tree_util.tree_map(lambda a: a[l], params["h"]["block"]) \
+            if cfg.scan_layers else params[f"h_{l}"]
+        B, T, _ = x.shape
+        H, D = cfg.n_head, cfg.head_dim
+        h = _ln(x, bp["ln_1"], cfg.layer_norm_epsilon)
+        qkv = _dense(h, bp["attn"]["c_attn"])
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = _split_heads(q, B, T, H, D)                  # (B, H, T, D)
+        cache.write_heads(0, k.reshape(B * T, H, D))
+        cache.write_heads(1, v.reshape(B * T, H, D))
+        kview, vview = cache.view_heads(0, H), cache.view_heads(1, H)
+        kview = jnp.where(cache.valid_keys, kview, 0)
+        vview = jnp.where(cache.valid_keys, vview, 0)
+        x = x + _attn_core(q, kview, vview, cache.valid_scores, bp["attn"],
+                           x.dtype)
+        return x + _ffn(_ln(x, bp["ln_2"], cfg.layer_norm_epsilon), bp, cfg)
+
+    def final_norm(self, params, x):
+        return _ln(x, params["ln_f"], self.cfg.layer_norm_epsilon)
+
+    def logits(self, params, xe):
+        return _lm_logits(params, self.cfg, xe)
+
+
 def _block_decode(x, bp, ck, cv, pos, cfg):
     a, ck, cv = _attn_decode(
         _ln(x, bp["ln_1"], cfg.layer_norm_epsilon), bp["attn"], ck, cv,

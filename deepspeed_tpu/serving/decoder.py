@@ -1,0 +1,80 @@
+"""The decoder-block contract between a model and the serving engine.
+
+``InferenceEngine`` owns pages, page tables, masks, scheduling, sampling
+and the programs' shapes; the MODEL owns everything that differs between
+architectures.  A configuration states the rows it caches a token
+(``cfg.cache_rows``, read through ``kv_cache.cache_rows``: one pool tensor
+each, ``(H*D, H*D)`` for keys and values where it states none,
+``(R + Dr,)`` for one latent row) and hands the engine a decoder object
+(``cfg.decoder()``; a configuration without one is GPT-2,
+``models.generation.GPT2Decoder``) with:
+
+``dtype``
+    the compute dtype.
+``weights_dtype``
+    the dtype the engine HOLDS the served weights in (it casts once, at
+    construction); ``None``: as given.
+``stat_names``
+    names of the int32 counters a block reports a step (``()``: none, and
+    the programs have the outputs they always had).  The engine sums them
+    over the layers, brings them to the host ON THE STEP'S ONE FETCH, and
+    (tracer armed) records each as a zero-length span
+    ``<name>_<group>``, a0 the value, ``group`` being ``decode`` or
+    ``prefill_<bucket>``; beside them, for every model, ``attn_pairs_<group>``
+    / ``attn_keys_decode`` (what the program attended) and
+    ``clock_ms_<group>`` (when it was fetched).
+``n_layer``, ``scan_layers``
+    how many blocks, and whether the engine runs them as ONE traced block
+    under ``lax.scan`` (``l`` then a traced scalar and the weights stacked
+    by layer: one operation a kernel in the compiled program and in the
+    device trace, a fifth of the tracing) or as a Python loop (``l`` an
+    int).
+``embed(params, tokens, positions) -> (..., E)``
+``block(params, l, x, cache) -> x`` (or ``(x, stats)`` with ``stat_names``)
+    block ``l`` over x (B, T, E), residuals included; the model reads its
+    own layer's weights out of ``params``.  ``cache`` is
+    the engine's hook to layer ``l`` of the pool (``engine._LayerCache``):
+    ``positions`` (B, T) and ``maxpos`` (B,) absolute, ``row_valid``
+    (B, T) bool or None, ``write_rows(i, rows)`` / ``view_rows(i)`` for
+    raw rows of cache tensor ``i`` ((B*T, width) in; (B, K*bs, stored) out
+    in view order, which is position order on the dense path, ``stored``
+    being the width padded with zeros to whole 128-lane tiles), and for a
+    model with heads ``write_heads`` / ``view_heads`` with the two masks
+    ``valid_scores`` / ``valid_keys`` of the gathered view.
+``final_norm(params, x)``, ``logits(params, xe (N, E)) -> (N, vocab) f32``
+
+What serves a model whose cache is not ``(keys, values)`` or whose blocks
+report counters: the dense decode program and the chunked prefill
+programs.  The other variants (``speculative``, ``sparse_context``,
+``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off) refuse
+it by name with :class:`UnsupportedForModel`.
+"""
+
+
+class UnsupportedForModel(ValueError):
+    """An engine variant that knows keys and values only was asked to
+    serve a model that caches something else."""
+
+
+def decoder_for(cfg):
+    """The decoder object of a configuration."""
+    own = getattr(cfg, "decoder", None)
+    if own is not None:
+        return own()
+    from deepspeed_tpu.models.generation import GPT2Decoder
+
+    return GPT2Decoder(cfg)
+
+
+def refuse_unless_plain(cfg, dec, variant):
+    """Keys and values, no counters: what every engine variant serves."""
+    from deepspeed_tpu.serving.kv_cache import cache_rows
+
+    if len(cache_rows(cfg)) != 2 or dec.stat_names:
+        raise UnsupportedForModel(
+            f"{variant}: this engine variant serves models that cache "
+            f"(keys, values) only; {type(dec).__name__} caches rows of "
+            f"widths {cache_rows(cfg)} and reports "
+            f"{len(dec.stat_names)} counters. The dense decode program and "
+            f"the chunked prefill programs serve it "
+            f"(docs/tutorials/serving.md, 'The decoder-block contract').")

@@ -17,11 +17,21 @@ them in place: steady-state decode is allocation-free, and the HLO
 contracts in tests/unit/test_hlo_contracts.py pin the decode jit to
 "host-transfer-free + pool donated + (sharded) zero collective bytes".
 
-The decode math reuses models/generation.py internals (``_attn_core``,
-``_ln``, ``_ffn``, ``_sample``) over a gathered page view, and the exact
--1e30 masking makes greedy tokens bit-identical to single-sequence
-``generate()`` — under staggered arrivals, eviction and cancellation
-churn (the parity acceptance test).
+The model enters through the decoder-block contract
+(``serving/decoder.py``): it gives the engine its embedding, its block
+(positions in; the cache written and viewed through the engine's hooks,
+:class:`_LayerCache`), its final norm and head, the rows it caches a token
+and the dtype its served weights are held in.  The engine knows no
+architecture: GPT-2 implements the contract with models/generation.py's
+functions (``GPT2Decoder``: the shared masked core over a gathered page
+view, whose exact -1e30 masking makes greedy tokens bit-identical to
+single-sequence ``generate()`` under staggered arrivals, eviction and
+cancellation churn, the parity acceptance test); ``models/mistral4.py``
+brings latent (MLA) pages, a routed feed-forward over the experts this
+chip holds, and per-step routing counters that ride the step's one fetch.
+A model whose cache is not (keys, values) is served by the dense decode
+program and the chunked prefill programs; the other variants refuse it by
+name (``decoder.UnsupportedForModel``).
 
 Sharding: with ``shards > 1`` the decode program runs under a shard_map
 over the slot axis — slots, page tables and the block pool are all split
@@ -51,14 +61,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.generation import (_attn_core, _block_params,
-                                             _dense, _ffn, _lm_logits,
-                                             _ln, _sample, _split_heads)
+from deepspeed_tpu.models.generation import _sample
 from deepspeed_tpu.runtime.quantization import (dequantize_rows,
                                                 quantize_rows)
 from deepspeed_tpu.runtime.resilience import chaos
+from deepspeed_tpu.serving.decoder import (decoder_for,
+                                           refuse_unless_plain)
 from deepspeed_tpu.serving.kv_cache import (TRASH_BLOCK, PagedKVPool,
-                                            PoolTensors)
+                                            cache_rows)
 from deepspeed_tpu.serving.metrics import ServingMetrics
 from deepspeed_tpu.serving.reliability import (ABORT_BUDGET, ABORT_EXPIRED,
                                                ABORT_POISONED, ABORT_SHED,
@@ -118,13 +128,58 @@ def _pool_view(pool, scales, l, tables, n_head, quantized, out_dtype):
     return g.reshape(B, W * bs, H, D).transpose(0, 2, 1, 3)
 
 
-def _paged_forward(params, cfg, pools, tables, pos, maxpos, blk, off, x,
-                   quantized, sparse=None, allowed=None):
-    """Shared transformer pass of decode and chunked prefill: per layer,
-    write this step's K/V rows into the pool, gather the page view, and
-    run the SAME attention core the contiguous cache uses.  x: (B, T, E)
-    with T == number of query tokens per lane; pos: (B*T?,) absolute
-    positions of the query tokens, flattened to match blk/off.
+class _LayerCache:
+    """A block's hook to ONE layer of the pool: what the decoder-block
+    contract calls ``cache`` (``serving/decoder.py``).  Writes land at
+    this step's ``(blk, off)``, views gather the step's page tables; the
+    pool tensors threaded through the program are updated here and read
+    back by :func:`_paged_forward` after the block."""
+
+    def __init__(self, pools, l, blk, off, gtables, quantized, dtype,
+                 positions, maxpos, row_valid, masks):
+        self.pools = list(pools)            # [k, v, k_scale, v_scale]
+        self.l, self.blk, self.off = l, blk, off
+        self.gtables, self.quantized, self.dtype = gtables, quantized, dtype
+        self.positions, self.maxpos = positions, maxpos
+        self.row_valid = row_valid
+        self.valid_scores, self.valid_keys = masks
+
+    # keys and values with heads (GPT-2): quantizable, (B, H, K, D) views
+    def write_heads(self, i, rows):
+        self.pools[i], self.pools[2 + i] = _pool_write(
+            self.pools[i], self.pools[2 + i], self.l, self.blk, self.off,
+            rows, self.quantized)
+
+    def view_heads(self, i, n_head):
+        return _pool_view(self.pools[i], self.pools[2 + i], self.l,
+                          self.gtables, n_head, self.quantized, self.dtype)
+
+    # raw rows (a latent row has no head in it)
+    def write_rows(self, i, rows):
+        pool = self.pools[i]
+        stored = pool.shape[3]          # the row, padded to whole lanes
+        if rows.shape[1] < stored:
+            rows = jnp.pad(rows, ((0, 0), (0, stored - rows.shape[1])))
+        self.pools[i] = pool.at[self.l, self.blk, self.off].set(
+            rows.astype(pool.dtype))
+
+    def view_rows(self, i):
+        B, K = self.gtables.shape
+        pool = self.pools[i]
+        # ONE gather over (layer, page): the layer is not sliced out first
+        return pool[self.l, self.gtables.reshape(-1)] \
+            .reshape(B, K * pool.shape[2], pool.shape[3])
+
+
+def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
+                   quantized, sparse=None, allowed=None, row_valid=None):
+    """Shared transformer pass of decode and chunked prefill: per layer
+    the model's block (``serving/decoder.py``) writes this step's rows
+    into the pool and attends over the gathered page view through a
+    :class:`_LayerCache`.  x: (B, T, E) with T == number of query tokens
+    per lane; pos: (B*T?,) absolute positions of the query tokens,
+    flattened to match blk/off.  Returns the final-normed x, the pools and
+    the blocks' counters summed over the layers (None without).
 
     ``maxpos``: (B,) last VALID absolute position per lane.  View
     positions beyond it have their VALUES zeroed before the attention
@@ -147,12 +202,13 @@ def _paged_forward(params, cfg, pools, tables, pos, maxpos, blk, off, x,
     so view order no longer being position order changes nothing — the
     masks are built from the TRUE absolute positions.  ``allowed``
     (B?, T, K*bs) further restricts each query to its OWN policy blocks
-    (chunked prefill gathers the chunk's union set)."""
-    pk, pv, ksc, vsc = pools
+    (chunked prefill gathers the chunk's union set).
+
+    ``row_valid`` (B, T): which query rows are real tokens (a routed block
+    sends padding nowhere); None where the model does not ask."""
     B, T, _ = x.shape
-    H, D = cfg.n_head, cfg.head_dim
     W = tables.shape[1]
-    bs = pk.shape[2]
+    bs = pools[0].shape[2]
     if sparse is None:
         gtables = tables
         validj = (jnp.arange(W * bs)[None, :]
@@ -170,24 +226,60 @@ def _paged_forward(params, cfg, pools, tables, pos, maxpos, blk, off, x,
             validj = validj & allowed
         validj = validj[:, None]                         # (B, 1, T, K*bs)
         validk = (view_pos <= maxpos[:, None])[:, None, :, None]
-    for l, bp in enumerate(_block_params(params, cfg)):
-        h = _ln(x, bp["ln_1"], cfg.layer_norm_epsilon)
-        qkv = _dense(h, bp["attn"]["c_attn"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = _split_heads(q, B, T, H, D)                  # (B, H, T, D)
-        kt = k.reshape(B * T, H, D)
-        vt = v.reshape(B * T, H, D)
-        pk, ksc = _pool_write(pk, ksc, l, blk, off, kt, quantized)
-        pv, vsc = _pool_write(pv, vsc, l, blk, off, vt, quantized)
-        kview = _pool_view(pk, ksc, l, gtables, H, quantized, x.dtype)
-        vview = _pool_view(pv, vsc, l, gtables, H, quantized, x.dtype)
-        kview = jnp.where(validk, kview, 0)
-        vview = jnp.where(validk, vview, 0)
-        a = _attn_core(q, kview, vview, validj, bp["attn"], x.dtype)
-        x = x + a
-        x = x + _ffn(_ln(x, bp["ln_2"], cfg.layer_norm_epsilon), bp, cfg)
-    x = _ln(x, params["ln_f"], cfg.layer_norm_epsilon)
-    return x, (pk, pv, ksc, vsc)
+    def layer(l, x, pools):
+        cache = _LayerCache(pools, l, blk, off, gtables, quantized, x.dtype,
+                            pos.reshape(B, T), maxpos, row_valid,
+                            (validj, validk))
+        out = dec.block(params, l, x, cache)
+        x, row = out if dec.stat_names else (out, None)
+        return x, tuple(cache.pools), row
+
+    stats = None
+    n_rows = sum(t is not None for t in pools[:2])
+    if dec.scan_layers:
+        # one traced block, the layer index a traced scalar: the model
+        # reads its own layer out of stacked weights, the pool is carried
+        # and updated in place, and the device trace has one operation a
+        # kernel, not one a layer
+        def body(carry, l):
+            x, pools, stats = carry
+            x, pools, row = layer(l, x, _pools_of(pools, n_rows, quantized))
+            return (x, _held(pools),
+                    None if row is None else stats + row), None
+
+        stats = jnp.zeros(len(dec.stat_names), jnp.int32) \
+            if dec.stat_names else None
+        (x, held, stats), _ = jax.lax.scan(
+            body, (x, _held(pools), stats), jnp.arange(dec.n_layer))
+        pools = _pools_of(held, n_rows, quantized)
+    else:
+        for l in range(dec.n_layer):
+            x, pools, row = layer(l, x, pools)
+            if row is not None:
+                stats = row if stats is None else stats + row
+    return dec.final_norm(params, x), pools, stats
+
+
+def _pools_of(args, n_rows, quantized):
+    """The leading pool arguments of a serving program as the four slots
+    ``(k, v, k_scale, v_scale)``, None where the model (``n_rows`` cached
+    rows a token: 2, or 1) or the storage has none."""
+    slots = list(args[:n_rows]) + [None] * (2 - n_rows)
+    slots += list(args[n_rows:2 * n_rows]) if quantized else []
+    return tuple(slots + [None] * (4 - len(slots)))
+
+
+def _held(pools):
+    return tuple(t for t in pools if t is not None)
+
+
+def _n_pool(n_rows, quantized):
+    return n_rows * (2 if quantized else 1)
+
+
+def _stats_out(stats):
+    """A program's counter output: none (a model without), else one."""
+    return () if stats is None else (stats,)
 
 
 def _pick_next(logits, seeds, pos, temperature, top_k, top_p):
@@ -230,27 +322,27 @@ def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
     ``finite`` output (non-finite logits detector) rides the same
     batched fetch as the sampled tokens — per-request quarantine costs
     zero extra host syncs and zero recompiles."""
+    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+
     def run(params, *args):
-        pools, (tables, pos, tok, active, seeds, poison) = \
-            (args[:4] if quantized else args[:2] + (None, None)), args[-6:]
+        pools = _pools_of(args, n_rows, quantized)
+        tables, pos, tok, active, seeds, poison = args[-6:]
         S = tok.shape[0]
-        x = params["wte"].astype(cfg.dtype)[tok][:, None, :] \
-            + params["wpe"].astype(cfg.dtype)[pos][:, None, :]   # (S, 1, E)
-        x = x + poison.astype(cfg.dtype)[:, None, None]
+        x = dec.embed(params, tok, pos)[:, None, :]              # (S, 1, E)
+        x = x + poison.astype(dec.dtype)[:, None, None]
         blk = jnp.where(active, tables[jnp.arange(S), pos // bs],
                         TRASH_BLOCK)
         off = pos % bs
-        x, pools = _paged_forward(params, cfg, pools, tables, pos, pos,
-                                  blk, off, x, quantized)
-        logits = _lm_logits(params, cfg, x[:, 0])
+        x, pools, stats = _paged_forward(
+            params, dec, pools, tables, pos, pos, blk, off, x, quantized,
+            row_valid=active[:, None] if dec.stat_names else None)
+        logits = dec.logits(params, x[:, 0])
         finite = jnp.isfinite(logits).all(axis=-1)
         nxt = _pick_next(logits, seeds, pos, temperature, top_k, top_p)
         nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-        out = pools[:4] if quantized else pools[:2]
-        return (*out, nxt, finite)
+        return (*_held(pools), *_stats_out(stats), nxt, finite)
 
-    n_pool = 4 if quantized else 2
-    return _shard_wrap(run, mesh, axis_name, n_pool,
+    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
                        in_streams=(True,) * 6, n_out_streams=2)
 
 
@@ -273,15 +365,16 @@ def _make_spec_verify(cfg, K, W, bs, quantized, mesh, axis_name):
     token budget masks the surplus positions to the trash block, so
     near-capacity lanes neither write past their page table nor trip
     false poison quarantines on clamped-gather garbage."""
+    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+
     def run(params, *args):
-        pools = args[:4] if quantized else args[:2] + (None, None)
+        pools = _pools_of(args, n_rows, quantized)
         tables, pos, toks, nvalid, active, poison = args[-6:]
         S, T = toks.shape
         posns = pos[:, None] + jnp.arange(T)[None, :]          # (S, T)
-        x = params["wte"].astype(cfg.dtype)[toks] \
-            + params["wpe"].astype(cfg.dtype)[
-                jnp.minimum(posns, cfg.n_positions - 1)]       # (S, T, E)
-        x = x + poison.astype(cfg.dtype)[:, None, None]
+        x = dec.embed(params, toks,
+                      jnp.minimum(posns, cfg.n_positions - 1))  # (S, T, E)
+        x = x + poison.astype(dec.dtype)[:, None, None]
         valid_q = jnp.arange(T)[None, :] < nvalid[:, None]     # (S, T)
         writable = active[:, None] & valid_q & (posns < W * bs)
         blk = jnp.where(
@@ -291,20 +384,18 @@ def _make_spec_verify(cfg, K, W, bs, quantized, mesh, axis_name):
             TRASH_BLOCK)
         off = posns % bs
         maxpos = pos + nvalid - 1                              # (S,)
-        x, pools = _paged_forward(params, cfg, pools, tables, posns,
-                                  maxpos, blk.reshape(-1),
-                                  off.reshape(-1), x, quantized)
-        logits = _lm_logits(params, cfg,
+        x, pools, _ = _paged_forward(params, dec, pools, tables, posns,
+                                     maxpos, blk.reshape(-1),
+                                     off.reshape(-1), x, quantized)
+        logits = dec.logits(params,
                             x.reshape(S * T, -1)).reshape(S, T, -1)
         finite = jnp.where(valid_q, jnp.isfinite(logits).all(-1),
                            True).all(axis=1)                   # (S,)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # (S, T)
         nxt = jnp.where(active[:, None], nxt, 0)
-        out = pools[:4] if quantized else pools[:2]
-        return (*out, nxt, finite)
+        return (*_held(pools), nxt, finite)
 
-    n_pool = 4 if quantized else 2
-    return _shard_wrap(run, mesh, axis_name, n_pool,
+    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
                        in_streams=(True,) * 6, n_out_streams=2)
 
 
@@ -316,33 +407,34 @@ def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
     its own table row / n_valid — non-owner shards get n_valid == 0, so
     their writes all land in the trash block and their (finite) outputs
     are ignored by the host."""
+    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+
     def run(params, *args):
-        pools = args[:4] if quantized else args[:2] + (None, None)
+        pools = _pools_of(args, n_rows, quantized)
         table_rows, tokens, start, n_valids, seed = args[-5:]
         row = table_rows[0]
         n_valid = n_valids[0]
         posns = start + jnp.arange(C)                      # (C,)
-        x = params["wte"].astype(cfg.dtype)[tokens][None] \
-            + params["wpe"].astype(cfg.dtype)[posns][None]  # (1, C, E)
+        x = dec.embed(params, tokens, posns)[None]         # (1, C, E)
         valid_i = jnp.arange(C) < n_valid
         blk = jnp.where(valid_i, row[posns // bs], TRASH_BLOCK)
         off = posns % bs
         maxpos = (start + n_valid - 1)[None]             # (1,)
-        x, pools = _paged_forward(params, cfg, pools, row[None], posns,
-                                  maxpos, blk, off, x, quantized)
-        out = pools[:4] if quantized else pools[:2]
+        x, pools, stats = _paged_forward(
+            params, dec, pools, row[None], posns, maxpos, blk, off, x,
+            quantized, row_valid=valid_i[None] if dec.stat_names else None)
+        out = (*_held(pools), *_stats_out(stats))
         if not final:
             return out
         xe = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                           keepdims=False)
-        logits = _lm_logits(params, cfg, xe[None])
+        logits = dec.logits(params, xe[None])
         finite = jnp.isfinite(logits).all(axis=-1)       # (1,)
         nxt = _pick_next(logits, seed[None], (start + n_valid - 1)[None],
                          temperature, top_k, top_p)
         return (*out, nxt, finite)
 
-    n_pool = 4 if quantized else 2
-    return _shard_wrap(run, mesh, axis_name, n_pool,
+    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
                        in_streams=(True, False, False, True, False),
                        n_out_streams=2 if final else 0)
 
@@ -358,28 +450,27 @@ def _make_sparse_decode_step(cfg, W, K, bs, quantized, temperature, top_k,
     no-mutation-before-fetch discipline as ``_pos``/``_tok``.  The
     single decode query needs no per-query ``allowed`` mask: its active
     row IS exactly its own policy set (lut row of its query block)."""
+    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+
     def run(params, *args):
-        pools = args[:4] if quantized else args[:2] + (None, None)
+        pools = _pools_of(args, n_rows, quantized)
         tables, stables, sbase, pos, tok, active, seeds, poison = args[-8:]
         S = tok.shape[0]
-        x = params["wte"].astype(cfg.dtype)[tok][:, None, :] \
-            + params["wpe"].astype(cfg.dtype)[pos][:, None, :]   # (S, 1, E)
-        x = x + poison.astype(cfg.dtype)[:, None, None]
+        x = dec.embed(params, tok, pos)[:, None, :]              # (S, 1, E)
+        x = x + poison.astype(dec.dtype)[:, None, None]
         blk = jnp.where(active, tables[jnp.arange(S), pos // bs],
                         TRASH_BLOCK)
         off = pos % bs
-        x, pools = _paged_forward(params, cfg, pools, tables, pos, pos,
-                                  blk, off, x, quantized,
-                                  sparse=(stables, sbase))
-        logits = _lm_logits(params, cfg, x[:, 0])
+        x, pools, _ = _paged_forward(params, dec, pools, tables, pos, pos,
+                                     blk, off, x, quantized,
+                                     sparse=(stables, sbase))
+        logits = dec.logits(params, x[:, 0])
         finite = jnp.isfinite(logits).all(axis=-1)
         nxt = _pick_next(logits, seeds, pos, temperature, top_k, top_p)
         nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-        out = pools[:4] if quantized else pools[:2]
-        return (*out, nxt, finite)
+        return (*_held(pools), nxt, finite)
 
-    n_pool = 4 if quantized else 2
-    return _shard_wrap(run, mesh, axis_name, n_pool,
+    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
                        in_streams=(True,) * 8, n_out_streams=2)
 
 
@@ -393,8 +484,10 @@ def _make_sparse_prefill_chunk(cfg, C, W, K, bs, win, g, quantized, final,
     trace-constant policy layout masks those per (query, key-block)
     pair inside the jit.  Same shard semantics as the dense chunk:
     non-owner shards get n_valid == 0 and all-sentinel sparse rows."""
+    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+
     def run(params, *args):
-        pools = args[:4] if quantized else args[:2] + (None, None)
+        pools = _pools_of(args, n_rows, quantized)
         table_rows, stab_rows, sbase_rows, tokens, start, n_valids, seed = \
             args[-7:]
         row = table_rows[0]
@@ -402,8 +495,7 @@ def _make_sparse_prefill_chunk(cfg, C, W, K, bs, win, g, quantized, final,
         sbase = sbase_rows[0]
         n_valid = n_valids[0]
         posns = start + jnp.arange(C)                      # (C,)
-        x = params["wte"].astype(cfg.dtype)[tokens][None] \
-            + params["wpe"].astype(cfg.dtype)[posns][None]  # (1, C, E)
+        x = dec.embed(params, tokens, posns)[None]         # (1, C, E)
         valid_i = jnp.arange(C) < n_valid
         blk = jnp.where(valid_i, row[posns // bs], TRASH_BLOCK)
         off = posns % bs
@@ -414,22 +506,21 @@ def _make_sparse_prefill_chunk(cfg, C, W, K, bs, win, g, quantized, final,
         sblk = jnp.minimum(view_pos // bs, W - 1)          # (K, bs)
         allow = layout[qb[:, None, None], sblk[None]] \
             .reshape(C, K * bs)[None]                      # (1, C, K*bs)
-        x, pools = _paged_forward(
-            params, cfg, pools, row[None], posns, maxpos, blk, off, x,
+        x, pools, _ = _paged_forward(
+            params, dec, pools, row[None], posns, maxpos, blk, off, x,
             quantized, sparse=(srow[None], sbase[None]), allowed=allow)
-        out = pools[:4] if quantized else pools[:2]
+        out = _held(pools)
         if not final:
             return out
         xe = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
                                           keepdims=False)
-        logits = _lm_logits(params, cfg, xe[None])
+        logits = dec.logits(params, xe[None])
         finite = jnp.isfinite(logits).all(axis=-1)       # (1,)
         nxt = _pick_next(logits, seed[None], (start + n_valid - 1)[None],
                          temperature, top_k, top_p)
         return (*out, nxt, finite)
 
-    n_pool = 4 if quantized else 2
-    return _shard_wrap(run, mesh, axis_name, n_pool,
+    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
                        in_streams=(True, True, True, False, False, True,
                                    False),
                        n_out_streams=2 if final else 0)
@@ -452,10 +543,14 @@ class InferenceEngine:
                  speculative=None, sparse_context=None,
                  prefill_fairness=0):
         cfg = model.config
-        assert not getattr(cfg, "moe_num_experts", 0), \
-            "InferenceEngine serves dense blocks only: chunked prefill " \
-            "changes MoE capacity-gating semantics (generation._moe_ffn " \
-            "gates whole prompts); use models.generation.generate for MoE"
+        dec = decoder_for(cfg)      # GPT-2's refuses its capacity-gated MoE
+        for variant, asked in (("quantize_kv", quantize_kv),
+                               ("prefix_cache", prefix_cache),
+                               ("speculative", speculative),
+                               ("sparse_context", sparse_context),
+                               ("shards", shards > 1)):
+            if asked:
+                refuse_unless_plain(cfg, dec, variant)
         assert prefill_chunk >= _MIN_BUCKET \
             and (prefill_chunk & (prefill_chunk - 1)) == 0, \
             f"prefill_chunk must be a power of two >= {_MIN_BUCKET}"
@@ -465,7 +560,18 @@ class InferenceEngine:
                 f"shards={shards} != mesh axis {axis_name} size"
         else:
             assert shards == 1, "shards > 1 requires a mesh"
-        self.model, self.cfg, self.params = model, cfg, params
+        self.model, self.cfg, self.dec = model, cfg, dec
+        # served weights are held in the dtype the model states (a model
+        # that states none is held as given): cast once, here
+        held = dec.weights_dtype
+        if held is not None and any(
+                jnp.issubdtype(l.dtype, jnp.floating) and l.dtype != held
+                for l in jax.tree_util.tree_leaves(params)):
+            params = jax.jit(lambda tree: jax.tree_util.tree_map(
+                lambda l: l.astype(held)
+                if jnp.issubdtype(l.dtype, jnp.floating) else l, tree))(
+                    params)
+        self.params = params
         self.max_slots = int(max_slots)
         self.shards = int(shards)
         self.mesh = mesh
@@ -742,6 +848,10 @@ class InferenceEngine:
         self._run = None
         self._gap = None
         self._gap_idle = False
+        # per program dispatched since the last fetch, while a tracer is
+        # armed: (group, counters known on the host, the model's counters
+        # still on the device or None) -- see _note_program
+        self._stats_pending = []
         self._memacct = None
         if spec is None:
             return
@@ -1136,6 +1246,8 @@ class InferenceEngine:
         shard's base — ``pool.global_table_row``), so the host copy is
         shard-layout-free and imports into a destination with ANY shard
         count."""
+        refuse_unless_plain(self.cfg, self.dec,
+                            "export_request (fleet hand-off)")
         req = self.scheduler.requests.get(rid)
         assert req is not None and req.state is RequestState.RUNNING, \
             f"export_request({rid}): not a RUNNING request"
@@ -1179,6 +1291,8 @@ class InferenceEngine:
         prefill.  Deadlines restart relative (the :meth:`recover`
         semantics — clocks do not cross replicas); work budgets carry
         over.  Returns ``"adopted"`` or ``"requeued"``."""
+        refuse_unless_plain(self.cfg, self.dec,
+                            "import_request (fleet hand-off)")
         rid = int(entry["rid"])
         assert rid not in self.scheduler.requests, \
             f"import_request({rid}): rid already live here"
@@ -1451,9 +1565,8 @@ class InferenceEngine:
         raise AssertionError(f"chunk {n} > prefill_chunk")
 
     def _rebind(self, arrays):
-        # 2 arrays (k, v) or 4 (+ scales); the NamedTuple defaults cover
-        # the missing scale slots with None
-        self.pool.tensors = PoolTensors(*arrays)
+        # the pool's own slots (k [, v] [, scales]) in ``.arrays`` order
+        self.pool.tensors = self.pool.tensors.with_arrays(arrays)
 
     def _shard_for_slot(self, slot):
         return slot // (self.max_slots // self.shards)
@@ -1609,17 +1722,44 @@ class InferenceEngine:
             self._run = tr.span(span, self._lane_serve, t0=now)
         return fn(*args)
 
+    def _note_program(self, group, out, n_pool, **counters):
+        """While a tracer is armed, keep what the next :meth:`_fetch`
+        records of a program just dispatched: ``group`` (``decode``,
+        ``prefill_<bucket>``), counters the host knows, and the model's
+        own (``decoder.stat_names``), which are still on the device and
+        come to the host WITH that fetch, never by a sync of their own."""
+        if self._tracer is not None:
+            self._stats_pending.append(
+                (group, counters,
+                 out[n_pool] if self.dec.stat_names else None))
+
     def _fetch(self, arrays, *, lanes=0, bucket=0):
         """The step's ONE batched fetch.  It waits for every program
         still in flight, so it ends the open ``run_*`` span (a0: the
         bucket of a final chunk that ran alone, else the lanes decoded
         under it) and opens the next ``host_gap`` at the same instant."""
-        fetched = jax.device_get(arrays)
+        pending, self._stats_pending = self._stats_pending, []
+        fetched, stats = jax.device_get(
+            (arrays, [row for _, _, row in pending if row is not None]))
         tr = self._tracer
         if tr is not None:
             run, self._run = self._run, None
             now = run.end(a0=bucket if run.name == "run_prefill"
                           else lanes)
+            # every program this fetch waited for: its counters as
+            # zero-length spans ``<counter>_<group>`` (a0 the value), and
+            # under ``clock_ms_<group>`` the tracer's clock, so that a
+            # reader can tell which programs fell into a stretch of time
+            stats = iter(stats)
+            for group, counters, row in pending:
+                if row is not None:
+                    counters = dict(counters, **dict(zip(
+                        self.dec.stat_names, map(int, next(stats)))))
+                tr.count(f"clock_ms_{group}", self._lane_serve,
+                         int(now * 1e3), at=now)
+                for name, value in counters.items():
+                    tr.count(f"{name}_{group}", self._lane_serve, value,
+                             at=now)
             self._gap = tr.span("host_gap", self._lane_serve, t0=now)
             self._gap_idle = False
         return fetched
@@ -1717,11 +1857,16 @@ class InferenceEngine:
             "run_prefill" if final else "run_prefill_decode", fn, pf_args)
         req.work_done += n
         self.metrics.record_prefill(n)
+        n_pool = self.n_pool_tensors()
+        # (query, key) pairs this chunk attends: n queries from ``start``,
+        # each over every position up to its own
+        self._note_program(f"prefill_{bucket}", out, n_pool,
+                           attn_pairs=n * start + n * (n + 1) // 2)
         if final:
             # ONE batched fetch: the sampled token and the non-finite-
             # logits detector travel together (no extra host sync)
             fetched = self._fetch((out[-2], out[-1]), bucket=bucket)
-            self._rebind(out[:-2])
+            self._rebind(out[:n_pool])
             first = int(np.asarray(fetched[0]).reshape(-1)[req.shard])
             ok = bool(np.asarray(fetched[1]).reshape(-1)[req.shard])
             req.prefill_done = total
@@ -1735,7 +1880,7 @@ class InferenceEngine:
                 self.pool.prefix_insert(req.rid, req.shard, req.prompt)
             self._on_new_token(req, first, events, promote=True)
         else:
-            self._rebind(out)
+            self._rebind(out[:n_pool])
             req.prefill_done = start + n
             if self.prefill_fairness:
                 # chunked-prefill fairness: after a quantum of chunks a
@@ -1935,7 +2080,13 @@ class InferenceEngine:
                 expect_label="serving decode step: donated-in-place KV "
                 "block pool + sampled tokens")
         out = self._dispatch("run_decode", self._decode, decode_args)
-        self._rebind(out[:-2])
+        n_pool = self.n_pool_tensors()
+        self._rebind(out[:n_pool])
+        if self._tracer is not None:
+            # keys the program's live lanes attend (positions 0 .. pos):
+            # with the touched experts, the bytes a decode step has to move
+            self._note_program("decode", out, n_pool, attn_keys=int(
+                self._pos[list(running)].sum()) + lanes)
         # kill-mid-decode chaos: the dispatch happened, NO host
         # bookkeeping has — the journal holds the last committed step
         chaos.serving_kill_step(self._step_idx)

@@ -21,6 +21,15 @@ per-sequence view reassembles ``(H, W*block_size, D)`` in
 absolute-position order, so the attention math (shared
 ``generation._attn_core``) is bit-identical to the contiguous cache.
 
+Cache kinds: what one token's row holds is the MODEL's to state
+(``cfg.cache_rows``; a configuration without it keeps keys and values of
+``n_head * head_dim``).  A model with latent attention keeps ONE row a
+token and layer (``kv_lora_rank + qk_rope_head_dim`` values) and no
+separate value tensor: ``pool_shapes`` then gives ``v = None`` and the
+pool holds one tensor.  One allocator and one page table serve every kind:
+the block ids, the trash block and the refcounts do not know what a row
+holds.
+
 Block 0 of every shard is a reserved TRASH block: masked lanes (inactive
 slots, prefill padding) route their writes there, which keeps every
 scatter in the jit fully dense — no branches, no recompiles.
@@ -76,6 +85,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.utils.logging import logger
 
 TRASH_BLOCK = 0          # per-shard block 0 absorbs masked writes
+LANES = 128              # minor-dim tile of the TPU's memory layout
 
 
 @functools.partial(jax.jit, donate_argnums=0)
@@ -87,30 +97,59 @@ def _cow_copy_rows(arrs, src, dst):
     return tuple(a.at[:, dst].set(a[:, src]) for a in arrs)
 
 
+def cache_rows(cfg):
+    """Widths of the rows a layer caches a token, one pool tensor each:
+    the configuration's own ``cache_rows`` (one latent row: ``(R + Dr,)``),
+    else keys and values of ``n_head * head_dim``."""
+    own = getattr(cfg, "cache_rows", None)
+    if own is not None:
+        return tuple(int(w) for w in own)
+    return (cfg.n_head * cfg.head_dim,) * 2
+
+
 def pool_shapes(cfg, num_blocks, block_size, quantized):
     """The pool's four shapes ``(k, v, k_scale, v_scale)``; the scales
-    are None unless ``quantized``.  One rule for all four: the dims a
-    write indexes — layer, block, offset in the block — are major, one
-    token's row is minor (its ``n_head * head_dim`` values; its
-    ``n_head`` scales).  Every reader of the layout asks here."""
-    L, H, D = cfg.n_layer, cfg.n_head, cfg.head_dim
-    kv = (L, int(num_blocks), int(block_size), H * D)
-    scale = (L, int(num_blocks), int(block_size), H) if quantized else None
-    return kv, kv, scale, scale
+    are None unless ``quantized``, and ``v`` is None for a model that
+    caches one row a token (:func:`cache_rows`).  One rule for all: the
+    dims a write indexes — layer, block, offset in the block — are major,
+    one token's row is minor (keys and values: its ``n_head * head_dim``
+    values, its ``n_head`` scales; latent: its ``R + Dr`` values).  Every
+    reader of the layout asks here."""
+    rows = cache_rows(cfg)
+    assert 1 <= len(rows) <= 2, rows
+    index = (cfg.n_layer, int(num_blocks), int(block_size))
+    if len(rows) == 1:
+        # a row that does not fill whole 128-lane tiles is STORED padded to
+        # the next multiple: the TPU's tiling pads it in memory either way
+        # (320 values occupy 384), and stated in the shape the compiler
+        # updates the donated pool in place instead of unpadding and
+        # padding all of it around every program
+        rows = (-(-rows[0] // LANES) * LANES,)
+    k = index + (rows[0],)
+    v = index + (rows[1],) if len(rows) == 2 else None
+    scale = index + (cfg.n_head,) if quantized else None
+    return k, v, scale, scale
 
 
 class PoolTensors(NamedTuple):
     """The device-side pool state threaded through (and donated into)
     the decode/prefill jits.  ``k_scale``/``v_scale`` are None unless
-    int8 KV is armed."""
+    int8 KV is armed; ``v`` is None where one row a token is cached
+    (``k`` then holds it)."""
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
 
     @property
     def arrays(self):
         return tuple(t for t in self if t is not None)
+
+    def with_arrays(self, arrays):
+        """The same slots, holding ``arrays`` (in ``.arrays`` order)."""
+        it = iter(arrays)
+        return PoolTensors(*(None if t is None else next(it)
+                             for t in self))
 
 
 class _PrefixNode:
@@ -214,6 +253,11 @@ class PagedKVPool:
         request warns loudly (the armed-or-warns DISARMED discipline)."""
         if not requested:
             return False
+        if len(cache_rows(self.cfg)) != 2:
+            raise ValueError(
+                "quantize_kv: the int8 pool scales one (token, head) row "
+                "of keys and of values; this model caches rows of widths "
+                f"{cache_rows(self.cfg)} with no head in them")
         elem = np.dtype(self.dtype).itemsize
         D = self.cfg.head_dim
         if np.dtype(self.dtype) == np.float64:
@@ -481,9 +525,7 @@ class PagedKVPool:
 
             spec = NamedSharding(self.mesh, P(None, self.axis_name))
             arrs = tuple(jax.device_put(a, spec) for a in arrs)
-        it = iter(arrs)
-        self.tensors = PoolTensors(*(next(it) if t is not None else None
-                                     for t in self.tensors))
+        self.tensors = self.tensors.with_arrays(arrs)
 
     def warm_cow(self) -> None:
         """Compile the COW-split copy program up front (a trash-block
@@ -528,6 +570,9 @@ class PagedKVPool:
         from deepspeed_tpu.runtime.memory_accounting import kv_pool_bytes
 
         cfg = self.cfg
+        if len(cache_rows(cfg)) != 2:       # one row a token: as allocated
+            return sum(t.size * t.dtype.itemsize
+                       for t in self.tensors.arrays) // self.shards
         return kv_pool_bytes(
             cfg.n_layer, self.num_blocks, cfg.n_head, self.block_size,
             cfg.head_dim, kv_dtype=np.dtype(self.dtype).name,

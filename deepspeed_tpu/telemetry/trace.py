@@ -154,6 +154,13 @@ class Tracer:
         """Record a zero-duration marker event."""
         self._record(_PH_INSTANT, name, lane, self.clock(), 0.0, a0, a1)
 
+    def count(self, name, lane, value, at=None):
+        """Record a counter's value as a ZERO-LENGTH SPAN at ``at`` (now
+        when None): readers that take spans only (``ph == "X"``, a0) see
+        it, and a sum over a stretch of time is a sum over its events."""
+        self._record(_PH_SPAN, name, lane,
+                     self.clock() if at is None else at, 0.0, value, -1)
+
     def _record(self, ph, name, lane, ts, dur, a0, a1):
         with self._lock:
             nid = self._name_ids.get(name)

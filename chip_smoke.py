@@ -20,6 +20,7 @@ the last line is then not the ``ok`` line.  The times printed are
 information, not claims: the benchmark defines the metrics.
 """
 import argparse
+import functools
 import gc
 import json
 import os
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu
-from deepspeed_tpu.models.generation import generate
+from deepspeed_tpu.models.generation import _attn_core, generate
 from deepspeed_tpu.models.gpt2 import GPT2Model, gpt2_config
 from deepspeed_tpu.models.gpt2_pipe import gpt2_pipeline_module
 from deepspeed_tpu.ops.sparse_attention import (FixedSparsityConfig,
@@ -41,8 +42,11 @@ from deepspeed_tpu.ops.sparse_attention import (FixedSparsityConfig,
 from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 from deepspeed_tpu.ops.transformer.functional import (
     scaled_dot_product_attention)
+from deepspeed_tpu.ops.transformer.paged_attention import \
+    paged_decode_attention
 from deepspeed_tpu.serving import (CompilationCounter, FleetRouter,
                                    InferenceEngine)
+from deepspeed_tpu.serving.engine import _pool_view
 from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 # Tolerances, bf16-sized.  A kernel output or gradient may differ from its
@@ -157,11 +161,61 @@ def _padding(rng, batch, seq):
     return np.arange(seq)[None, :] < lengths[:, None]
 
 
+def _paged_decode(rng, lanes, n_head, head_dim, block, pages, layers=2):
+    """The serving decode kernel against the shared core over the gathered
+    view (the path every other platform runs), on a pool whose pages are
+    scattered, with idle lanes, and with NaN in every row no query may see:
+    the trash block, the pages past a lane's length, its last page's tail."""
+    HD, rows = n_head * head_dim, pages * block
+    n_blocks = 1 + lanes * pages
+    lengths = rng.integers(1, rows + 1, size=lanes)
+    lengths[:3] = (rows, 0, block + 1)     # full, idle, one row into a page
+    tables = 1 + rng.permutation(lanes * pages).reshape(lanes, pages)
+    seen = np.arange(rows)[None, :] < lengths[:, None]           # (B, S)
+    filled = np.zeros((n_blocks, block), bool)
+    filled[tables] = seen.reshape(lanes, pages, block)
+    k, v = (np.where(filled[None, :, :, None], rng.standard_normal(
+        (layers, n_blocks, block, HD)) * 0.5, np.nan) for _ in range(2))
+    k, v = jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((lanes, HD)) * 0.5, jnp.bfloat16)
+    tables, lengths = jnp.asarray(tables, jnp.int32), jnp.asarray(
+        lengths, jnp.int32)
+    layer = layers - 1
+    got = jax.jit(functools.partial(paged_decode_attention, n_head=n_head))(
+        q, k, v, layer, tables, lengths)
+
+    @jax.jit
+    def reference(q, k, v):
+        keep = jnp.asarray(seen)[:, None, :, None]
+        views = [jnp.where(keep, _pool_view(t, None, layer, tables, n_head,
+                                            False, q.dtype), 0)
+                 for t in (k, v)]
+        unprojected = {"c_proj": {"kernel": jnp.eye(HD, dtype=q.dtype),
+                                  "bias": jnp.zeros(HD, q.dtype)}}
+        return _attn_core(q.reshape(lanes, n_head, 1, head_dim), *views,
+                          jnp.asarray(seen)[:, None, None, :], unprojected,
+                          q.dtype)[:, 0]
+
+    live = np.asarray(lengths) > 0
+    check(not np.asarray(got, np.float32)[~live].any(),
+          "paged decode: an idle lane's output is not zero")
+    err = _rel_err(np.asarray(got, np.float32)[live],
+                   np.asarray(reference(q, k, v), np.float32)[live])
+    check(err <= KERNEL_TOL, f"paged decode kernel disagrees with the core "
+                             f"over the view: {err} > {KERNEL_TOL}")
+    return {"out": round(err, 5)}
+
+
 def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
                   bias_shape=(8, 16, 512, 64),
-                  sparse_shape=(2, 12, 4096, 64), sparse_block=64):
+                  sparse_shape=(2, 12, 4096, 64), sparse_block=64,
+                  paged_shape=(28, 16, 64, 16, 64)):
     rng = np.random.default_rng(seed)
     seen = {}
+
+    # serving decode over the paged pool: lanes, heads, head size, rows a
+    # page, pages a lane (the chat cell's)
+    seen["paged_decode_attn"] = _paged_decode(rng, *paged_shape)
 
     # flash, causal: the GPT-2 path
     q, k, v = _qkv(rng, causal_shape)
@@ -255,7 +309,8 @@ def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
             "shapes": {"flash_causal": list(causal_shape),
                        "flash_key_bias": list(bias_shape),
                        "block_sparse": list(sparse_shape)
-                       + [f"block {sparse_block}"]}}
+                       + [f"block {sparse_block}"],
+                       "paged_decode_attn": list(paged_shape)}}
 
 
 # ---------------------------------------------------------------------------

@@ -165,11 +165,7 @@ class GPT2Decoder:
         q = _split_heads(q, B, T, H, D)                  # (B, H, T, D)
         cache.write_heads(0, k.reshape(B * T, H, D))
         cache.write_heads(1, v.reshape(B * T, H, D))
-        kview, vview = cache.view_heads(0, H), cache.view_heads(1, H)
-        kview = jnp.where(cache.valid_keys, kview, 0)
-        vview = jnp.where(cache.valid_keys, vview, 0)
-        x = x + _attn_core(q, kview, vview, cache.valid_scores, bp["attn"],
-                           x.dtype)
+        x = x + cache.attend_heads(q, H, bp["attn"])
         return x + _ffn(_ln(x, bp["ln_2"], cfg.layer_norm_epsilon), bp, cfg)
 
     def final_norm(self, params, x):

@@ -1,0 +1,223 @@
+"""Decode attention over the serving engine's paged KV pool, read in place.
+
+One query a lane attends the keys and values of ITS pages where they lie in
+the pool (``kv_cache.pool_shapes``: ``(L, NB, bs, H*D)``, one token's H
+heads of D side by side in a row).  The pool stays in HBM; the kernel copies
+to VMEM only the pages a lane has filled (``ceil(length / bs)`` of its page
+table, none for an idle lane), ``pages_per_step`` at a time into one of two
+buffers while it computes on the other, and starts the next lane's first
+pages under the current lane's last.  A page is one contiguous
+``(bs, H*D)`` tile, so one copy brings every head.
+
+Rows are never relaid by head.  The per-head dot products come off the MXU
+from a block-diagonal query: ``Qbd (H, H*D)`` holds ``q_h`` in columns
+``h*D .. h*D + D - 1`` of row ``h`` and zeros elsewhere, so
+``Qbd . K^T (H, S)`` is every head's scores over S cached rows and
+``P (H, S) . V (S, H*D)`` holds head h's output in the same columns of row
+``h``: H times the needed operations, still far under the time the bytes
+take.  Scores and the running max and sum are f32 (online softmax), the
+probabilities meet the values in the values' dtype with f32 accumulation.
+
+Isolation: a row at or past a lane's length (the stale tail of its last
+page, rows of the buffer no copy filled) scores -1e30 whatever it holds and
+has its VALUES zeroed before the product, so NaN or inf there changes no
+output (``0 * NaN`` would).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.transformer.flash_attention import \
+    _interpret_default
+
+KERNEL_NAME = "paged_decode_attn"
+NEG_INF = -1e30
+_LANES = 128
+_STEP_BYTES = 512 * 1024
+
+
+def reads_in_place(pool_shape):
+    """Whether the compiled kernel can copy a page of this pool as it lies:
+    Mosaic slices VMEM by whole (8, 128) tiles, so a page's rows come in
+    eights and a row in whole lanes (gpt2-xl's 25 heads of 64 are 12.5).
+    The interpreter takes any shape."""
+    _, _, bs, row = pool_shape
+    return bs % 8 == 0 and row % _LANES == 0
+
+
+def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
+            q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, slot_ref, m_scr, l_scr, acc_scr, *,
+            n_head, pages, table_width, scale):
+    b = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    bs = k_buf.shape[1] // pages
+    S = pages * bs                      # cached rows a step
+    HD = q_ref.shape[-1]
+    D = HD // n_head
+    layer = layer_ref[0]
+    length = lengths_ref[b]
+
+    def page_copies(lane, c, slot, act):
+        """Start or wait for the copies of step ``c`` of ``lane``: the
+        pages of that step the lane has filled, keys and values.  (A
+        rolled loop: unrolled, sixteen pages at three sites were most of
+        the time it takes to trace and lower the kernel.)"""
+        first = c * pages
+        filled = (lengths_ref[lane] + bs - 1) // bs - first
+
+        def page(i, carry):
+            src = tables_ref[lane * table_width + first + i]
+            rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            for j, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                            (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(hbm.at[layer, src],
+                                          buf.at[slot, rows],
+                                          sems.at[j, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(filled, 0, pages), page, 0)
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    @pl.when(length == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _attend():
+        steps = (length + S - 1) // S
+
+        @pl.when(b == next_ref[n_lanes])        # the first live lane
+        def _first():
+            slot_ref[0] = 0
+            page_copies(b, 0, 0, start)
+
+        slot0 = slot_ref[0]
+        following = next_ref[b]                 # next live lane, or n_lanes
+        head = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 1)
+        own = jnp.logical_and(col >= head * D, col < (head + 1) * D)
+        # (the select runs in 32 bits: the mask has that layout)
+        q_bd = jnp.where(own, jnp.broadcast_to(
+            q_ref[0].astype(jnp.float32), (n_head, HD)), 0.0) \
+            .astype(q_ref.dtype)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        def step(c, carry):
+            slot = (slot0 + c) % 2
+            last = c + 1 == steps
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                page_copies(b, c + 1, 1 - slot, start)
+
+            @pl.when(jnp.logical_and(last, following < n_lanes))
+            def _():
+                page_copies(following, 0, 1 - slot, start)
+
+            page_copies(b, c, slot, wait)
+
+            @pl.when((c + 1) * S > length)      # rows no query may see
+            def _():
+                row = c * S + jax.lax.broadcasted_iota(jnp.int32, (S, HD), 0)
+                v_buf[slot] = jnp.where(row < length, v_buf[slot],
+                                        jnp.zeros((), v_buf.dtype))
+
+            k, v = k_buf[slot], v_buf[slot]
+            s = jax.lax.dot_general(
+                q_bd, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (H, S)
+            pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (n_head, S), 1)
+            s = jnp.where(pos < length, s, NEG_INF)
+            m_prev = m_scr[:, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)              # (H, H*D)
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            return carry
+
+        jax.lax.fori_loop(0, steps, step, 0)
+        slot_ref[0] = (slot0 + steps) % 2
+        # head h's output is the diagonal block of row h
+        out = jnp.where(own, acc_scr[:] / l_scr[:, 0:1], 0.0)
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n_head", "pages_per_step",
+                                             "interpret"))
+def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
+                           n_head, pages_per_step=None, interpret=None):
+    """q: (B, H*D), one query a lane, heads side by side; k_pool / v_pool:
+    (L, NB, bs, H*D), left where they are; ``layer``: the layer attended
+    (traced or not); tables: (B, W) page ids in position order; lengths:
+    (B,) cached rows a lane may see (positions 0 .. length - 1; 0: an idle
+    lane, which reads nothing and gets zeros).  Returns (B, H*D) in q's
+    dtype: softmax(q_h . K_h^T * D^-0.5) . V_h per head.  Entries of
+    ``tables`` past a lane's filled pages are never read."""
+    B, HD = q.shape
+    L, NB, bs, row = k_pool.shape
+    W = tables.shape[1]
+    assert row == HD and v_pool.shape == k_pool.shape, \
+        (q.shape, k_pool.shape, v_pool.shape)
+    assert tables.shape == (B, W) and lengths.shape == (B,)
+    if interpret is None:
+        interpret = _interpret_default()
+    assert interpret or reads_in_place(k_pool.shape), \
+        f"pages of {bs} rows of {row} do not fill whole (8, 128) tiles"
+    # a step's keys fill half a MiB of VMEM (as do its values, in each of
+    # two buffers): 256 rows of gpt2-350m's in bf16.  Measured on a v5e at
+    # 8 and 16 pages of 16 such rows a step: 71 and 90 % of the bytes' time
+    # with every page of 28 lanes filled
+    pages = pages_per_step or max(1, min(
+        W, _STEP_BYTES // (bs * HD * k_pool.dtype.itemsize)))
+    lengths = lengths.astype(jnp.int32)
+    lane = jnp.arange(B, dtype=jnp.int32)
+    # the next live lane after each (B: none), and in [B] the first one
+    live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
+    following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
+                                 live_from[:1]])
+    kernel = functools.partial(
+        _kernel, n_head=n_head, pages=pages, table_width=W,
+        scale=(HD // n_head) ** -0.5)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, HD), k_pool.dtype),
+                pltpu.VMEM((2, pages * bs, HD), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((n_head, _LANES), jnp.float32),
+                pltpu.VMEM((n_head, _LANES), jnp.float32),
+                pltpu.VMEM((n_head, HD), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      tables.astype(jnp.int32).reshape(-1), lengths, following,
+      q.reshape(B, 1, HD), k_pool, v_pool)
+    return out.reshape(B, HD)

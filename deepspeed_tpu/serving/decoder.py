@@ -40,7 +40,15 @@ each, ``(H*D, H*D)`` for keys and values where it states none,
     in view order, which is position order on the dense path, ``stored``
     being the width padded with zeros to whole 128-lane tiles), and for a
     model with heads ``write_heads`` / ``view_heads`` with the two masks
-    ``valid_scores`` / ``valid_keys`` of the gathered view.
+    ``valid_scores`` / ``valid_keys`` of the gathered view, and
+    ``attend_heads(q, n_head, p)``: the masked attention of q
+    (B, H, T, D) over the layer's cached keys and values through
+    ``p["c_proj"]``, (B, T, E).  The ENGINE chooses its form from what it
+    observes, since pages, tables and masks are its own: one query a lane
+    over the dense unquantized pool, lowered for a TPU, reads each lane's
+    filled pages where they lie (``ops/transformer/paged_attention.py``);
+    every other program, pool and platform attends the gathered view with
+    the ``jax.numpy`` core ``generate`` shares.
 ``final_norm(params, x)``, ``logits(params, xe (N, E)) -> (N, vocab) f32``
 
 What serves a model whose cache is not ``(keys, values)`` or whose blocks

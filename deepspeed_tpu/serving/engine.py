@@ -26,7 +26,9 @@ architecture: GPT-2 implements the contract with models/generation.py's
 functions (``GPT2Decoder``: the shared masked core over a gathered page
 view, whose exact -1e30 masking makes greedy tokens bit-identical to
 single-sequence ``generate()`` under staggered arrivals, eviction and
-cancellation churn, the parity acceptance test); ``models/mistral4.py``
+cancellation churn, the parity acceptance test; lowered for a TPU, the
+decode program attends each lane's filled pages where they lie instead,
+``_LayerCache.attend_heads``); ``models/mistral4.py``
 brings latent (MLA) pages, a routed feed-forward over the experts this
 chip holds, and per-step routing counters that ride the step's one fetch.
 A model whose cache is not (keys, values) is served by the dense decode
@@ -61,7 +63,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.generation import _sample
+from deepspeed_tpu.models.generation import _attn_core, _dense, _sample
+from deepspeed_tpu.ops.transformer.paged_attention import (
+    paged_decode_attention, reads_in_place)
 from deepspeed_tpu.runtime.quantization import (dequantize_rows,
                                                 quantize_rows)
 from deepspeed_tpu.runtime.resilience import chaos
@@ -114,15 +118,15 @@ def _pool_view(pool, scales, l, tables, n_head, quantized, out_dtype):
     (B, W) tables over the (L, NB, bs, H*D) pool -> (B, H, W*bs, D).
     Only the GATHERED pages are reshaped and transposed, never the pool.
     View position j IS absolute sequence position j, so the attention
-    mask of the contiguous cache applies unchanged.  (``pool[l]`` is
-    still sliced out before the gather; one gather ``pool[l, tables]``
-    is measured and waiting: ROADMAP.md S3d.)"""
+    mask of the contiguous cache applies unchanged.  ONE gather over
+    (layer, page): slicing ``pool[l]`` out first made the compiler
+    materialise the layer before every gather."""
     B, W = tables.shape
     _, _, bs, HD = pool.shape
     H, D = n_head, HD // n_head
-    g = pool[l][tables.reshape(-1)]                      # (B*W, bs, H*D)
+    g = pool[l, tables.reshape(-1)]                      # (B*W, bs, H*D)
     if quantized:
-        s = scales[l][tables.reshape(-1)]                # (B*W, bs, H)
+        s = scales[l, tables.reshape(-1)]                # (B*W, bs, H)
         g = dequantize_rows(g.reshape(B * W * bs, HD),
                             s.reshape(B * W * bs, H), HD, out_dtype)
     return g.reshape(B, W * bs, H, D).transpose(0, 2, 1, 3)
@@ -136,13 +140,14 @@ class _LayerCache:
     back by :func:`_paged_forward` after the block."""
 
     def __init__(self, pools, l, blk, off, gtables, quantized, dtype,
-                 positions, maxpos, row_valid, masks):
+                 positions, maxpos, row_valid, masks, lengths=None):
         self.pools = list(pools)            # [k, v, k_scale, v_scale]
         self.l, self.blk, self.off = l, blk, off
         self.gtables, self.quantized, self.dtype = gtables, quantized, dtype
         self.positions, self.maxpos = positions, maxpos
         self.row_valid = row_valid
         self.valid_scores, self.valid_keys = masks
+        self.lengths = lengths
 
     # keys and values with heads (GPT-2): quantizable, (B, H, K, D) views
     def write_heads(self, i, rows):
@@ -153,6 +158,38 @@ class _LayerCache:
     def view_heads(self, i, n_head):
         return _pool_view(self.pools[i], self.pools[2 + i], self.l,
                           self.gtables, n_head, self.quantized, self.dtype)
+
+    def attend_heads(self, q, n_head, p):
+        """Masked attention of q (B, H, T, D) over this layer's cached keys
+        and values, through the output projection ``p["c_proj"]``:
+        (B, T, E).  One algorithm whose best form differs with the query
+        count.  Where the program states ``lengths`` (one query a lane
+        over a dense unquantized pool of whole-tile pages) and is lowered
+        for a TPU, the paged kernel reads each lane's filled pages where
+        they lie (``ops/transformer/paged_attention.py``); everywhere else
+        the shared ``jax.numpy`` core attends the gathered view, whose
+        rows past ``maxpos`` are zeroed first (:func:`_paged_forward`)."""
+        def over_view(q):
+            kview, vview = (jnp.where(self.valid_keys,
+                                      self.view_heads(i, n_head), 0)
+                            for i in (0, 1))
+            return _attn_core(q, kview, vview, self.valid_scores, p,
+                              self.dtype)
+
+        if self.lengths is None:
+            return over_view(q)
+
+        def over_pages(q):
+            B = q.shape[0]
+            y = paged_decode_attention(
+                q.reshape(B, -1), self.pools[0], self.pools[1], self.l,
+                self.gtables, self.lengths, n_head=n_head, interpret=False)
+            return _dense(y[:, None], p["c_proj"])
+
+        # known only when the program is lowered (a described chip, a
+        # host-side run of the same program): only that branch is lowered
+        return jax.lax.platform_dependent(q, tpu=over_pages,
+                                          default=over_view)
 
     # raw rows (a latent row has no head in it)
     def write_rows(self, i, rows):
@@ -175,7 +212,8 @@ def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
                    quantized, sparse=None, allowed=None, row_valid=None):
     """Shared transformer pass of decode and chunked prefill: per layer
     the model's block (``serving/decoder.py``) writes this step's rows
-    into the pool and attends over the gathered page view through a
+    into the pool and attends over the gathered page view (or, one query a
+    lane on a TPU, the pages themselves: ``attend_heads``) through a
     :class:`_LayerCache`.  x: (B, T, E) with T == number of query tokens
     per lane; pos: (B*T?,) absolute positions of the query tokens,
     flattened to match blk/off.  Returns the final-normed x, the pools and
@@ -226,10 +264,19 @@ def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
             validj = validj & allowed
         validj = validj[:, None]                         # (B, 1, T, K*bs)
         validk = (view_pos <= maxpos[:, None])[:, None, :, None]
+    # one query a lane over the dense unquantized pool, its pages whole
+    # tiles: the rows each lane may see, none for a lane that is not live,
+    # for the paged kernel
+    lengths = None
+    if T == 1 and sparse is None and not quantized \
+            and reads_in_place(pools[0].shape):
+        lengths = maxpos + 1 if row_valid is None \
+            else jnp.where(row_valid[:, 0], maxpos + 1, 0)
+
     def layer(l, x, pools):
         cache = _LayerCache(pools, l, blk, off, gtables, quantized, x.dtype,
                             pos.reshape(B, T), maxpos, row_valid,
-                            (validj, validk))
+                            (validj, validk), lengths)
         out = dec.block(params, l, x, cache)
         x, row = out if dec.stat_names else (out, None)
         return x, tuple(cache.pools), row
@@ -335,7 +382,7 @@ def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
         off = pos % bs
         x, pools, stats = _paged_forward(
             params, dec, pools, tables, pos, pos, blk, off, x, quantized,
-            row_valid=active[:, None] if dec.stat_names else None)
+            row_valid=active[:, None])
         logits = dec.logits(params, x[:, 0])
         finite = jnp.isfinite(logits).all(axis=-1)
         nxt = _pick_next(logits, seeds, pos, temperature, top_k, top_p)
@@ -2084,9 +2131,14 @@ class InferenceEngine:
         self._rebind(out[:n_pool])
         if self._tracer is not None:
             # keys the program's live lanes attend (positions 0 .. pos):
-            # with the touched experts, the bytes a decode step has to move
-            self._note_program("decode", out, n_pool, attn_keys=int(
-                self._pos[list(running)].sum()) + lanes)
+            # with the touched experts, the bytes a decode step has to
+            # move; and the pages those keys lie in, which over max_slots
+            # x max_blocks_per_seq is the share of a fixed-shape view that
+            # attention reading live pages only still reads
+            keys = self._pos[list(running)] + 1
+            self._note_program("decode", out, n_pool,
+                               attn_keys=int(keys.sum()),
+                               attn_pages=int((-(-keys // self.bs)).sum()))
         # kill-mid-decode chaos: the dispatch happened, NO host
         # bookkeeping has — the journal holds the last committed step
         chaos.serving_kill_step(self._step_idx)

@@ -32,10 +32,11 @@ SERVE_TINY = dict(n_requests=4, prompt_range=(5, 20), new_tokens=4,
 def test_kernels_phase_interpret_mode():
     out = chip_smoke.phase_kernels(
         causal_shape=(1, 1, 256, 64), bias_shape=(2, 2, 128, 64),
-        sparse_shape=(2, 2, 256, 64))
+        sparse_shape=(2, 2, 256, 64), paged_shape=(4, 2, 8, 4, 8))
     assert set(out["rel_err"]) == {
-        "flash_causal", "flash_causal_compact_lse", "flash_dropout",
-        "flash_key_bias", "block_sparse", "block_sparse_key_bias"}
+        "paged_decode_attn", "flash_causal", "flash_causal_compact_lse",
+        "flash_dropout", "flash_key_bias", "block_sparse",
+        "block_sparse_key_bias"}
     assert "DSTPU_FLASH_LSE2D" not in os.environ
 
 

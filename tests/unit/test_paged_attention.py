@@ -1,0 +1,219 @@
+"""The paged decode-attention kernel (``ops/transformer/paged_attention.py``),
+interpret mode on the CPU, against what every other platform runs: the shared
+``jax.numpy`` core over the one-gather page view.
+
+- it IS that attention, to f32 rounding, at every length around a page's and
+  a step's edge, through shuffled page tables and beside idle lanes;
+- the isolation promise of ``_paged_forward``: no row past a lane's length
+  (its last page's tail, its unfilled pages, the trash block, a stranger's
+  page) reaches its output, whatever that row holds;
+- lanes that share pages read the same rows;
+- the engine's decode program, forced onto the kernel, serves ``generate``'s
+  greedy tokens.
+"""
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.models.generation import _attn_core, generate
+from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+from deepspeed_tpu.ops.transformer.paged_attention import (
+    KERNEL_NAME, paged_decode_attention, reads_in_place)
+from deepspeed_tpu.serving import engine as serving
+from deepspeed_tpu.serving.kv_cache import TRASH_BLOCK
+
+# heads, head size, rows a page, pages a lane: W * bs = 256 rows in both
+WIDTHS = {"toy": (4, 8, 4, 64), "hd1024": (16, 64, 16, 16)}
+ROWS = 256
+LAYERS, LAYER = 2, 1
+
+
+def _case(rng, width, lengths, dtype=jnp.float32):
+    """A pool whose pages lie scattered (block 0 is the trash block, every
+    other block belongs to one lane's table), one query a lane."""
+    H, D, bs, W = WIDTHS[width]
+    B, HD = len(lengths), H * D
+    NB = 1 + B * W
+    k, v = (jnp.asarray(rng.standard_normal((LAYERS, NB, bs, HD)), dtype)
+            for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, HD)), dtype)
+    tables = jnp.asarray(1 + rng.permutation(B * W).reshape(B, W), jnp.int32)
+    return q, k, v, tables, jnp.asarray(lengths, jnp.int32), H
+
+
+def _kernel(q, k, v, tables, lengths, H, **kw):
+    return np.asarray(paged_decode_attention(
+        q, k, v, LAYER, tables, lengths, n_head=H, interpret=True, **kw),
+        np.float32)
+
+
+def _core_over_view(q, k, v, tables, lengths, H):
+    """What ``_LayerCache.attend_heads`` runs off the TPU, without the
+    output projection."""
+    B, HD = q.shape
+    rows = tables.shape[1] * k.shape[2]
+    seen = jnp.arange(rows)[None, :] < lengths[:, None]
+    kview, vview = (jnp.where(
+        seen[:, None, :, None],
+        serving._pool_view(t, None, LAYER, tables, H, False, q.dtype), 0)
+        for t in (k, v))
+    unprojected = {"c_proj": {"kernel": jnp.eye(HD, dtype=q.dtype),
+                              "bias": jnp.zeros(HD, q.dtype)}}
+    return np.asarray(_attn_core(
+        q.reshape(B, H, 1, HD // H), kview, vview, seen[:, None, None, :],
+        unprojected, q.dtype)[:, 0], np.float32)
+
+
+@pytest.mark.parametrize("length", [1, 15, 16, 17, 255, ROWS])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_equals_the_core_over_the_view(width, length):
+    rng = np.random.default_rng(length)
+    lengths = [length, 0, ROWS + 1 - length, 0, int(rng.integers(1, ROWS))]
+    case = _case(rng, width, lengths)
+    got, want = _kernel(*case), _core_over_view(*case)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+    assert not got[~live].any(), "an idle lane reads nothing and gets zeros"
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 3, 16])
+def test_any_step_size_gives_the_same_attention(pages_per_step):
+    """Steps that do not divide a lane's pages, and a page a step."""
+    rng = np.random.default_rng(pages_per_step)
+    case = _case(rng, "toy", [ROWS, 0, 37, 1, 0, 130])
+    got = _kernel(*case, pages_per_step=pages_per_step)
+    live = np.asarray(case[4]) > 0
+    np.testing.assert_allclose(got[live], _core_over_view(*case)[live],
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_bf16_probabilities_meet_bf16_values():
+    """The cell's dtype: scores and softmax in f32, as the core's."""
+    rng = np.random.default_rng(7)
+    case = _case(rng, "hd1024", [200, 0, 17, ROWS], jnp.bfloat16)
+    got, want = _kernel(*case), _core_over_view(*case)
+    live = np.asarray(case[4]) > 0
+    err = np.linalg.norm(got[live] - want[live]) / np.linalg.norm(want[live])
+    assert err < 1e-2, err
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_rows_past_a_lane_change_nothing(width, poison):
+    """The isolation promise: values are masked, not only scores."""
+    rng = np.random.default_rng(11)
+    lengths = [1, 0, 17, ROWS - 1, 130]
+    q, k, v, tables, lens, H = _case(rng, width, lengths)
+    bs = k.shape[2]
+    clean = _kernel(q, k, v, tables, lens, H)
+    seen = np.arange(ROWS)[None, :] < np.asarray(lens)[:, None]
+    filled = np.zeros(k.shape[1:3], bool)         # (NB, bs); trash: unseen
+    filled[np.asarray(tables)] = seen.reshape(len(lengths), -1, bs)
+    assert not filled[TRASH_BLOCK].any() and not filled.all()
+    k, v = (jnp.where(filled[None, :, :, None], t, poison) for t in (k, v))
+    np.testing.assert_array_equal(_kernel(q, k, v, tables, lens, H), clean)
+
+
+@pytest.mark.parametrize("length", [5, 48, 49, 100])
+def test_lanes_sharing_pages_read_the_same_rows(length):
+    """A prefix cache hands two lanes the same leading pages (here 3 of
+    16 rows); with one query they agree while the length lies inside them
+    and part ways with their own pages after."""
+    rng = np.random.default_rng(3)
+    q, k, v, tables, _, H = _case(rng, "hd1024", [length, 0, length])
+    shared = 3
+    q = q.at[2].set(q[0])
+    tables = tables.at[2, :shared].set(tables[0, :shared])
+    lens = jnp.asarray([length, 0, length], jnp.int32)
+    got = _kernel(q, k, v, tables, lens, H)
+    np.testing.assert_allclose(got[[0, 2]],
+                               _core_over_view(q, k, v, tables, lens, H)[[0, 2]],
+                               rtol=2e-5, atol=2e-6)
+    if length <= shared * k.shape[2]:
+        np.testing.assert_array_equal(got[0], got[2])
+    else:
+        assert np.abs(got[0] - got[2]).max() > 1e-3
+
+
+@pytest.mark.parametrize("pool_shape, whole", [
+    ((24, 1793, 16, 1024), True),       # gpt2-350m, the serving cells'
+    ((48, 257, 16, 1600), False),       # gpt2-xl: 25 heads of 64, 12.5 lanes
+    ((2, 25, 4, 1024), False),          # a page of 4 rows
+    ((2, 25, 8, 128), True)])
+def test_only_whole_tile_pages_are_read_in_place(pool_shape, whole):
+    """Mosaic slices VMEM by (8, 128) tiles; the engine keeps the view for
+    a pool the compiled kernel would refuse."""
+    assert reads_in_place(pool_shape) is whole
+    q = jnp.zeros((3, pool_shape[3]), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct(pool_shape, jnp.bfloat16)
+    tables, lens = jnp.zeros((3, 4), jnp.int32), jnp.zeros((3,), jnp.int32)
+
+    def compiled(q, k, v):
+        return paged_decode_attention(q, k, v, 0, tables, lens,
+                                      n_head=pool_shape[3] // 64,
+                                      interpret=False)
+
+    if whole:
+        assert jax.eval_shape(compiled, q, pool, pool).shape == q.shape
+    else:
+        with pytest.raises(AssertionError, match="whole"):
+            jax.eval_shape(compiled, q, pool, pool)
+
+
+def test_the_program_names_the_kernel():
+    """``device_ops`` and ``benchmark/tools/top_ops.py`` show a kernel under
+    its ``pallas_call``'s name."""
+    q, k, v, tables, lens, H = _case(np.random.default_rng(0), "hd1024", [3])
+    program = jax.make_jaxpr(lambda *a: paged_decode_attention(
+        *a, n_head=H, interpret=False))(q, k, v, LAYER, tables, lens)
+    assert KERNEL_NAME == "paged_decode_attn"
+    assert f"name={KERNEL_NAME}" in str(program)
+
+
+def test_engine_decodes_through_the_kernel(monkeypatch):
+    """The decode program on the branch a TPU takes (the kernel in
+    interpret mode here) serves ``generate``'s greedy tokens: staggered
+    arrivals, mixed lengths, idle lanes."""
+    # the smallest pool the compiled kernel would take: pages of 8 rows of
+    # 128 (``reads_in_place``); a narrower one keeps the view on every chip
+    cfg = GPT2Config(vocab_size=97, n_positions=64, n_embd=128, n_layer=2,
+                     n_head=4, dtype=jnp.float32, loss_chunk_tokens=0)
+    model = GPT2Model(cfg)
+    ids = np.random.default_rng(0).integers(0, 97, (2, 8))
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": ids, "labels": ids})
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 97, n).astype(np.int32) for n in (5, 11, 3, 9)]
+    maxnew = [6, 9, 12, 5]
+    want = [generate(model, params, p[None], max_new_tokens=m)[0]
+            for p, m in zip(prompts, maxnew)]
+
+    calls = []
+
+    def on_the_kernel(*args, **kw):
+        calls.append(args[0].shape)
+        return paged_decode_attention(*args, **{**kw, "interpret": True})
+
+    monkeypatch.setattr(serving, "paged_decode_attention", on_the_kernel)
+    monkeypatch.setattr(serving.jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    # a program traced by an earlier test holds the other branch, and this
+    # one must not be left for a later test
+    serving._make_decode_step.cache_clear()
+    try:
+        eng = serving.InferenceEngine(model, params, max_slots=3,
+                                      kv_block_size=8, prefill_chunk=8,
+                                      max_blocks_per_seq=8)
+        rids = []
+        for p, m in zip(prompts, maxnew):
+            rids.append(eng.submit(p, max_new_tokens=m))
+            eng.step()
+            eng.step()
+        res = eng.serve(max_steps=500)
+    finally:
+        serving._make_decode_step.cache_clear()
+    assert calls and set(calls) == {(3, 128)}, \
+        "only the decode program (one query a lane) takes the kernel"
+    for rid, tokens in zip(rids, want):
+        np.testing.assert_array_equal(res[rid]["tokens"], tokens)

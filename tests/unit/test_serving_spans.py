@@ -136,6 +136,33 @@ def test_what_each_stretch_is_named_for(toy):
     assert ticks[0]["ts"] + ticks[0]["dur"] > chunked["ts"] + chunked["dur"]
 
 
+def test_a_decode_program_records_its_keys_and_their_pages(toy):
+    """``attn_keys_decode``: the positions the live lanes attend;
+    ``attn_pages_decode`` beside it: the pages those lie in, which over
+    ``max_slots x max_blocks_per_seq`` is the share of a fixed-shape view
+    that attention over live pages still reads.  Host arithmetic, one of
+    each a decode program."""
+    eng = _engine(toy)
+    eng.warmup()
+    tr = eng.telemetry.tracer
+    tr.reset()
+    eng.submit(_prompt(5), 6)               # pages of 4 rows: 2, then 3
+    eng.submit(_prompt(9, seed=2), 6)       # 3 pages
+    eng.serve(max_steps=50)
+    events = tr.events()
+    keys, pages = ([e["a0"] for e in events if e["name"] == name]
+                   for name in ("attn_keys_decode", "attn_pages_decode"))
+    assert len(keys) == len(pages) == sum(
+        e["name"] in ("run_decode", "run_prefill_decode") and e["a0"] > 0
+        for e in events)
+    # the first decode program: one lane, its 5 prompt rows and the token
+    # just sampled
+    assert (keys[0], pages[0]) == (6, 2)
+    assert all(k / 4 <= p < k / 4 + 2 for k, p in zip(keys, pages))
+    # the last one: the later lane alone, 9 + 5 rows
+    assert (keys[-1], pages[-1]) == (14, 4)
+
+
 def test_gap_across_an_empty_engine_and_chunks_into_one(toy):
     eng = _engine(toy, clock=TickClock())
     eng.warmup()

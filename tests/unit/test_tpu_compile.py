@@ -189,7 +189,11 @@ def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
     bf16 pool is compiled at the cell's 24 layers, because the temporaries
     hold the weights' bf16 casts and so grow with depth as the pool does;
     the int8 pool, which no cell runs, at 2 layers and for the copies
-    alone (they sit at a program's edge, whatever the depth)."""
+    alone (they sit at a program's edge, whatever the depth).
+
+    Nor does a bf16 program hold one layer of the pool on its own (ONE
+    gather over (layer, page), ``_pool_view``), and the decode program
+    builds no view at all: the paged kernel reads the pool where it lies."""
     import re
 
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
@@ -244,9 +248,96 @@ def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
         return
     one_pool_tensor = tensors[0].size * tensors[0].dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < one_pool_tensor
+
+    def made(*dims):
+        """The instructions whose result has this shape."""
+        shape = ",".join(str(d) for d in dims)
+        return [line.strip()[:120] for line in text.splitlines()
+                if re.search(rf"= \(?\w+\[{shape}\]", line)]
+
+    _, NB, _, HD = tensors[0].shape
+    assert not made(NB, bs, HD), f"{program} holds a layer of the pool"
+    if program == "decode":
+        H = cfg.n_head
+        assert not made(S, W * bs, H, HD // H), "decode relays a page view"
+        assert not made(S * W, bs, HD), "decode gathers every lane's pages"
+        assert "paged_decode_attn" in text
+    else:
+        assert "paged_decode_attn" not in text
     # the pool tensors are the leading outputs; parameter numbers shift
     # with the weights a program leaves unused, output numbers do not
     assert hc.aliased_outputs(text) >= set(range(len(tensors)))
+
+
+def test_decode_program_keeps_the_view_where_pages_are_not_whole_tiles(v5e):
+    """gpt2-xl's row of 25 heads x 64 is 12.5 lanes wide: Mosaic would
+    refuse to slice it, so the engine, which sees the pool's shape, builds
+    the one-gather view there and the program still compiles."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving.kv_cache import pool_shapes
+
+    S, W, bs = 8, 16, 16
+    model = GPT2Model(GPT2Config(
+        vocab_size=50257, n_positions=1024, n_embd=1600, n_layer=1,
+        n_head=25, dtype=jnp.bfloat16, scan_layers=True))
+    cfg = model.config
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ids = np.zeros((1, 8), np.int32)
+    params = jax.tree_util.tree_map(
+        lambda l: struct(l.shape, l.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       {"input_ids": ids, "labels": ids}))
+    tensors = [struct(shape, cfg.dtype) for shape in
+               pool_shapes(cfg, 1 + S * W, bs, False) if shape is not None]
+    streams = [struct((S, W), jnp.int32)] + [
+        struct((S,), dtype) for dtype in
+        (jnp.int32, jnp.int32, jnp.bool_, jnp.int32, jnp.float32)]
+    jitted = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0, None,
+                                       "data")
+    text = jitted.lower(params, *tensors, *streams).compile().as_text()
+    assert "paged_decode_attn" not in text
+
+
+def test_sharded_decode_program_runs_the_paged_kernel_on_each_chip(v5e_host):
+    """``shards=4``: the decode program is a shard_map over the slot and
+    block axes, and each chip's part attends ITS lanes' pages in ITS blocks
+    of the pool with the paged kernel (a Mosaic kernel that GSPMD met
+    outside a shard_map would be refused)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving.kv_cache import pool_shapes
+
+    S, W, bs = (SERVE_CHAT[k] for k in ("slots", "pages", "block"))
+    mesh = Mesh(np.asarray(v5e_host), ("data",))
+    model = GPT2Model(GPT2Config(
+        vocab_size=50257, n_positions=1024, n_embd=1024, n_layer=2,
+        n_head=16, dtype=jnp.bfloat16, scan_layers=True))
+    cfg = model.config
+
+    def struct(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    ids = np.zeros((1, 8), np.int32)
+    params = jax.tree_util.tree_map(
+        lambda l: struct(l.shape, l.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       {"input_ids": ids, "labels": ids}))
+    tensors = [struct(shape, cfg.dtype, None, "data") for shape in
+               pool_shapes(cfg, 4 + S * W, bs, False) if shape is not None]
+    streams = [struct((S, W), jnp.int32, "data")] + [
+        struct((S,), dtype, "data") for dtype in
+        (jnp.int32, jnp.int32, jnp.bool_, jnp.int32, jnp.float32)]
+    jitted = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0, mesh,
+                                       "data")
+    text = jitted.lower(params, *tensors, *streams).compile().as_text()
+    assert "paged_decode_attn" in text
 
 
 def test_lut_beyond_smem_fails_at_trace_time():

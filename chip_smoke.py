@@ -73,8 +73,8 @@ TIE_STEPS = 1
 NEAR_BEST_STEPS = 2
 
 SEQ = 1024
-MICRO_BATCH = 8     # the one size the driver has seen fit (BENCH_r03.json)
-# remat / scan_layers / loss_chunk_tokens as bench.py defaults them
+MICRO_BATCH = 8     # fits one v5e with full remat (the benchmark's cell runs 16)
+# full remat, scanned layers, chunked loss head: what a 16 GB chip trains with
 TRAINED = dict(remat=True, remat_policy="nothing", scan_layers=True,
                loss_chunk_tokens=8192)
 SERVED = dict(scan_layers=True)
@@ -363,8 +363,8 @@ def phase_train(seed=0, *, cfg=None, micro_batch=MICRO_BATCH, seq=SEQ,
             loss.block_until_ready()
             step_s.append(time.perf_counter() - t0)
             losses.append(float(loss))
-        # the same step, closed by the transfer instead: bench.py times
-        # this way, so the two must agree
+        # the same step, closed by the transfer instead: a driver that
+        # reads the loss back times this way, so the two must agree
         t0 = time.perf_counter()
         last = float(jax.device_get(engine.train_batch(batch=batch)))
         device_get_step_s = time.perf_counter() - t0

@@ -26,9 +26,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# tuned on TPU v5e at (8, 16, 1024, 64): 512/1024 reached 22 TF fwd /
-# 45 TF fwd+bwd vs 13.6/25 for the fused-XLA jnp path (tools/flash_tune.py);
-# blocks are clamped to the sequence length at call time
+# 512/1024 were picked at (8, 16, 1024, 64) on a v5e before PR 21; the
+# kernel's own rate is not measured since PR 21 (PERF.md §5: the flash
+# calls are 26.5 % of the training cell's busy time).  Blocks are clamped
+# to the sequence length at call time
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 # optional overrides for the backward sweeps only (0 = inherit fwd blocks);
@@ -409,7 +410,7 @@ def _flash_bwd(res, g, *, scale, causal, bias_kind, num_heads, dropout_rate,
     s_k = k.shape[1]
     # the backward sweeps accumulate into (block, d) fp32 scratch and run a
     # 5-matmul body — their best tile shape differs from the forward's;
-    # independent env knobs let tools/flash_tune.py sweep them on-chip.
+    # independent env knobs let a sweep on the chip set them alone.
     # A knob with no 128-aligned divisor fails as loudly as the forward
     # does (flash_attention.py asserts in flash_attention()) — a partial
     # Pallas block would silently corrupt the gradients.

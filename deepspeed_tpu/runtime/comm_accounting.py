@@ -649,7 +649,7 @@ def serving_gather_bytes_per_step(
     ``pages`` pool pages per lane, per layer — the memory-bound side of
     decode, and where the sparse page policy's active-page factor lands
     (``pages`` is the page-table width W dense, the policy's fixed K
-    sparse — the serve_bench A/B's ≥4x claim IS this ratio).  int8
+    sparse: W / K is the factor the sparse policy saves).  int8
     pools read int8 rows plus the per-(token, head) f32 scales, the
     same layout ``kv_cache._pool_view`` dequantizes."""
     store = 1 if quantized else DTYPE_BYTES[kv_dtype]

@@ -1084,7 +1084,7 @@ class DeepSpeedEngine:
 
             # the chaos observer list is PROCESS-GLOBAL: register a
             # weakref trampoline, not a bound method, so an abandoned
-            # engine (bench ladders build one per attempt) stays
+            # engine (a loop that builds one per attempt) stays
             # collectable and its __del__ can deregister cleanly
             ref = weakref.ref(self)
 
@@ -1116,8 +1116,8 @@ class DeepSpeedEngine:
         """Release the telemetry session's process-global hooks (the
         chaos observer) and close the metrics-stream file handle.
         Idempotent; also runs at GC so loops that build many engines
-        (bench ladders) never accumulate observers or leak JSONL fds.
-        The session object stays readable — only the stream is closed."""
+        never accumulate observers or leak JSONL fds.  The session
+        object stays readable — only the stream is closed."""
         obs = getattr(self, "_chaos_observer", None)
         if obs is not None:
             self._chaos_observer = None

@@ -20,16 +20,16 @@ total work per micro than the fused schedules. Its bubble FRACTION still
 lands lowest (utilization is high), but compare ``makespan`` for
 throughput: at pipe=4/gas=8 that model gives zb-h1 makespan 36.5 vs
 1f1b 33 — under always-remat the extra recompute outweighs the bubble it
-fills (M*f extra work vs a constant (S-1)(f+b-(f+d-w)) saving), matching
-the CPU-mesh measurement in BENCH_NOTES. A schedule compiled with
+fills (M*f extra work vs a constant (S-1)(f+b-(f+d-w)) saving); on a
+chip this is not measured. A schedule compiled with
 ``stash=True`` (bounded activation stashing — the engine runs the
 forward once and both split passes consume its stashed vjp residuals)
 defaults to ``CostModel.stash()`` (d = w = 1, d + w = b): zb-h1 becomes
 a genuine throughput win, makespan 27 vs 33 at the same point, paid for
 in stash memory (``peak_live_stash`` per stage). With f == b
 (``CostModel.equal_fwd_bwd()``) the plain 1F1B simulation reproduces the
-closed form (S-1)/(M+S-1) exactly (the round-5 BENCH_NOTES numbers:
-0.20 at pipe=2, 0.43 at pipe=4, gas=4).
+closed form (S-1)/(M+S-1) exactly (0.20 at pipe=2, 0.43 at pipe=4,
+gas=4).
 
 A stream that can never satisfy one of its Recvs makes the simulation
 wedge; that raises ``DeadlockError`` naming the blocked stages — the

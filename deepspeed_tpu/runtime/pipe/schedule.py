@@ -10,11 +10,11 @@ device_put between adjacent stage submeshes (runtime/pipe/engine.py).
 Why host-driven (and not one fused whole-schedule lax.scan): dispatch is
 asynchronous — the host enqueues every stage's program for a tick without
 waiting, so stage programs overlap on-device exactly as 1F1B intends, and
-the host cost is enqueue-only (measured by tools/pipe_bench.py; numbers in
-BENCH_NOTES.md). A single fused scan would need every stage's weights and
-buffers resident in ONE program over the whole mesh with uniform tick
-bodies, giving up heterogeneous stage partitions and per-stage remat
-choices; the measured enqueue overhead does not justify that trade.
+the host cost is enqueue-only (not measured on a chip: no benchmark cell
+runs the pipeline, PERF.md §7). A single fused scan would need every
+stage's weights and buffers resident in ONE program over the whole mesh
+with uniform tick bodies, giving up heterogeneous stage partitions and
+per-stage remat choices.
 """
 
 

@@ -384,9 +384,8 @@ def record_serving_poison(rid):
 
 def serving_burst(step_index):
     """Extra request arrivals to release at this serving step — traffic
-    drivers (tools/serve_bench.py, tests) query it so thundering-herd
-    bursts run through the same arming/audit machinery as every other
-    fault."""
+    drivers query it so thundering-herd bursts run through the same
+    arming/audit machinery as every other fault."""
     if _plan is None or not _plan.burst_arrival_every:
         return 0
     if step_index % _plan.burst_arrival_every:

@@ -584,10 +584,9 @@ class InferenceEngine:
     def __init__(self, model, params, *, max_slots=4, kv_block_size=16,
                  kv_blocks=None, max_blocks_per_seq=None, prefill_chunk=16,
                  quantize_kv=False, temperature=0.0, top_k=0, top_p=0.0,
-                 policy="continuous", shards=1, mesh=None,
-                 axis_name="data", watchdog=None, clock=time.monotonic,
-                 reliability=None, telemetry=None, prefix_cache=False,
-                 speculative=None, sparse_context=None,
+                 shards=1, mesh=None, axis_name="data", watchdog=None,
+                 clock=time.monotonic, reliability=None, telemetry=None,
+                 prefix_cache=False, speculative=None, sparse_context=None,
                  prefill_fairness=0):
         cfg = model.config
         dec = decoder_for(cfg)      # GPT-2's refuses its capacity-gated MoE
@@ -636,7 +635,7 @@ class InferenceEngine:
         self.temperature = float(temperature)
         self.top_k = int(top_k or 0)
         self.top_p = float(top_p or 0.0)
-        self.scheduler = Scheduler(max_slots, policy=policy)
+        self.scheduler = Scheduler(max_slots)
         # admission placement: prefer the slot whose shard already holds
         # the candidate's cached prefix (prefix-cache locality beats raw
         # headroom — a hit skips whole prefill chunks), then the slot
@@ -692,12 +691,6 @@ class InferenceEngine:
         # picks which decode program the engine serves
         self.sparse = self._arm_sparse_context(sparse_context)
         self.prefill_fairness = int(prefill_fairness or 0)
-        if self.prefill_fairness and policy != "continuous":
-            logger.warning(
-                "prefill fairness: DISARMED — the static batch gate "
-                "already runs each batch to completion; the pause "
-                "quantum only applies to continuous batching.")
-            self.prefill_fairness = 0
         self._stables = self._sbase = None
         if self.sparse is not None:
             self._stables = np.full((S, self.sparse.K), TRASH_BLOCK,
@@ -951,7 +944,8 @@ class InferenceEngine:
         """Close the metrics-stream file handle of a telemetry session
         THIS engine created from a dict spec (a caller-provided
         ``Telemetry`` instance is the caller's to close).  Idempotent;
-        also runs at GC so bench loops never leak JSONL fds."""
+        also runs at GC so loops that build engines never leak JSONL
+        fds."""
         if getattr(self, "_owns_telemetry", False) \
                 and self.telemetry is not None:
             self.telemetry.close()
@@ -1053,8 +1047,8 @@ class InferenceEngine:
         if _work_done:
             req.work_done = int(_work_done)
         # TTFT class: "long" prompts (several prefill chunks) vs chatty
-        # "short" ones — the per-class view the long-context bench's
-        # fairness guard reads
+        # "short" ones — the per-class view the fairness guard's tests
+        # read
         self.metrics.record_submit(
             rid, klass="long" if prompt.size >= 4 * self.prefill_chunk
             else "short")
@@ -1124,7 +1118,6 @@ class InferenceEngine:
             _tick.end(a0=decoded)
             for rid_ in events["admitted"]:
                 tr.instant("admit", self._lane_serve, a0=rid_)
-        self.scheduler.on_drained()
         self.reliability.on_step_end()
         occ = self.pool.occupancy()
         frag = self.pool.fragmentation()
@@ -1480,7 +1473,6 @@ class InferenceEngine:
             "max_blocks_per_seq": self.W,
             "prefill_chunk": self.prefill_chunk,
             "quantized_kv": self.pool.quantized,
-            "policy": self.scheduler.policy,
             "temperature": self.temperature, "top_k": self.top_k,
             "top_p": self.top_p,
             "prefix_cache": self.prefix_cache,

@@ -9,10 +9,8 @@ fleet layer:
   the lowest predicted TTFT, computed per replica by the SAME
   queue-depth x measured-TPOT estimator the admission gate uses
   (``reliability.Reliability.predicted_ttft_s``).  An idle or
-  not-yet-measured replica predicts 0 and soaks up traffic first.  When
-  the estimator cannot describe a replica (non-``continuous`` scheduler
-  policy) the router warns DISARMED — naming the blocker, per the
-  repo's arming discipline — and falls back to round-robin.
+  not-yet-measured replica predicts 0 and soaks up traffic first.
+  ``dispatch="round-robin"`` is the baseline placement.
 - **Replica health / circuit breaker** — each replica carries a
   watchdog heartbeat (the engine's per-step ``observe_serving_step``);
   stall events, poison quarantines and step crashes are health STRIKES.
@@ -196,7 +194,6 @@ class FleetRouter:
         self.lost: List[int] = []
         self.replica_steps = 0      # sum of alive replicas over steps:
         #                             the honest autoscale denominator
-        self._arm_dispatch()
         self._arm_telemetry(telemetry)
         self._arm_autoscale(autoscale)
         self._arm_transport(transport)
@@ -237,28 +234,12 @@ class FleetRouter:
             return ACTION_CONTINUE
         return _cb
 
-    # -- arming (DISARMED discipline) -----------------------------------
-    def _arm_dispatch(self):
-        """Arm SLO-aware placement, or warn loudly (DISARMED) naming
-        every blocker and fall back to round-robin — the armed-or-warns
-        discipline graftlint enforces on ``_arm_*`` sites."""
-        self.dispatch_armed = False
-        if self.config.dispatch == "round-robin":
-            return    # explicitly requested baseline, not a fallback
-        blockers = [
-            f"replica {r.index} runs the "
-            f"'{r.engine.scheduler.policy}' scheduler policy (the "
-            f"predicted-TTFT model only describes 'continuous')"
-            for r in self.replicas
-            if r.engine.scheduler.policy != "continuous"]
-        if blockers:
-            logger.warning(
-                "fleet router: SLO-aware dispatch DISARMED — %s; "
-                "falling back to round-robin placement.",
-                "; ".join(blockers))
-            return
-        self.dispatch_armed = True
+    @property
+    def dispatch_armed(self) -> bool:
+        """SLO-aware placement unless round-robin was asked for."""
+        return self.config.dispatch != "round-robin"
 
+    # -- arming (DISARMED discipline) -----------------------------------
     def _arm_telemetry(self, spec):
         """Arm the router telemetry session (``router`` tracer lane +
         chaos instants via a weakref observer).  Disarmed fleets hold
@@ -309,9 +290,7 @@ class FleetRouter:
         (DISARMED) naming every blocker and keep the replica set fixed.
         Blockers: a role-split fleet (growing a replica means choosing
         its prefill/decode role — a placement policy this autoscaler
-        does not make), invalid bounds, and — when the predicted-TTFT
-        trigger is requested — any replica the estimator cannot
-        describe."""
+        does not make) and invalid bounds."""
         self.autoscale_armed = False
         self._autoscale = None
         self.scale_events: List[dict] = []
@@ -330,13 +309,6 @@ class FleetRouter:
             blockers.append(
                 f"invalid replica bounds "
                 f"[{cfg.min_replicas}, {cfg.max_replicas}]")
-        if cfg.scale_up_ttft_s > 0:
-            blockers.extend(
-                f"replica {r.index} runs the "
-                f"'{r.engine.scheduler.policy}' scheduler policy (the "
-                f"predicted-TTFT trigger only describes 'continuous')"
-                for r in self.replicas
-                if r.engine.scheduler.policy != "continuous")
         if blockers:
             logger.warning(
                 "fleet autoscaler: DISARMED — %s; the replica set stays "

@@ -135,9 +135,9 @@ class ServingMetrics:
     # -- request lifecycle ---------------------------------------------
     def record_submit(self, rid, klass=None):
         """``klass`` (e.g. "short"/"long" by prompt length) buckets this
-        request's eventual TTFT sample — the per-class view is how the
-        long-context bench proves chatty short requests keep their
-        latency while huge prompts prefill."""
+        request's eventual TTFT sample — the per-class view shows whether
+        chatty short requests keep their latency while huge prompts
+        prefill."""
         self._arrival[rid] = self._clock()
         if klass is not None:
             self._class_of[rid] = str(klass)

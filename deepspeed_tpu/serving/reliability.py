@@ -278,14 +278,6 @@ class Reliability:
                 "slo_ttft_s=%g is not positive; admission gate off, "
                 "overload will queue unboundedly.", cfg.slo_ttft_s)
             return
-        if self.engine.scheduler.policy != "continuous":
-            logger.warning(
-                "serving reliability: SLO shedding DISARMED — the "
-                "'%s' scheduler policy gates admission on batch "
-                "membership, which the predicted-TTFT model does not "
-                "describe; use policy='continuous'.",
-                self.engine.scheduler.policy)
-            return
         self.shedding_armed = True
 
     # -- predicted TTFT (the admission model) ---------------------------

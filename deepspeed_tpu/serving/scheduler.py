@@ -5,17 +5,8 @@ waiting queue (priority classes, FCFS within a class), the running-slot
 map, the single in-flight chunked prefill, and the victim choice for
 eviction.  The engine consults it between decode steps; every decision
 is deterministic (heap keyed on (priority, submit_seq)) so parity tests
-can replay exact schedules.
-
-Policies:
-
-- ``continuous`` (the point of this subsystem): a slot freed by a
-  finished/evicted/cancelled request is refilled on the very next step;
-- ``static`` (the naive baseline tools/serve_bench.py measures against):
-  admission only happens while the batch gate is open — the gate opens
-  when the engine fully drains and closes once the batch is formed, so
-  every batch runs to its slowest member like a classic batched
-  ``generate()`` call.
+can replay exact schedules.  A slot freed by a finished, evicted or
+cancelled request is refilled on the very next step.
 
 Eviction: when the KV pool cannot cover a growth or an admission, the
 victim is the least-important (highest priority value), youngest running
@@ -100,10 +91,8 @@ class Request:
 
 
 class Scheduler:
-    def __init__(self, max_slots: int, *, policy: str = "continuous"):
-        assert policy in ("continuous", "static"), policy
+    def __init__(self, max_slots: int):
         self.max_slots = int(max_slots)
-        self.policy = policy
         self._seq = itertools.count()
         self._waiting: List = []                  # heap of (key, rid)
         self.requests: Dict[int, Request] = {}    # every live request
@@ -114,11 +103,6 @@ class Scheduler:
         # prefill_done, and waits here FIFO while shorter prompts take a
         # turn.  Distinct from _requeue, which resets prefill progress.
         self.paused: List[Request] = []
-        # static-policy batch gate: a batch's MEMBERSHIP is fixed when it
-        # forms — the budget stops freed lanes from being refilled until
-        # the whole batch drains (that refill IS continuous batching)
-        self._gate_open = True
-        self._batch_left = self.max_slots
         self.chaos_step = 0
         # graceful drain (engine.request_drain / SIGTERM): admission
         # stops, in-flight work runs to completion, waiting requests
@@ -217,23 +201,12 @@ class Scheduler:
         return max(free, key=lambda s: (self.slot_ranker(s, req), -s))
 
     def may_admit(self) -> bool:
-        if self.draining:
-            return False
-        if self.policy == "continuous":
-            return True
-        return self._gate_open
-
-    def on_drained(self) -> None:
-        """Engine signal: no running, no prefilling — a static batch may
-        form again."""
-        if not self.running and self.prefilling is None:
-            self._gate_open = True
-            self._batch_left = self.max_slots
+        return not self.draining
 
     def start_admission(self) -> Optional[Request]:
         """Pop the next admissible request into the PREFILL state (the
         engine assigns shard + drives chunks).  None when no slot, no
-        candidate, or the static gate is closed."""
+        candidate, or the engine is draining."""
         if self.prefilling is not None or not self.may_admit():
             return None
         slot = self.free_slot(self.peek_waiting())
@@ -241,13 +214,7 @@ class Scheduler:
             return None
         req = self._pop_waiting()
         if req is None:
-            if self.policy == "static" and (self.running or self.prefilling):
-                self._gate_open = False   # batch formed: queue exhausted
             return None
-        if self.policy == "static":
-            self._batch_left -= 1
-            if self._batch_left <= 0:
-                self._gate_open = False   # batch formed: slots budgeted
         req.state = RequestState.PREFILL
         req.slot = slot
         self.prefilling = req
@@ -281,12 +248,6 @@ class Scheduler:
     def drop_prefill(self, req: Request, *, requeue: bool) -> None:
         assert req is self.prefilling
         self.prefilling = None
-        if self.policy == "static":
-            # the dropped request was the LAST admission: hand its batch
-            # budget back (and reopen the gate it may just have closed),
-            # or repeated drop/re-admit cycles shrink the batch
-            self._batch_left += 1
-            self._gate_open = True
         if requeue:
             self._requeue(req)
 

@@ -21,7 +21,7 @@ record per optimizer/serving step, flushed at every emit (optionally
 fsync'd) — the request-journal idiom from the serving reliability
 layer.  A crash can tear at most the final line; :meth:`replay`
 tolerates exactly that (a torn tail is skipped, every complete record
-is returned), so dead bench rounds still leave a readable step trail.
+is returned), so a run that died still leaves a readable step trail.
 """
 import json
 import os

@@ -6,8 +6,7 @@ questions:
 - **model FLOPs** (``model_flops_per_step``): the 6ND forward+backward
   formula (2ND forward-only for serving decode) — what the model
   mathematically requires.  ``MFU = model_flops / (step_time × devices
-  × peak)``; remat recompute and padding never inflate it (the same
-  convention as bench.py's TFLOPS claims).
+  × peak)``; remat recompute and padding never inflate it.
 - **hardware FLOPs**: summed ``compiled.cost_analysis()["flops"]`` over
   every registered jitted program × its calls per step — what XLA
   actually scheduled, including remat recompute, so
@@ -26,29 +25,33 @@ and never runs device code, but it IS a compile, so it stays off the
 hot path and outside any recompile-guard window.
 
 Peak FLOPS resolution: an explicit ``peak_tflops_per_device`` config
-wins; otherwise the device-kind table below (bf16 peaks); unknown kinds (CPU meshes) report achieved FLOPS with
-``mfu``/``hfu`` = None rather than a ratio against a guessed peak.
+wins; otherwise the device-kind table below (bf16 peaks); a kind that
+is not in it (a CPU mesh, a TPU the table has no row for) reports
+achieved FLOPS with ``mfu``/``hfu`` = None rather than a ratio against
+a guessed peak.
 """
 import threading
 
 import numpy as np
 
-# bf16 peak TFLOPS per chip by device-kind substring — the one table;
-# bench.py reads it too (v5e: Google Cloud documentation, "TPU v5e")
-PEAK_TFLOPS_TABLE = [
-    ("v6e", 918.0), ("v6", 918.0),
-    ("v5p", 459.0), ("v5e", 197.0), ("v5lite", 197.0), ("v5", 459.0),
-    ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
-]
+# bf16 peak TFLOPS per chip, keyed by the ``device_kind`` JAX reports,
+# lower-cased with the spaces out, and matched exactly (Google Cloud
+# documentation of each generation; v5e: "TPU v5e", 197)
+PEAK_TFLOPS_TABLE = {
+    "tpuv6lite": 918.0, "tpuv6e": 918.0,
+    "tpuv5p": 459.0, "tpuv5": 459.0,
+    "tpuv5lite": 197.0, "tpuv5e": 197.0,
+    "tpuv4": 275.0, "tpuv3": 123.0, "tpuv2": 45.0,
+}
 
 
 def peak_flops_per_device(device_kind):
-    """(peak FLOPS/s per device, known) for a device-kind string."""
-    kind = (device_kind or "").lower().replace(" ", "")
-    for key, peak in PEAK_TFLOPS_TABLE:
-        if key in kind:
-            return peak * 1e12, True
-    return None, False
+    """(peak FLOPS/s per device, known) for a device-kind string; a kind
+    without a row is unknown, never a neighbour's peak."""
+    peak = PEAK_TFLOPS_TABLE.get((device_kind or "").lower().replace(" ", ""))
+    if peak is None:
+        return None, False
+    return peak * 1e12, True
 
 
 def normalize_cost_analysis(compiled):

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache for the entry scripts.
 
-Called by the programs a user starts (chip_smoke.py, bench.py's worker,
-tools/serve_bench.py, tools/profile_step.py) before their first compile —
+Called by the programs a user starts (chip_smoke.py, benchmark/run.py)
+before their first compile —
 never on ``import deepspeed_tpu``, so a library user's own cache choice is
 left alone and the tests run with the cache off.
 """

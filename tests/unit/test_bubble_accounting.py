@@ -3,7 +3,7 @@
 The guard (test_schedule_quality_guard) is the analytic counterpart of
 comm_budget: if a schedule change regresses the interleaved or zero-bubble
 win at the canonical pipe=4/gas=8 point, the suite fails — the bubble
-claim in BENCH_NOTES.md is enforced, not aspirational."""
+claim in the schedule's docstring is enforced, not aspirational."""
 import pytest
 
 from deepspeed_tpu.runtime.pipe import bubble_accounting as ba
@@ -19,9 +19,9 @@ def test_1f1b_matches_closed_form():
             ba.ideal_1f1b_bubble(micros, stages), abs=1e-12)
 
 
-def test_round5_bench_notes_numbers():
-    """The numbers the round-5 bench quoted (gas=4): 0.20 at pipe=2,
-    0.43 at pipe=4."""
+def test_1f1b_bubble_fraction_at_gas4():
+    """The closed form's values at gas=4 that the module's docstring
+    quotes: 0.20 at pipe=2, 0.43 at pipe=4."""
     eq = ba.CostModel.equal_fwd_bwd()
     assert ba.bubble_report("1f1b", 4, 2, costs=eq)["bubble_fraction"] == \
         pytest.approx(0.20, abs=5e-3)

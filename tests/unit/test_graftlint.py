@@ -266,6 +266,22 @@ def test_nonexistent_root_raises_not_empty_scan(tmp_path):
     assert proc.returncode == 2 and "not found" in proc.stderr
 
 
+@pytest.mark.parametrize("name", ["HOT_FILES", "BENCH_FILES",
+                                  "TELEMETRY_FILES", "DEFAULT_ROOTS"])
+def test_every_path_a_rule_is_scoped_to_exists(name):
+    """A rule scoped to a file that is gone lints nothing and says
+    nothing: every path in host-sync's file sets, and every default
+    root, names something in the tree."""
+    from tools.graftlint.rules import host_sync
+
+    paths = core.DEFAULT_ROOTS if name == "DEFAULT_ROOTS" \
+        else getattr(host_sync, name)
+    assert paths
+    gone = sorted(p for p in paths
+                  if not os.path.exists(os.path.join(REPO, p)))
+    assert not gone, f"{name} names paths that do not exist: {gone}"
+
+
 def test_cli_relative_roots_resolve_from_user_cwd(tmp_path):
     """`python -m tools.graftlint mydir` from any cwd lints that dir."""
     tmp = str(tmp_path)
@@ -461,8 +477,9 @@ def test_host_sync_flags_plan_builder_in_hot_fn():
 
 @pytest.mark.parametrize("path", ["deepspeed_tpu/runtime/engine.py",
                                   "deepspeed_tpu/runtime/pipe/engine.py",
-                                  "bench.py", "tools/pipe_bench.py",
-                                  "tools/serve_bench.py"])
+                                  "benchmark/harness/drive_train.py",
+                                  "benchmark/harness/drive_serve.py",
+                                  "benchmark/tools/knee_sweep.py"])
 def test_host_sync_fires_in_hot_loop(path):
     got = lint(HS_HOT_LOOP_BAD, path, rules=["host-sync"])
     assert rule_names(got) == ["host-sync"], path
@@ -1402,9 +1419,9 @@ def test_host_sync_flags_measured_memory_read_in_hot_fn():
 
 
 def test_host_sync_flags_memory_read_in_bench_timed_region():
-    # bench files hold EVERY fn to the bar — the one blessed read in
-    # bench.py carries an inline suppression
-    got = lint(HS_MEMORY_READ_BAD, "bench.py", rules=["host-sync"])
+    # the timed drivers hold EVERY fn to the bar
+    got = lint(HS_MEMORY_READ_BAD, "benchmark/harness/drive_train.py",
+               rules=["host-sync"])
     assert rule_names(got) == ["host-sync"]
 
 

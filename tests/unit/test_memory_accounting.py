@@ -47,8 +47,16 @@ def test_normalize_memory_analysis_real_compiled():
     assert m["argument_bytes"] == (8 * 16 + 16 * 16) * 4
     assert m["output_bytes"] == 4
     assert m["temp_bytes"] is not None and m["temp_bytes"] >= 0
-    assert m["peak_bytes"] == (m["argument_bytes"] + m["output_bytes"]
-                               - m["alias_bytes"] + m["temp_bytes"])
+    # the backend's own peak wins where it reports one (buffers that are
+    # never live together share room, so it may be under the sum); the
+    # derived footprint is the fallback, and then it IS the sum
+    derived = (m["argument_bytes"] + m["output_bytes"]
+               - m["alias_bytes"] + m["temp_bytes"])
+    own = getattr(compiled.memory_analysis(), "peak_memory_in_bytes", None)
+    if own is None:
+        assert m["peak_bytes"] == derived
+    else:
+        assert m["peak_bytes"] == own and 0 < own <= derived
 
 
 def test_normalize_memory_analysis_variants():

@@ -183,11 +183,11 @@ def test_env_report_names_the_backend(capsys):
 
 
 def test_parents_of_chip_processes_stay_off_jax():
-    """A chip belongs to one process: bench.py's parent and the launchers
-    start the child that needs it, so importing them must not touch JAX."""
+    """A chip belongs to one process: the launchers start the child
+    that needs it, so importing them must not touch JAX."""
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    code = ("import sys, bench, deepspeed_tpu.launcher.launch, "
+    code = ("import sys, deepspeed_tpu.launcher.launch, "
             "deepspeed_tpu.launcher.runner; "
             "sys.exit('jax' in sys.modules)")
     assert subprocess.run([sys.executable, "-c", code], cwd=repo,

@@ -24,7 +24,8 @@ from deepspeed_tpu.runtime.resilience.watchdog import TrainingWatchdog
 from deepspeed_tpu.serving.engine import InferenceEngine
 from deepspeed_tpu.serving.kv_cache import PagedKVPool, pool_shapes
 from deepspeed_tpu.serving.metrics import CompilationCounter, ServingMetrics
-from deepspeed_tpu.serving.scheduler import Request, Scheduler
+from deepspeed_tpu.serving.scheduler import (Request, RequestState,
+                                              Scheduler)
 
 
 @pytest.fixture(scope="module")
@@ -478,39 +479,32 @@ def test_scheduler_victim_policy():
     assert s.victim(for_req=newcomer, admission=True, shard=3) is None
 
 
-def test_scheduler_static_gate_drains_between_batches():
-    s = Scheduler(2, policy="static")
-    for rid in range(4):
-        s.submit(_req(rid))
-    a = s.start_admission(); s.promote(a)
-    b = s.start_admission(); s.promote(b)
-    assert {a.rid, b.rid} == {0, 1}
-    # batch formed: the gate closes until the engine drains
-    assert s.start_admission() is None
-    s.finish(a)
-    s.on_drained()
-    assert s.start_admission() is None, "gate must stay shut mid-batch"
-    s.finish(b)
-    s.on_drained()
-    c = s.start_admission()
-    assert c is not None and c.rid == 2
-
-
-def test_scheduler_static_budget_restored_on_dropped_prefill():
-    """A prefill the engine drops (pool pressure) hands its batch budget
-    back — repeated drop/re-admit cycles must not shrink the batch."""
-    s = Scheduler(2, policy="static")
+def test_scheduler_dropped_prefill_is_the_next_admission_again():
+    """A prefill the engine drops (pool pressure) goes back in line at
+    its own FCFS age with its progress reset, and the slot it held is
+    free for the request behind it."""
+    s = Scheduler(2)
     for rid in range(3):
         s.submit(_req(rid))
     a = s.start_admission()
+    a.prefill_done = 4                    # a chunk had been written
     s.drop_prefill(a, requeue=True)       # engine couldn't fit it
+    assert s.prefilling is None and s.queue_depth() == 3
+    assert (a.state, a.slot, a.prefill_done) == (RequestState.WAITING,
+                                                 None, 0)
     a2 = s.start_admission()
-    assert a2.rid == a.rid                # FCFS: same request retries
+    assert a2 is a and a2.slot == 0       # FCFS: same request retries
     s.promote(a2)
     b = s.start_admission()
-    assert b is not None, "budget leaked: batch closed after 1 member"
+    assert b.rid == 1 and b.slot == 1     # the slot behind it is free
     s.promote(b)
-    assert s.start_admission() is None    # budget of 2 now spent
+    assert s.start_admission() is None    # both slots running
+    # without requeue the request leaves the line for good
+    s.finish(a2)
+    c = s.start_admission()
+    s.drop_prefill(c, requeue=False)
+    assert c.rid == 2 and s.prefilling is None
+    assert s.start_admission() is None and s.queue_depth() == 0
 
 
 def test_admission_spreads_across_shard_pools(toy, eight_devices):
@@ -630,39 +624,12 @@ def test_watchdog_heartbeats_every_step(toy):
 
 
 # ---------------------------------------------------------------------------
-# continuous vs static throughput (the serve_bench claim, in miniature)
-# ---------------------------------------------------------------------------
-
-def test_continuous_beats_static_batching(toy):
-    """Mixed output lengths: static batching burns slot-steps running
-    every batch to its slowest member; continuous refills freed lanes
-    next step.  >= 1.3x tokens per slot-step (the deterministic
-    hardware-time proxy tools/serve_bench.py reports)."""
-    model, params, _ = toy
-    rng = np.random.default_rng(11)
-    prompts = _prompts(11, rng.integers(4, 8, 16))
-    maxnew = [2 if i % 2 == 0 else 24 for i in range(16)]
-
-    def run(policy):
-        eng = _engine(model, params, max_slots=4, policy=policy)
-        for p, m in zip(prompts, maxnew):
-            eng.submit(p, max_new_tokens=m)
-        eng.serve(max_steps=1000)
-        rep = eng.serving_report()
-        assert rep["requests"]["completed"] == len(prompts)
-        return rep["throughput"]["tokens_per_slot_step"]
-
-    cont, static = run("continuous"), run("static")
-    assert cont >= 1.3 * static, (cont, static)
-
-
-# ---------------------------------------------------------------------------
 # prefix cache + speculative decode (ISSUE 17)
 # ---------------------------------------------------------------------------
 
 def _shared_prefix_prompts(seed, n, prefix_len=16, tail=(2, 5)):
     """System-prompt traffic in miniature: one shared prefix, short
-    random tails — the serve_bench ``shared-prefix`` shape."""
+    random tails."""
     rng = np.random.default_rng(seed)
     prefix = rng.integers(0, 97, prefix_len).astype(np.int32)
     return [np.concatenate(
@@ -702,9 +669,9 @@ def test_parity_cache_and_spec_matrix(toy, cache, spec):
 
 
 def test_prefix_cache_prefill_ratio_guard(toy):
-    """The serve_bench shared-prefix gate in miniature (tier-1, like the
-    1.3x continuous-batching guard): the radix cache computes >= 2x
-    fewer prefill tokens than the cache-off run of the SAME traffic."""
+    """Shared-prefix traffic in miniature (tier-1): the radix cache
+    computes >= 2x fewer prefill tokens than the cache-off run of the
+    SAME traffic."""
     model, params, ref = toy
     prompts = _shared_prefix_prompts(22, 6)
     maxnew = [4, 6, 3, 5, 4, 6]

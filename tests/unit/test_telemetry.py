@@ -219,16 +219,28 @@ def test_mfu_report_math():
     assert rep["peak_known"] and rep["hw_flops_complete"]
 
 
-def test_mfu_peak_table_matches_bench():
-    import bench
+def test_mfu_peak_table_matches_benchmark_peaks():
+    """Every kind the benchmark's table knows has the same peak here,
+    and a kind without a row is unknown, not its neighbour."""
+    import importlib.util
 
-    for kind, expect in (("TPU v5 lite", 197.0), ("TPU v4", 275.0)):
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    spec = importlib.util.spec_from_file_location(
+        "_benchmark_device",
+        os.path.join(repo, "benchmark", "harness", "device.py"))
+    device = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(device)
+    assert "TPU v5 lite" in device.PEAKS
+    for kind, row in device.PEAKS.items():
+        got, known = peak_flops_per_device(kind)
+        assert known and got == pytest.approx(row["bf16_flops_per_s"])
+    for kind, expect in (("TPU v5 lite", 197.0), ("tpuv5lite", 197.0),
+                         ("TPU v5p", 459.0), ("TPU v4", 275.0)):
         got, known = peak_flops_per_device(kind)
         assert known and got == pytest.approx(expect * 1e12)
-        assert bench._peak_tflops(kind) == pytest.approx(expect)
-    assert peak_flops_per_device("weird-cpu") == (None, False)
-    with pytest.raises(ValueError, match="weird-cpu"):
-        bench._peak_tflops("weird-cpu")
+    for kind in ("TPU v5 ultra", "TPU v5x", "v5", "weird-cpu", None):
+        assert peak_flops_per_device(kind) == (None, False)
     assert model_flops_per_step(10, 5) == 300.0
     assert model_flops_per_step(10, 5, fwd_only=True) == 100.0
 
@@ -571,103 +583,6 @@ def test_serving_abort_events_traced(serving_toy):
     eng.step()
     names = [e["name"] for e in eng.telemetry.tracer.events()]
     assert "abort_expired" in names
-
-
-# ---------------------------------------------------------------------------
-# perf trend tool
-# ---------------------------------------------------------------------------
-
-def _bench_round(tmp_path, n, payload, wrapped=True):
-    doc = {"n": n, "cmd": "bench", "rc": 0,
-           "tail": json.dumps(payload) + "\n"} if wrapped else payload
-    (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
-
-
-def test_perf_trend_rows_and_regression(tmp_path):
-    from tools import perf_trend
-
-    metric = "gpt2 seq1024 train TFLOPS/chip"
-    _bench_round(tmp_path, 1, {"metric": metric, "value": 20.0,
-                               "unit": "TFLOPS/chip", "mfu": 0.10,
-                               "step_ms": 700.0})
-    # dead round: rc!=0, traceback tail — a GAP, not a zero
-    (tmp_path / "BENCH_r02.json").write_text(
-        json.dumps({"n": 2, "cmd": "bench", "rc": 1, "tail": "Trace"}))
-    _bench_round(tmp_path, 3, {"metric": metric, "value": 26.4,
-                               "unit": "TFLOPS/chip", "mfu": 0.134,
-                               "step_ms": 660.0,
-                               "telemetry": {"trace": "t.json",
-                                             "metrics_jsonl": "m.jsonl"}},
-                 wrapped=False)
-    rows = perf_trend.trend_rows(perf_trend.load_rounds(root=str(tmp_path)))
-    assert [r["ok"] for r in rows] == [True, False, True]
-    assert rows[2]["trace"] == "t.json"
-    v = perf_trend.check_regression(rows)
-    assert not v["regressed"] and v["comparable_rounds"] == 1
-
-    # a >10% drop on the SAME metric regresses
-    _bench_round(tmp_path, 4, {"metric": metric, "value": 20.0,
-                               "unit": "TFLOPS/chip", "mfu": 0.10})
-    rows = perf_trend.trend_rows(perf_trend.load_rounds(root=str(tmp_path)))
-    v = perf_trend.check_regression(rows)
-    assert v["regressed"] and v["baseline"]["round"] == 3
-    assert perf_trend.main(["--root", str(tmp_path), "--check"]) == 1
-
-    # a different metric string never gates against it
-    _bench_round(tmp_path, 5, {"metric": "other A/B", "value": 1.0,
-                               "unit": "x"})
-    rows = perf_trend.trend_rows(perf_trend.load_rounds(root=str(tmp_path)))
-    v = perf_trend.check_regression(rows)
-    assert not v["regressed"] and v["comparable_rounds"] == 0
-
-
-def test_perf_trend_payload_appends_current_round(tmp_path):
-    from tools import perf_trend
-
-    _bench_round(tmp_path, 1, {"metric": "m", "value": 10.0, "unit": "u"})
-    out = perf_trend.trend_payload(root=str(tmp_path),
-                                   latest={"metric": "m", "value": 5.0,
-                                           "unit": "u"})
-    assert out["regression"]["regressed"]
-    assert [r["round"] for r in out["rounds"]] == [1, 2]
-    assert out["dead_rounds"] == []
-
-
-def test_perf_trend_optimizer_wire_gaps_honest(tmp_path):
-    """PR 18: the 0/1 Adam optimizer-wire scalar trends only on rounds
-    that ran the --optimizer zeroone A/B; rounds without it show None
-    (an honest gap), never a zero-byte wire or a fake vs-qgZ win."""
-    from tools import perf_trend
-
-    _bench_round(tmp_path, 1, {"metric": "dense TFLOPS", "value": 20.0,
-                               "unit": "TFLOPS/chip"})
-    _bench_round(tmp_path, 2, {
-        "metric": "0/1 Adam post-freeze step time vs fused Adam",
-        "value": 1.02, "unit": "x step-time vs dense Adam",
-        "optimizer_wire_bytes_per_step": 48480320,
-        "optimizer_wire_vs_qgz": 0.152})
-    rows = perf_trend.trend_rows(perf_trend.load_rounds(root=str(tmp_path)))
-    assert rows[0]["optimizer_wire_bytes_per_step"] is None
-    assert rows[0]["optimizer_wire_vs_qgz"] is None
-    assert rows[1]["optimizer_wire_bytes_per_step"] == 48480320
-    out = perf_trend.trend_payload(root=str(tmp_path))
-    assert out["rounds"][0]["optimizer_wire_vs_qgz"] is None
-    assert out["rounds"][1]["optimizer_wire_vs_qgz"] == 0.152
-
-
-def test_perf_trend_real_repo_rounds_parse():
-    """The real BENCH_r*.json history (wrapper format, truncated tails)
-    must load without crashing and expose r03's published number."""
-    from tools import perf_trend
-
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    rows = perf_trend.trend_rows(perf_trend.load_rounds(root=repo))
-    if not rows:
-        pytest.skip("no BENCH_r*.json in repo root")
-    ok = [r for r in rows if r["ok"]]
-    assert any(r["round"] == 3 and r["value"] == pytest.approx(26.43)
-               for r in ok)
 
 
 # ---------------------------------------------------------------------------

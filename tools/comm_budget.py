@@ -31,7 +31,7 @@ BUDGET_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "comm_budgets.json")
 GROWTH_TOLERANCE = 0.10
 
-# GPT-2 350M-ish decoder shapes (what bench.py trains): embeddings + 24
+# GPT-2 350M-ish decoder shapes (the benchmark's gpt2-350m): embeddings + 24
 # blocks of qkv/proj/mlp + layernorms.  Shapes only — no model is built.
 _H, _L, _V, _S = 1024, 24, 50304, 1024
 GPT2ISH = (

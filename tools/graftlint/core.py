@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Sequence
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-DEFAULT_ROOTS = ("deepspeed_tpu", "tools", "tests", "bench.py")
+DEFAULT_ROOTS = ("deepspeed_tpu", "tools", "tests")
 DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "baseline.json")
 

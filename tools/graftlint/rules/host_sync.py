@@ -125,7 +125,9 @@ HOT_FN_RE = re.compile(
     r"|active_row|prefill_active_row|window_expired_free)$")
 # benchmark drivers: every loop is (or brackets) a timed region — a sync
 # per iteration pollutes the measured step time with transfer latency
-BENCH_FILES = {"bench.py", "tools/pipe_bench.py", "tools/serve_bench.py"}
+BENCH_FILES = {"benchmark/harness/drive_train.py",
+               "benchmark/harness/drive_serve.py",
+               "benchmark/tools/knee_sweep.py"}
 # telemetry: the whole package is hot-path by contract (span emit runs
 # once per instruction/step inside the engines' dispatch loops, and the
 # armed-overhead bound is a tier-1 test) — every function is held to the

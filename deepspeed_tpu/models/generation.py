@@ -133,10 +133,11 @@ class GPT2Decoder:
     (``serving/decoder.py``), made of the functions above: learned
     positions in the embedding, ``c_attn`` split into equal Q, K and V
     heads, the shared masked core over the gathered page view, ``c_proj``,
-    the GELU MLP, LayerNorm and the tied head."""
+    the GELU MLP, LayerNorm and the tied head.  Served from the tree
+    :meth:`hold` states: the weights in ``cfg.dtype``, cast once by the
+    engine and never in a program; LayerNorm's in f32."""
 
     stat_names = ()             # no block reports counters
-    weights_dtype = None        # held as given (f32), cast in the program
     scan_layers = False         # the engine's loop; the programs as they were
 
     def __init__(self, cfg):
@@ -148,6 +149,16 @@ class GPT2Decoder:
         self.cfg = cfg
         self.dtype = cfg.dtype
         self.n_layer = cfg.n_layer
+
+    def hold(self, params):
+        """What ``_dense``, ``embed`` and the tied head cast, in the dtype
+        they cast to; the LayerNorm leaves as given, for ``_ln`` reads
+        them in f32.  Their ``.astype`` calls stay: no-ops on this tree,
+        and ``generate`` shares them over a tree as given."""
+        from deepspeed_tpu.serving.decoder import held_as
+
+        return held_as(params, self.dtype, keep=lambda path: any(
+            str(getattr(k, "key", "")).startswith("ln_") for k in path))
 
     def embed(self, params, tokens, positions):
         return params["wte"].astype(self.dtype)[tokens] \

@@ -246,8 +246,12 @@ class Mistral4Decoder:
     def __init__(self, cfg):
         self.cfg = cfg
         self.dtype = cfg.dtype
-        self.weights_dtype = cfg.dtype
         self.n_layer = cfg.num_hidden_layers
+
+    def hold(self, params):
+        from deepspeed_tpu.serving.decoder import held_as
+
+        return held_as(params, self.dtype)
 
     def embed(self, params, tokens, positions):
         return params["embed"][tokens]          # positions enter by RoPE

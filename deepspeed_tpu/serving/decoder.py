@@ -11,9 +11,18 @@ each, ``(H*D, H*D)`` for keys and values where it states none,
 
 ``dtype``
     the compute dtype.
-``weights_dtype``
-    the dtype the engine HOLDS the served weights in (it casts once, at
-    construction); ``None``: as given.
+``hold(params) -> params``
+    the tree the engine HOLDS the served weights in: the model casts, once,
+    the leaves its block would otherwise cast in every program, and leaves
+    the rest as given (:func:`held_as` does it in ONE program that keeps
+    each leaf's sharding).  The engine calls it once, at construction, and
+    keeps nothing else: a caller that drops its own tree frees it.
+    Idempotent: a tree already held comes back as the same object, no
+    program run, so ``FleetRouter`` holds once and its replicas share.
+    ``Mistral4Decoder``: every floating leaf in ``cfg.dtype``.
+    ``GPT2Decoder``: the same but for its LayerNorm leaves, which ``_ln``
+    reads in f32.  ``bf16(w)`` computed once is ``bf16(w)`` computed in
+    every program: the served tokens and logits are the same bits.
 ``stat_names``
     names of the int32 counters a block reports a step (``()``: none, and
     the programs have the outputs they always had).  The engine sums them
@@ -57,11 +66,37 @@ programs.  The other variants (``speculative``, ``sparse_context``,
 ``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off) refuse
 it by name with :class:`UnsupportedForModel`.
 """
+import functools
+
+import jax
+import jax.numpy as jnp
 
 
 class UnsupportedForModel(ValueError):
     """An engine variant that knows keys and values only was asked to
     serve a model that caches something else."""
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def held_as(params, dtype, keep=lambda path: False):
+    """``params`` with every floating leaf in ``dtype``, but for the leaves
+    whose key path ``keep`` names, which stay as given: what a decoder's
+    ``hold`` is made of.  ONE program over the leaves that differ; where
+    none does, ``params`` itself."""
+    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+    cast = [i for i, (path, leaf) in enumerate(leaves)
+            if jnp.issubdtype(leaf.dtype, jnp.floating)
+            and leaf.dtype != dtype and not keep(path)]
+    if not cast:
+        return params
+    out = [leaf for _, leaf in leaves]
+    for i, leaf in zip(cast, _cast([out[i] for i in cast], dtype)):
+        out[i] = leaf
+    return jax.tree_util.tree_unflatten(tree, out)
 
 
 def decoder_for(cfg):

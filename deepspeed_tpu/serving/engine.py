@@ -607,17 +607,9 @@ class InferenceEngine:
         else:
             assert shards == 1, "shards > 1 requires a mesh"
         self.model, self.cfg, self.dec = model, cfg, dec
-        # served weights are held in the dtype the model states (a model
-        # that states none is held as given): cast once, here
-        held = dec.weights_dtype
-        if held is not None and any(
-                jnp.issubdtype(l.dtype, jnp.floating) and l.dtype != held
-                for l in jax.tree_util.tree_leaves(params)):
-            params = jax.jit(lambda tree: jax.tree_util.tree_map(
-                lambda l: l.astype(held)
-                if jnp.issubdtype(l.dtype, jnp.floating) else l, tree))(
-                    params)
-        self.params = params
+        # the served weights as the model holds them (decoder.py, ``hold``):
+        # cast once, here, and the tree as given is not kept
+        self.params = dec.hold(params)
         self.max_slots = int(max_slots)
         self.shards = int(shards)
         self.mesh = mesh
@@ -1425,6 +1417,13 @@ class InferenceEngine:
         short prompt per bucket plus ONE prompt longer than
         prefill_chunk (iff any admissible prompt is) covers everything."""
         assert not self.scheduler.has_work(), "warmup on a busy engine"
+        if self._tracer is not None:
+            # the width a reader should price the weights at: what is held
+            from deepspeed_tpu.runtime.memory_accounting import \
+                tree_device_bytes
+
+            self._tracer.count("served_weight_bytes", self._lane_serve,
+                               tree_device_bytes(self.params))
         # warmup traffic is synthetic: bypass the admission gate and the
         # journal (a recovery replay must never see throwaway requests)
         self._warming = True

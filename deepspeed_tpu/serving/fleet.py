@@ -63,6 +63,7 @@ from deepspeed_tpu.runtime.resilience import chaos
 from deepspeed_tpu.runtime.resilience.watchdog import (ACTION_CONTINUE,
                                                        EVENT_STALL,
                                                        TrainingWatchdog)
+from deepspeed_tpu.serving.decoder import decoder_for
 from deepspeed_tpu.serving.engine import InferenceEngine
 from deepspeed_tpu.serving.reliability import (ABORT_POISONED,
                                                RequestJournal)
@@ -176,7 +177,9 @@ class FleetRouter:
         # the SAME spec as the founding set (and shares the lru-cached
         # compiled programs, so growing costs no recompile)
         self._model = model
-        self._params = params
+        # held ONCE, as the model states (decoder.py, ``hold``): every
+        # replica's engine finds the tree held and shares it
+        self._params = decoder_for(model.config).hold(params)
         self._engine_kwargs = dict(engine_kwargs or {})
         self._reliability_spec = dict(reliability or {})
         self._journal_dir = journal_dir

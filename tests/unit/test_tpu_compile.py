@@ -3,6 +3,7 @@ with interpret=False and compiled by the TPU compiler for a described (not
 attached) v5e chip at real widths.  Nothing runs, so this says nothing about
 results or times — it catches what interpret mode cannot: block shapes the
 Mosaic lowering refuses and memory a kernel may not use."""
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
@@ -171,39 +172,32 @@ def test_kernels_compile_inside_a_four_chip_program(kernel, v5e_host,
 SERVE_CHAT = dict(slots=28, pages=64, block=16, chunk=256)   # the chat cell
 
 
-@pytest.mark.parametrize("pool", [
-    "bf16",
-    pytest.param("int8", marks=pytest.mark.xfail(
-        strict=True,
-        reason="the int8 K and V are updated in place, but both f32 scale "
-               "tensors (minor dim 16 heads, padded to 128 lanes) are "
-               "relaid on the way in and out: PERF.md section 7"))])
-@pytest.mark.parametrize("program", ["decode", "prefill256",
-                                     "prefill256-final"])
-def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
-    """The KV pool's layout (``kv_cache.pool_shapes``) is the one the
-    compiler runs the per-layer scatter and page gather in: at the chat
-    cell's sizes and gpt2-350m widths no program relays a pool tensor (no
-    ``copy`` of a pool tensor's shape), the temporaries stay under ONE
-    pool tensor and the donated pool comes back in the same buffers.  The
-    bf16 pool is compiled at the cell's 24 layers, because the temporaries
-    hold the weights' bf16 casts and so grow with depth as the pool does;
-    the int8 pool, which no cell runs, at 2 layers and for the copies
-    alone (they sit at a program's edge, whatever the depth).
+def _served_shapes(model, struct, held=True):
+    """The model's parameters as shapes, as the engine holds them: through
+    the model's own ``hold`` (``serving/decoder.py``), nothing run
+    (``held=False``: as a trainer leaves them, f32)."""
+    from deepspeed_tpu.serving.decoder import decoder_for
 
-    Nor does a bf16 program hold one layer of the pool on its own (ONE
-    gather over (layer, page), ``_pool_view``), and the decode program
-    builds no view at all: the paged kernel reads the pool where it lies."""
-    import re
+    ids = np.zeros((1, 8), np.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            {"input_ids": ids, "labels": ids})
+    if held:
+        params = jax.eval_shape(decoder_for(model.config).hold, params)
+    return jax.tree_util.tree_map(lambda l: struct(l.shape, l.dtype), params)
 
+
+@functools.lru_cache(maxsize=None)      # two tests read each program
+def _chat_program(program, quantized, v5e, held=True):
+    """One serving program at the chat cell's sizes and gpt2-350m widths,
+    compiled for the described chip with arguments as the engine holds
+    them (``held=False``: as a trainer leaves them, f32): ``(compiled,
+    cfg, params, pool tensors)``."""
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
     from deepspeed_tpu.serving import engine as serving
     from deepspeed_tpu.serving.kv_cache import pool_shapes
-    from tools.graftlint import hlo_contracts as hc
 
     S, W, bs, C = (SERVE_CHAT[k] for k in ("slots", "pages", "block",
                                            "chunk"))
-    quantized = pool == "int8"
     model = GPT2Model(GPT2Config(
         vocab_size=50257, n_positions=1024, n_embd=1024,
         n_layer=2 if quantized else 24, n_head=16, dtype=jnp.bfloat16,
@@ -213,11 +207,7 @@ def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    ids = np.zeros((1, 8), np.int32)
-    params = jax.tree_util.tree_map(
-        lambda l: struct(l.shape, l.dtype),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                       {"input_ids": ids, "labels": ids}))
+    params = _served_shapes(model, struct, held)
     store = jnp.int8 if quantized else cfg.dtype
     tensors = [struct(shape, dtype) for shape, dtype in
                zip(pool_shapes(cfg, 1 + S * W, bs, quantized),
@@ -237,6 +227,83 @@ def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
                    struct((), jnp.int32), struct((1,), jnp.int32),
                    struct((), jnp.int32)]
     compiled = jitted.lower(params, *tensors, *streams).compile()
+    return compiled, cfg, params, tensors
+
+
+CHAT_PROGRAMS = ["decode", "prefill256", "prefill256-final"]
+
+
+@pytest.mark.parametrize("program", CHAT_PROGRAMS)
+def test_serving_programs_cast_no_weight(program, v5e):
+    """The engine holds GPT-2's weights in the dtype its programs compute
+    in (``GPT2Decoder.hold``), so no program of the chat cell casts one:
+    no ``convert`` whose result is a bf16 array of a weight's shape, and
+    the arguments are 0.71 GB smaller than the f32 tree's (which every
+    program used to read whole, 1.42 GB, and write again at half the
+    width, before its first matmul).  LayerNorm's leaves stay f32."""
+    import re
+
+    compiled, cfg, params, tensors = _chat_program(program, False, v5e)
+    L, E, V = cfg.n_layer, cfg.n_embd, params["wte"].shape[0]
+    assert V == 50304
+    weights = [(L, 4 * E, E), (L, E, 4 * E), (L, E, 3 * E), (L, E, E),
+               (V, E)]
+    # the program's own instructions, whose results lie in memory (a
+    # fusion's inner instructions do not: the one-row head is a fused
+    # multiply and sum that rounds its products as it goes)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    casts = [line.strip()[:120] for line in entry.splitlines()
+             if "convert" in line and any(
+                 re.search(rf"= bf16\[{','.join(map(str, dims))}\]", line)
+                 for dims in weights)]
+    assert not casts, f"{program} casts a weight it holds: {casts}"
+    cast_values = sum(l.size for l in jax.tree_util.tree_leaves(params)
+                      if l.dtype == jnp.bfloat16)
+    assert {jax.tree_util.keystr(path) for path, l in
+            jax.tree_util.tree_flatten_with_path(params)[0]
+            if l.dtype == jnp.float32} == {
+        f"['{at}']['{leaf}']" if at == "ln_f"
+        else f"['h']['block']['{at}']['{leaf}']"
+        for at in ("ln_1", "ln_2", "ln_f") for leaf in ("scale", "bias")}
+    assert 0.70e9 < 2 * cast_values < 0.72e9    # what an f32 tree adds
+    held = sum(l.size * l.dtype.itemsize
+               for l in jax.tree_util.tree_leaves(params))
+    # what the compiler takes as arguments: the held tree, the pool and
+    # the streams
+    pool = sum(t.size * t.dtype.itemsize for t in tensors)
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert abs(args - held - pool) < 1e5, (args, held, pool)
+
+
+@pytest.mark.parametrize("pool", [
+    "bf16",
+    pytest.param("int8", marks=pytest.mark.xfail(
+        strict=True,
+        reason="the int8 K and V are updated in place, but both f32 scale "
+               "tensors (minor dim 16 heads, padded to 128 lanes) are "
+               "relaid on the way in and out: PERF.md section 7"))])
+@pytest.mark.parametrize("program", CHAT_PROGRAMS)
+def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
+    """The KV pool's layout (``kv_cache.pool_shapes``) is the one the
+    compiler runs the per-layer scatter and page gather in: at the chat
+    cell's sizes and gpt2-350m widths no program relays a pool tensor (no
+    ``copy`` of a pool tensor's shape), the temporaries stay under ONE
+    pool tensor and the donated pool comes back in the same buffers.  The
+    bf16 pool is compiled at the cell's 24 layers, as the cell runs it;
+    the int8 pool, which no cell runs, at 2 layers and for the copies
+    alone (they sit at a program's edge, whatever the depth).
+
+    Nor does a bf16 program hold one layer of the pool on its own (ONE
+    gather over (layer, page), ``_pool_view``), and the decode program
+    builds no view at all: the paged kernel reads the pool where it lies."""
+    import re
+
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs = (SERVE_CHAT[k] for k in ("slots", "pages", "block"))
+    quantized = pool == "int8"
+    compiled, cfg, _, tensors = _chat_program(program, quantized, v5e)
     text = compiled.as_text()
 
     shapes = {",".join(str(d) for d in t.shape) for t in tensors}
@@ -286,11 +353,7 @@ def test_decode_program_keeps_the_view_where_pages_are_not_whole_tiles(v5e):
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    ids = np.zeros((1, 8), np.int32)
-    params = jax.tree_util.tree_map(
-        lambda l: struct(l.shape, l.dtype),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                       {"input_ids": ids, "labels": ids}))
+    params = _served_shapes(model, struct)
     tensors = [struct(shape, cfg.dtype) for shape in
                pool_shapes(cfg, 1 + S * W, bs, False) if shape is not None]
     streams = [struct((S, W), jnp.int32)] + [
@@ -324,11 +387,8 @@ def test_sharded_decode_program_runs_the_paged_kernel_on_each_chip(v5e_host):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, P(*spec)))
 
-    ids = np.zeros((1, 8), np.int32)
-    params = jax.tree_util.tree_map(
-        lambda l: struct(l.shape, l.dtype),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                       {"input_ids": ids, "labels": ids}))
+    params = _served_shapes(model, struct)
+    assert params["wte"].dtype == jnp.bfloat16
     tensors = [struct(shape, cfg.dtype, None, "data") for shape in
                pool_shapes(cfg, 4 + S * W, bs, False) if shape is not None]
     streams = [struct((S, W), jnp.int32, "data")] + [

@@ -11,10 +11,13 @@ ROUTED feed-forward beside a shared expert, no biases, an untied head.
   YaRN's ``mscale_all_dim`` squared, and ``llama_4_scaling_beta`` scales
   the query by ``1 + beta ln(1 + floor(pos / original_max))``.  WHAT IS
   CACHED is ``c_kv`` and ``k_r``: ``kv_lora_rank + qk_rope_head_dim`` values
-  a token, one row, no separate value (``cache_rows``).  Prefill expands
-  keys and values from the gathered latent rows and attends with the
-  rectangle kernel; decode absorbs ``W_kvb`` into the query and the output
-  and attends over the latent rows themselves.  Same mathematics.
+  a token, one raw row, no separate value (``cache_rows``,
+  ``cache_kind``).  Prefill expands keys and values from the gathered
+  latent rows and attends with the rectangle kernel; decode absorbs
+  ``W_kvb`` into the query and the output and attends over the latent rows
+  themselves.  Same mathematics, and ONE function for every latent model:
+  ``models/latent_attention.py``, which this model calls with its YaRN
+  table and its position-dependent query scale.
 - Feed-forward: ``moe/dropless.py`` over the experts this chip holds
   (``experts_held``), plus the shared expert once.
 
@@ -31,10 +34,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.models.latent_attention import (_rms_norm, _swiglu,
+                                                   latent_attention)
 from deepspeed_tpu.moe.dropless import STAT_NAMES, dropless_moe
 from deepspeed_tpu.moe.grouped_matmul import KERNEL_NAME
-from deepspeed_tpu.ops.transformer.rect_attention import (
-    mla_decode_attention, rect_flash_attention)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,6 +97,8 @@ class Mistral4Config:
         """Widths of the rows a layer caches a token: one latent row."""
         return (self.kv_lora_rank + self.qk_rope_head_dim,)
 
+    cache_kind = "rows"     # raw rows, no (keys, values) of heads in them
+
     def decoder(self):
         return Mistral4Decoder(self)
 
@@ -134,30 +139,6 @@ def _rope_cos_sin(cfg, positions):
     scale = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
         / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
-
-
-def _rope_interleaved(x, cos, sin):
-    """Rotate the pairs (x[2i], x[2i+1]) of the last dim; cos/sin broadcast
-    against (..., d / 2).  f32 in, f32 out."""
-    pairs = x.reshape(*x.shape[:-1], -1, 2)
-    a, b = pairs[..., 0], pairs[..., 1]
-    return jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1) \
-        .reshape(x.shape)
-
-
-def _rms_norm(x, weight, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
-                            + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def _swiglu(x, p):
-    gate_up = (x @ p["gate_up"]).astype(jnp.float32)
-    inner = p["down"].shape[0]
-    h = (jax.nn.silu(gate_up[..., :inner]) * gate_up[..., inner:]) \
-        .astype(x.dtype)
-    return h @ p["down"]
 
 
 # one layer's matrices outside its routed experts: name -> shape
@@ -295,56 +276,12 @@ class Mistral4Decoder:
 
     def _attention(self, bp, x, cache):
         cfg = self.cfg
-        B, T, _ = x.shape
-        H, R = cfg.num_attention_heads, cfg.kv_lora_rank
-        Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
-            cfg.v_head_dim
         pos = cache.positions                               # (B, T)
         cos, sin = _rope_cos_sin(cfg, pos)                  # (B, T, Dr/2)
-
-        c_q = _rms_norm(x @ bp["q_a"], bp["q_a_norm"], cfg.rms_norm_eps)
-        q = (c_q @ bp["q_b"]).reshape(B, T, H, Dn + Dr).astype(jnp.float32)
         # the softmax scale and the position-dependent query scale, once
-        q = q * (softmax_scale(cfg) * (
+        q_scale = softmax_scale(cfg) * (
             1.0 + cfg.llama_4_scaling_beta * jnp.log1p(jnp.floor(
                 pos.astype(jnp.float32)
-                / cfg.rope_original_max_position_embeddings))))[..., None,
-                                                                 None]
-        q_nope = q[..., :Dn].astype(x.dtype)
-        q_rope = _rope_interleaved(q[..., Dn:], cos[:, :, None],
-                                   sin[:, :, None]).astype(x.dtype)
-
-        kv = x @ bp["kv_a"]                                 # (B, T, R + Dr)
-        c_kv = _rms_norm(kv[..., :R], bp["kv_a_norm"], cfg.rms_norm_eps)
-        k_rope = _rope_interleaved(kv[..., R:].astype(jnp.float32), cos,
-                                   sin).astype(x.dtype)
-        cache.write_rows(0, jnp.concatenate([c_kv, k_rope], axis=-1)
-                         .reshape(B * T, R + Dr))
-        latent = cache.view_rows(0)         # (B, S, R + Dr padded to lanes)
-
-        w_kvb = bp["kv_b"].reshape(R, H, Dn + Dv)
-        if T == 1:
-            # decode, absorbed: scores over the latent rows themselves
-            q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0],
-                               w_kvb[..., :Dn])
-            o_lat = mla_decode_attention(q_lat, q_rope[:, 0], latent,
-                                         cache.maxpos + 1, R)
-            out = jnp.einsum("bhc,chv->bhv", o_lat, w_kvb[..., Dn:]) \
-                .reshape(B, 1, H * Dv)
-        else:
-            # prefill, expanded: one sequence, keys and values of every
-            # cached position; the kernel reads none past the last query
-            assert B == 1, "chunked prefill attends one sequence a program"
-            S = latent.shape[1]
-            seen = (jnp.arange(S) <= cache.maxpos[0])[:, None]
-            rows = jnp.where(seen, latent[0], 0)
-            # head-major straight out of the products; the rotary key is
-            # one (S, Dr) array for all heads, never copied per head
-            k_nope = jnp.einsum("sc,chd->hsd", rows[:, :R], w_kvb[..., :Dn])
-            values = jnp.einsum("sc,chd->hsd", rows[:, :R], w_kvb[..., Dn:])
-            out = rect_flash_attention(
-                q_nope[0].transpose(1, 0, 2), k_nope, values, pos[0, 0],
-                q_rope[0].transpose(1, 0, 2), rows[:, R:R + Dr],
-                interpret=cfg.pallas_interpret)
-            out = out.transpose(1, 0, 2).reshape(1, T, H * Dv)
-        return out @ bp["o"]
+                / cfg.rope_original_max_position_embeddings)))
+        return latent_attention(cfg, bp, x, cache, q_scale=q_scale,
+                                cos=cos, sin=sin)

@@ -22,6 +22,12 @@ Isolation: a row at or past a lane's length (the stale tail of its last
 page, rows of the buffer no copy filled) scores -1e30 whatever it holds and
 has its VALUES zeroed before the product, so NaN or inf there changes no
 output (``0 * NaN`` would).
+
+``paged_latent_decode_attention`` is the same walk over pages of raw latent
+(MLA) rows, ``(L, NB, bs, stored)``: every head reads the SAME row, which is
+key and value at once, so there is one pool, one buffer a step, and the
+query arrives whole (``[q . W_uk | q_rope | zeros]`` a head), no
+block-diagonal.
 """
 import functools
 
@@ -34,9 +40,11 @@ from deepspeed_tpu.ops.transformer.flash_attention import \
     _interpret_default
 
 KERNEL_NAME = "paged_decode_attn"
+LATENT_KERNEL_NAME = "paged_latent_decode_attn"
 NEG_INF = -1e30
 _LANES = 128
 _STEP_BYTES = 512 * 1024
+_LATENT_STEP_BYTES = 1024 * 1024
 
 
 def reads_in_place(pool_shape):
@@ -46,6 +54,12 @@ def reads_in_place(pool_shape):
     The interpreter takes any shape."""
     _, _, bs, row = pool_shape
     return bs % 8 == 0 and row % _LANES == 0
+
+
+def latent_reads_in_place(pool_shape, latent_rank):
+    """The same for pages of latent rows, whose leading ``latent_rank``
+    values the kernel cuts out of its accumulator: whole lanes too."""
+    return reads_in_place(pool_shape) and latent_rank % _LANES == 0
 
 
 def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
@@ -221,3 +235,166 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
       tables.astype(jnp.int32).reshape(-1), lengths, following,
       q.reshape(B, 1, HD), k_pool, v_pool)
     return out.reshape(B, HD)
+
+
+def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
+                   q_ref, hbm, o_ref,
+                   buf, sems, slot_ref, m_scr, l_scr, acc_scr, *,
+                   pages, table_width):
+    b = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    bs = buf.shape[1] // pages
+    S = pages * bs                      # cached rows a step
+    H, stored = q_ref.shape[1:]
+    rank = o_ref.shape[-1]
+    layer = layer_ref[0]
+    length = lengths_ref[b]
+
+    def page_copies(lane, c, slot, act):
+        """Start or wait for the copies of step ``c`` of ``lane``: the
+        pages of that step the lane has filled."""
+        first = c * pages
+        filled = (lengths_ref[lane] + bs - 1) // bs - first
+
+        def page(i, carry):
+            src = tables_ref[lane * table_width + first + i]
+            rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            act(pltpu.make_async_copy(hbm.at[layer, src],
+                                      buf.at[slot, rows], sems.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(filled, 0, pages), page, 0)
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    @pl.when(length == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _attend():
+        steps = (length + S - 1) // S
+
+        @pl.when(b == next_ref[n_lanes])        # the first live lane
+        def _first():
+            slot_ref[0] = 0
+            page_copies(b, 0, 0, start)
+
+        slot0 = slot_ref[0]
+        following = next_ref[b]                 # next live lane, or n_lanes
+        q = q_ref[0]                            # (H, stored)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        def step(c, carry):
+            slot = (slot0 + c) % 2
+            last = c + 1 == steps
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                page_copies(b, c + 1, 1 - slot, start)
+
+            @pl.when(jnp.logical_and(last, following < n_lanes))
+            def _():
+                page_copies(following, 0, 1 - slot, start)
+
+            page_copies(b, c, slot, wait)
+
+            @pl.when((c + 1) * S > length)      # rows no query may see
+            def _():
+                row = c * S + jax.lax.broadcasted_iota(
+                    jnp.int32, (S, stored), 0)
+                buf[slot] = jnp.where(row < length, buf[slot],
+                                      jnp.zeros((), buf.dtype))
+
+            rows = buf[slot]                    # keys and values at once
+            s = jax.lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)              # (H, S)
+            pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
+            s = jnp.where(pos < length, s, NEG_INF)
+            m_prev = m_scr[:, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+                p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (H, stored)
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            return carry
+
+        jax.lax.fori_loop(0, steps, step, 0)
+        slot_ref[0] = (slot0 + steps) % 2
+        o_ref[0] = (acc_scr[:, :rank] / l_scr[:, 0:1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("latent_rank", "pages_per_step",
+                                             "interpret"))
+def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
+                                  *, latent_rank, pages_per_step=None,
+                                  interpret=None):
+    """One query a lane over ITS pages of latent rows, up-projections
+    absorbed: what ``rect_attention.mla_decode_attention`` computes over the
+    gathered view, read where the rows lie.
+
+    q_lat: (B, H, R) = q_nope . W_uk, scale folded in; q_rope: (B, H, Dr);
+    pool: (L, NB, bs, stored) rows ``[c_kv | k_rope | zeros]``, left where
+    they are; ``layer``: the layer attended (traced or not); tables: (B, W)
+    page ids in position order; lengths: (B,) cached rows a lane may see
+    (0: an idle lane, which reads nothing and gets zeros).  Returns
+    (B, H, R) in the query's dtype: ``softmax(scores) . c_kv``.  Entries of
+    ``tables`` past a lane's filled pages are never read."""
+    B, H, R = q_lat.shape
+    L, NB, bs, stored = pool.shape
+    W = tables.shape[1]
+    assert R == latent_rank and R + q_rope.shape[-1] <= stored, \
+        (q_lat.shape, q_rope.shape, pool.shape)
+    assert tables.shape == (B, W) and lengths.shape == (B,)
+    if interpret is None:
+        interpret = _interpret_default()
+    assert interpret or latent_reads_in_place(pool.shape, R), \
+        f"pages of {bs} rows of {stored}, of which {R} are the latent, do " \
+        f"not fill whole (8, 128) tiles"
+    # the stored row is wider than the query (padded to whole lanes): the
+    # query is padded, not the rows cut
+    q = jnp.concatenate([q_lat, q_rope], axis=-1)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, stored - q.shape[-1])))
+    pages = pages_per_step or max(1, min(
+        W, _LATENT_STEP_BYTES // (bs * stored * pool.dtype.itemsize)))
+    lengths = lengths.astype(jnp.int32)
+    lane = jnp.arange(B, dtype=jnp.int32)
+    # the next live lane after each (B: none), and in [B] the first one
+    live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
+    following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
+                                 live_from[:1]])
+    kernel = functools.partial(_latent_kernel, pages=pages, table_width=W)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, stored), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, R), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, stored), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, _LANES), jnp.float32),
+                pltpu.VMEM((H, _LANES), jnp.float32),
+                pltpu.VMEM((H, stored), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, R), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=LATENT_KERNEL_NAME,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      tables.astype(jnp.int32).reshape(-1), lengths, following, q, pool)

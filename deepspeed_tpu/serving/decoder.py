@@ -5,7 +5,11 @@ and the programs' shapes; the MODEL owns everything that differs between
 architectures.  A configuration states the rows it caches a token
 (``cfg.cache_rows``, read through ``kv_cache.cache_rows``: one pool tensor
 each, ``(H*D, H*D)`` for keys and values where it states none,
-``(R + Dr,)`` for one latent row) and hands the engine a decoder object
+``(R + Dr,)`` for one latent row, ``(R + Dr, R + Dr)`` for a block of two
+latent attentions) and of what KIND they are (``cfg.cache_kind``, read
+through ``kv_cache.cache_kind``: ``"keys_values"`` where it states none,
+``"rows"`` for raw rows; the engine never tells the kind from the number
+of rows), and hands the engine a decoder object
 (``cfg.decoder()``; a configuration without one is GPT-2,
 ``models.generation.GPT2Decoder``) with:
 
@@ -25,13 +29,18 @@ each, ``(H*D, H*D)`` for keys and values where it states none,
     every program: the served tokens and logits are the same bits.
 ``stat_names``
     names of the int32 counters a block reports a step (``()``: none, and
-    the programs have the outputs they always had).  The engine sums them
+    the programs have the outputs they always had).  A decoder adds a
+    counter of its own by naming it here and returning it in its row:
+    ``LongCatFlashDecoder.stat_names`` is ``moe.dropless.STAT_NAMES`` plus
+    ``moe_zero_rows``, and nothing else in the engine changes.  The engine sums them
     over the layers, brings them to the host ON THE STEP'S ONE FETCH, and
     (tracer armed) records each as a zero-length span
     ``<name>_<group>``, a0 the value, ``group`` being ``decode`` or
     ``prefill_<bucket>``; beside them, for every model, ``attn_pairs_<group>``
-    / ``attn_keys_decode`` (what the program attended) and
-    ``clock_ms_<group>`` (when it was fetched).
+    / ``attn_keys_decode`` (what ONE attention of a block attended: the
+    live lanes' positions, the host's arithmetic; a block of two
+    attentions reads that many keys twice) and ``clock_ms_<group>`` (when
+    it was fetched).
 ``n_layer``, ``scan_layers``
     how many blocks, and whether the engine runs them as ONE traced block
     under ``lax.scan`` (``l`` then a traced scalar and the weights stacked
@@ -47,7 +56,14 @@ each, ``(H*D, H*D)`` for keys and values where it states none,
     (B, T) bool or None, ``write_rows(i, rows)`` / ``view_rows(i)`` for
     raw rows of cache tensor ``i`` ((B*T, width) in; (B, K*bs, stored) out
     in view order, which is position order on the dense path, ``stored``
-    being the width padded with zeros to whole 128-lane tiles), and for a
+    being the width padded with zeros to whole 128-lane tiles; a block
+    that caches several raw rows writes and views each by its index;
+    ``attend_rows(i, q_lat, q_rope, rank)``: latent decode attention of one
+    query a lane over cache tensor ``i``, up-projections absorbed, (B, H,
+    rank); as with ``attend_heads`` the engine chooses its form: lowered
+    for a TPU it reads each lane's filled pages where they lie, elsewhere
+    ``mla_decode_attention`` over the view), and
+    for a
     model with heads ``write_heads`` / ``view_heads`` with the two masks
     ``valid_scores`` / ``valid_keys`` of the gathered view, and
     ``attend_heads(q, n_head, p)``: the masked attention of q
@@ -60,7 +76,8 @@ each, ``(H*D, H*D)`` for keys and values where it states none,
     the ``jax.numpy`` core ``generate`` shares.
 ``final_norm(params, x)``, ``logits(params, xe (N, E)) -> (N, vocab) f32``
 
-What serves a model whose cache is not ``(keys, values)`` or whose blocks
+What serves a model whose cache is not ``(keys, values)`` (by its stated
+kind) or whose blocks
 report counters: the dense decode program and the chunked prefill
 programs.  The other variants (``speculative``, ``sparse_context``,
 ``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off) refuse
@@ -111,13 +128,14 @@ def decoder_for(cfg):
 
 def refuse_unless_plain(cfg, dec, variant):
     """Keys and values, no counters: what every engine variant serves."""
-    from deepspeed_tpu.serving.kv_cache import cache_rows
+    from deepspeed_tpu.serving.kv_cache import (KEYS_VALUES, cache_kind,
+                                                cache_rows)
 
-    if len(cache_rows(cfg)) != 2 or dec.stat_names:
+    if cache_kind(cfg) != KEYS_VALUES or dec.stat_names:
         raise UnsupportedForModel(
             f"{variant}: this engine variant serves models that cache "
-            f"(keys, values) only; {type(dec).__name__} caches rows of "
-            f"widths {cache_rows(cfg)} and reports "
+            f"(keys, values) only; {type(dec).__name__} caches "
+            f"{cache_kind(cfg)} of widths {cache_rows(cfg)} and reports "
             f"{len(dec.stat_names)} counters. The dense decode program and "
             f"the chunked prefill programs serve it "
             f"(docs/tutorials/serving.md, 'The decoder-block contract').")

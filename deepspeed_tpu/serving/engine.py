@@ -30,8 +30,10 @@ cancellation churn, the parity acceptance test; lowered for a TPU, the
 decode program attends each lane's filled pages where they lie instead,
 ``_LayerCache.attend_heads``); ``models/mistral4.py``
 brings latent (MLA) pages, a routed feed-forward over the experts this
-chip holds, and per-step routing counters that ride the step's one fetch.
-A model whose cache is not (keys, values) is served by the dense decode
+chip holds, and per-step routing counters that ride the step's one fetch;
+``models/longcat_flash.py`` a block of two latent attentions, hence two
+raw rows a token.  A model whose cache is not (keys, values), by the kind
+its configuration states (``kv_cache.cache_kind``), is served by the dense decode
 program and the chunked prefill programs; the other variants refuse it by
 name (``decoder.UnsupportedForModel``).
 
@@ -65,7 +67,10 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.generation import _attn_core, _dense, _sample
 from deepspeed_tpu.ops.transformer.paged_attention import (
-    paged_decode_attention, reads_in_place)
+    latent_reads_in_place, paged_decode_attention,
+    paged_latent_decode_attention, reads_in_place)
+from deepspeed_tpu.ops.transformer.rect_attention import \
+    mla_decode_attention
 from deepspeed_tpu.runtime.quantization import (dequantize_rows,
                                                 quantize_rows)
 from deepspeed_tpu.runtime.resilience import chaos
@@ -207,6 +212,31 @@ class _LayerCache:
         return pool[self.l, self.gtables.reshape(-1)] \
             .reshape(B, K * pool.shape[2], pool.shape[3])
 
+    def attend_rows(self, i, q_lat, q_rope, rank):
+        """Latent (MLA) decode attention of one query a lane over cache
+        tensor ``i``, the up-projections absorbed by the caller: q_lat
+        (B, H, rank), q_rope (B, H, Dr) -> (B, H, rank).  As
+        ``attend_heads``, the engine chooses the form: where the program
+        states ``lengths``, the latent fills whole lanes and it is lowered
+        for a TPU, the paged kernel reads each lane's filled pages where
+        they lie; everywhere else ``mla_decode_attention`` over the gathered
+        view."""
+        def over_view(q_lat, q_rope):
+            return mla_decode_attention(q_lat, q_rope, self.view_rows(i),
+                                        self.maxpos + 1, rank)
+
+        if self.lengths is None \
+                or not latent_reads_in_place(self.pools[i].shape, rank):
+            return over_view(q_lat, q_rope)
+
+        def over_pages(q_lat, q_rope):
+            return paged_latent_decode_attention(
+                q_lat, q_rope, self.pools[i], self.l, self.gtables,
+                self.lengths, latent_rank=rank, interpret=False)
+
+        return jax.lax.platform_dependent(q_lat, q_rope, tpu=over_pages,
+                                          default=over_view)
+
 
 def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
                    quantized, sparse=None, allowed=None, row_valid=None):
@@ -310,7 +340,8 @@ def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
 def _pools_of(args, n_rows, quantized):
     """The leading pool arguments of a serving program as the four slots
     ``(k, v, k_scale, v_scale)``, None where the model (``n_rows`` cached
-    rows a token: 2, or 1) or the storage has none."""
+    rows a token, 2 or 1: keys and values, or the model's raw rows in the
+    same two slots) or the storage has none."""
     slots = list(args[:n_rows]) + [None] * (2 - n_rows)
     slots += list(args[n_rows:2 * n_rows]) if quantized else []
     return tuple(slots + [None] * (4 - len(slots)))

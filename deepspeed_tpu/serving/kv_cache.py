@@ -23,10 +23,14 @@ absolute-position order, so the attention math (shared
 
 Cache kinds: what one token's row holds is the MODEL's to state
 (``cfg.cache_rows``; a configuration without it keeps keys and values of
-``n_head * head_dim``).  A model with latent attention keeps ONE row a
-token and layer (``kv_lora_rank + qk_rope_head_dim`` values) and no
-separate value tensor: ``pool_shapes`` then gives ``v = None`` and the
-pool holds one tensor.  One allocator and one page table serve every kind:
+``n_head * head_dim``), and what KIND of rows they are
+(``cfg.cache_kind``: keys and values of heads, or raw rows the model alone
+reads; stated, never told from how many there are).  A model with latent
+attention keeps ONE raw row a token and layer (``kv_lora_rank +
+qk_rope_head_dim`` values) and no separate value tensor: ``pool_shapes``
+then gives ``v = None`` and the pool holds one tensor; a block of TWO
+latent attentions keeps two raw rows, one pool tensor each, and neither is
+a value.  One allocator and one page table serve every kind:
 the block ids, the trash block and the refcounts do not know what a row
 holds.
 
@@ -97,34 +101,49 @@ def _cow_copy_rows(arrs, src, dst):
     return tuple(a.at[:, dst].set(a[:, src]) for a in arrs)
 
 
+KEYS_VALUES = "keys_values"      # rows with heads in them: the default
+RAW_ROWS = "rows"                # rows of the model's own (latent rows)
+
+
 def cache_rows(cfg):
     """Widths of the rows a layer caches a token, one pool tensor each:
-    the configuration's own ``cache_rows`` (one latent row: ``(R + Dr,)``),
-    else keys and values of ``n_head * head_dim``."""
+    the configuration's own ``cache_rows`` (one latent row: ``(R + Dr,)``;
+    a block of two latent attentions: two), else keys and values of
+    ``n_head * head_dim``."""
     own = getattr(cfg, "cache_rows", None)
     if own is not None:
         return tuple(int(w) for w in own)
     return (cfg.n_head * cfg.head_dim,) * 2
 
 
+def cache_kind(cfg):
+    """WHAT the cached rows are, as the configuration states it
+    (``cfg.cache_kind``): :data:`KEYS_VALUES`, two rows of ``n_head``
+    heads each (what a configuration that says nothing caches), or
+    :data:`RAW_ROWS`, rows the model alone can read.  Never inferred from
+    how many rows there are: two raw rows are not keys and values."""
+    return getattr(cfg, "cache_kind", KEYS_VALUES)
+
+
 def pool_shapes(cfg, num_blocks, block_size, quantized):
     """The pool's four shapes ``(k, v, k_scale, v_scale)``; the scales
     are None unless ``quantized``, and ``v`` is None for a model that
-    caches one row a token (:func:`cache_rows`).  One rule for all: the
-    dims a write indexes — layer, block, offset in the block — are major,
-    one token's row is minor (keys and values: its ``n_head * head_dim``
-    values, its ``n_head`` scales; latent: its ``R + Dr`` values).  Every
-    reader of the layout asks here."""
+    caches one row a token (:func:`cache_rows`; a model of two raw rows
+    has its second in ``v``'s slot).  One rule for all: the dims a write
+    indexes — layer, block, offset in the block — are major, one token's
+    row is minor (keys and values: its ``n_head * head_dim`` values, its
+    ``n_head`` scales; latent: its ``R + Dr`` values).  Every reader of
+    the layout asks here."""
     rows = cache_rows(cfg)
     assert 1 <= len(rows) <= 2, rows
     index = (cfg.n_layer, int(num_blocks), int(block_size))
-    if len(rows) == 1:
+    if cache_kind(cfg) == RAW_ROWS:
         # a row that does not fill whole 128-lane tiles is STORED padded to
         # the next multiple: the TPU's tiling pads it in memory either way
-        # (320 values occupy 384), and stated in the shape the compiler
-        # updates the donated pool in place instead of unpadding and
-        # padding all of it around every program
-        rows = (-(-rows[0] // LANES) * LANES,)
+        # (320 values occupy 384, 576 occupy 640), and stated in the shape
+        # the compiler updates the donated pool in place instead of
+        # unpadding and padding all of it around every program
+        rows = tuple(-(-w // LANES) * LANES for w in rows)
     k = index + (rows[0],)
     v = index + (rows[1],) if len(rows) == 2 else None
     scale = index + (cfg.n_head,) if quantized else None
@@ -135,7 +154,8 @@ class PoolTensors(NamedTuple):
     """The device-side pool state threaded through (and donated into)
     the decode/prefill jits.  ``k_scale``/``v_scale`` are None unless
     int8 KV is armed; ``v`` is None where one row a token is cached
-    (``k`` then holds it)."""
+    (``k`` then holds it).  The two slots are the two cached rows, keys and
+    values or not (``cache_kind``)."""
     k: jax.Array
     v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None
@@ -253,7 +273,7 @@ class PagedKVPool:
         request warns loudly (the armed-or-warns DISARMED discipline)."""
         if not requested:
             return False
-        if len(cache_rows(self.cfg)) != 2:
+        if cache_kind(self.cfg) != KEYS_VALUES:
             raise ValueError(
                 "quantize_kv: the int8 pool scales one (token, head) row "
                 "of keys and of values; this model caches rows of widths "
@@ -570,7 +590,7 @@ class PagedKVPool:
         from deepspeed_tpu.runtime.memory_accounting import kv_pool_bytes
 
         cfg = self.cfg
-        if len(cache_rows(cfg)) != 2:       # one row a token: as allocated
+        if cache_kind(cfg) != KEYS_VALUES:       # raw rows: as allocated
             return sum(t.size * t.dtype.itemsize
                        for t in self.tensors.arrays) // self.shards
         return kv_pool_bytes(

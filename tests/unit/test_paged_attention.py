@@ -9,7 +9,10 @@ interpret mode on the CPU, against what every other platform runs: the shared
   page) reaches its output, whatever that row holds;
 - lanes that share pages read the same rows;
 - the engine's decode program, forced onto the kernel, serves ``generate``'s
-  greedy tokens.
+  greedy tokens;
+- the latent (MLA) kernel over pages of raw rows is
+  ``mla_decode_attention`` over the gathered view under the same promises
+  (the engine forced onto it: ``test_longcat_flash.py``).
 """
 import numpy as np
 import jax
@@ -19,7 +22,9 @@ import pytest
 from deepspeed_tpu.models.generation import _attn_core, generate
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.ops.transformer.paged_attention import (
-    KERNEL_NAME, paged_decode_attention, reads_in_place)
+    KERNEL_NAME, LATENT_KERNEL_NAME, latent_reads_in_place,
+    paged_decode_attention, paged_latent_decode_attention, reads_in_place)
+from deepspeed_tpu.ops.transformer.rect_attention import mla_decode_attention
 from deepspeed_tpu.serving import engine as serving
 from deepspeed_tpu.serving.kv_cache import TRASH_BLOCK
 
@@ -217,3 +222,120 @@ def test_engine_decodes_through_the_kernel(monkeypatch):
         "only the decode program (one query a lane) takes the kernel"
     for rid, tokens in zip(rids, want):
         np.testing.assert_array_equal(res[rid]["tokens"], tokens)
+
+
+# ---------------------------------------------------------------------------
+# pages of latent rows: one pool, every head reads the same row
+# ---------------------------------------------------------------------------
+# heads, latent rank, rotary size, stored row, rows a page, pages a lane
+LATENT = {"toy": (4, 16, 8, 128, 4, 64), "r128": (8, 128, 64, 256, 16, 16)}
+
+
+def _latent_case(rng, width, lengths, dtype=jnp.float32):
+    H, R, Dr, stored, bs, W = LATENT[width]
+    B = len(lengths)
+    pool = rng.standard_normal((LAYERS, 1 + B * W, bs, stored))
+    pool[..., R + Dr:] = 0              # a row is padded to whole lanes
+    q_lat, q_rope = (jnp.asarray(rng.standard_normal((B, H, n)) * n ** -0.5,
+                                 dtype) for n in (R, Dr))
+    tables = jnp.asarray(1 + rng.permutation(B * W).reshape(B, W), jnp.int32)
+    return q_lat, q_rope, jnp.asarray(pool, dtype), tables, \
+        jnp.asarray(lengths, jnp.int32)
+
+
+def _latent_kernel(q_lat, q_rope, pool, tables, lengths, **kw):
+    return np.asarray(paged_latent_decode_attention(
+        q_lat, q_rope, pool, LAYER, tables, lengths,
+        latent_rank=q_lat.shape[-1], interpret=True, **kw), np.float32)
+
+
+def _latent_over_view(q_lat, q_rope, pool, tables, lengths):
+    """What ``_LayerCache.attend_rows`` runs off the TPU."""
+    B, W = tables.shape
+    view = pool[LAYER, tables.reshape(-1)].reshape(B, -1, pool.shape[3])
+    return np.asarray(mla_decode_attention(
+        q_lat, q_rope, view, lengths, q_lat.shape[-1]), np.float32)
+
+
+@pytest.mark.parametrize("length", [1, 15, 16, 17, 255, ROWS])
+@pytest.mark.parametrize("width", list(LATENT))
+def test_latent_equals_mla_decode_attention_over_the_view(width, length):
+    rng = np.random.default_rng(length)
+    lengths = [length, 0, ROWS + 1 - length, 0, int(rng.integers(1, ROWS))]
+    case = _latent_case(rng, width, lengths)
+    got, want = _latent_kernel(*case), _latent_over_view(*case)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+    assert not got[~live].any(), "an idle lane reads nothing and gets zeros"
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 3, 64])
+def test_latent_any_step_size_gives_the_same_attention(pages_per_step):
+    rng = np.random.default_rng(pages_per_step)
+    case = _latent_case(rng, "toy", [ROWS, 0, 37, 1, 0, 130])
+    got = _latent_kernel(*case, pages_per_step=pages_per_step)
+    live = np.asarray(case[4]) > 0
+    np.testing.assert_allclose(got[live], _latent_over_view(*case)[live],
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_latent_bf16_probabilities_meet_bf16_rows():
+    rng = np.random.default_rng(7)
+    case = _latent_case(rng, "r128", [200, 0, 17, ROWS], jnp.bfloat16)
+    got, want = _latent_kernel(*case), _latent_over_view(*case)
+    live = np.asarray(case[4]) > 0
+    err = np.linalg.norm(got[live] - want[live]) / np.linalg.norm(want[live])
+    assert err < 1e-2, err
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf])
+@pytest.mark.parametrize("width", list(LATENT))
+def test_latent_rows_past_a_lane_change_nothing(width, poison):
+    rng = np.random.default_rng(11)
+    lengths = [1, 0, 17, ROWS - 1, 130]
+    q_lat, q_rope, pool, tables, lens = _latent_case(rng, width, lengths)
+    bs = pool.shape[2]
+    clean = _latent_kernel(q_lat, q_rope, pool, tables, lens)
+    seen = np.arange(ROWS)[None, :] < np.asarray(lens)[:, None]
+    filled = np.zeros(pool.shape[1:3], bool)      # (NB, bs); trash: unseen
+    filled[np.asarray(tables)] = seen.reshape(len(lengths), -1, bs)
+    assert not filled[TRASH_BLOCK].any() and not filled.all()
+    pool = jnp.where(filled[None, :, :, None], pool, poison)
+    np.testing.assert_array_equal(
+        _latent_kernel(q_lat, q_rope, pool, tables, lens), clean)
+
+
+@pytest.mark.parametrize("pool_shape, rank, whole", [
+    ((4, 1665, 64, 640), 512, True),    # longcat-flash-chat-ep32's cell
+    ((5, 6209, 64, 384), 256, True),    # mistral-small-4-ep4's
+    ((2, 25, 8, 128), 16, False),       # a latent of a part of a lane
+    ((2, 25, 4, 256), 128, False)])     # a page of 4 rows
+def test_latent_only_whole_tiles_are_read_in_place(pool_shape, rank, whole):
+    assert latent_reads_in_place(pool_shape, rank) is whole
+    stored = pool_shape[3]
+    q_lat = jnp.zeros((3, 4, rank), jnp.bfloat16)
+    q_rope = jnp.zeros((3, 4, min(64, stored - rank)), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct(pool_shape, jnp.bfloat16)
+    tables, lens = jnp.zeros((3, 4), jnp.int32), jnp.zeros((3,), jnp.int32)
+
+    def compiled(q_lat, q_rope, pool):
+        return paged_latent_decode_attention(
+            q_lat, q_rope, pool, 0, tables, lens, latent_rank=rank,
+            interpret=False)
+
+    if whole:
+        assert jax.eval_shape(compiled, q_lat, q_rope, pool).shape \
+            == q_lat.shape
+    else:
+        with pytest.raises(AssertionError, match="whole"):
+            jax.eval_shape(compiled, q_lat, q_rope, pool)
+
+
+def test_the_program_names_the_latent_kernel():
+    case = _latent_case(np.random.default_rng(0), "r128", [3])
+    program = jax.make_jaxpr(lambda q_lat, q_rope, pool, tables, lens:
+                             paged_latent_decode_attention(
+                                 q_lat, q_rope, pool, LAYER, tables, lens,
+                                 latent_rank=128, interpret=False))(*case)
+    assert LATENT_KERNEL_NAME == "paged_latent_decode_attn"
+    assert f"name={LATENT_KERNEL_NAME}" in str(program)

@@ -336,6 +336,82 @@ def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
     assert hc.aliased_outputs(text) >= set(range(len(tensors)))
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill1024"])
+def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(program, v5e):
+    """LongCat-Flash's double block caches TWO latent rows a token, 576
+    values each: ``kv_cache.pool_shapes`` states them as two tensors of 640
+    stored lanes (whole 128-lane tiles), and at the published widths and
+    the cell's sizes (64 slots x 26 pages of 64, chunks of 1,024; two of
+    the four blocks, one traced block either way) no program relays either
+    tensor, holds a layer of it on its own, or returns the pool in other
+    buffers than the donated ones.  Stored unpadded, 576 wide, the compiler
+    unpads and pads the whole pool around every program (PR 30)."""
+    import re
+
+    from deepspeed_tpu.models.longcat_flash import (LongCatFlashConfig,
+                                                    LongCatFlashModel)
+    from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving.kv_cache import pool_shapes
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs, C = 64, 26, 64, 1024
+    cfg = LongCatFlashConfig(num_layers=2, vocab_size=16384,
+                             experts_held=(0, 16), pallas_interpret=False)
+    model = LongCatFlashModel(cfg)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree_util.tree_map(
+        lambda l: struct(l.shape, l.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    k, v, k_scale, v_scale = pool_shapes(cfg, 1 + S * W, bs, False)
+    assert k == v == (2, 1 + S * W, bs, 640) and k_scale is v_scale is None
+    tensors = [struct(k, cfg.dtype), struct(v, cfg.dtype)]
+    if program == "decode":
+        jitted = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0,
+                                           None, "data")
+        streams = [struct((S, W), jnp.int32)] + [
+            struct((S,), dtype) for dtype in
+            (jnp.int32, jnp.int32, jnp.bool_, jnp.int32, jnp.float32)]
+    else:
+        jitted = serving._make_prefill_chunk(cfg, C, W, bs, False, False,
+                                             0.0, 0, 0.0, None, "data")
+        streams = [struct((1, W), jnp.int32), struct((C,), jnp.int32),
+                   struct((), jnp.int32), struct((1,), jnp.int32),
+                   struct((), jnp.int32)]
+    text = jitted.lower(params, *tensors, *streams).compile().as_text()
+    dims = ",".join(str(d) for d in k)
+    relays = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= \w+\[{dims}\]\S* copy\(", line)]
+    assert not relays, f"{program} relays the pool: {relays}"
+    layer = ",".join(str(d) for d in k[1:])
+    assert not [line for line in text.splitlines()
+                if re.search(rf"= \(?\w+\[{layer}\]", line)], \
+        f"{program} holds a layer of the pool"
+    assert hc.aliased_outputs(text) >= {0, 1}
+    # nor a weight: the stacks are read where they lie, a matrix a slice
+    # (``q_b``, ``kv_a``, ``kv_b`` are held (out, in) for that)
+    stacks = {",".join(map(str, l.shape)) for l in
+              jax.tree_util.tree_leaves(params) if l.ndim >= 3}
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if any(re.search(rf"= bf16\[{dims}\]\S* (copy|fusion)\(", line)
+                     for dims in stacks)]
+    assert not copied, f"{program} copies a stack of weights: {copied}"
+    # the kernels take heads of (128 | 64) and the 640-lane row as they are
+    assert "moe_grouped_matmul_" + ("decode" if program == "decode"
+                                    else "prefill") in text
+    assert ("mla_prefill_attn" in text) == (program != "decode")
+    # decode reads each lane's filled pages where they lie: no view of
+    # every lane's W pages is gathered (nor selected against its mask)
+    assert ("paged_latent_decode_attn" in text) == (program == "decode")
+    if program == "decode":
+        view = re.compile(rf"= \w+\[({S},{W * bs}|{W * bs},{S}),640\]")
+        made = [line.strip()[:120] for line in text.splitlines()
+                if view.search(line)]
+        assert not made, f"decode gathers every lane's pages: {made}"
+
+
 def test_decode_program_keeps_the_view_where_pages_are_not_whole_tiles(v5e):
     """gpt2-xl's row of 25 heads x 64 is 12.5 lanes wide: Mosaic would
     refuse to slice it, so the engine, which sees the pool's shape, builds

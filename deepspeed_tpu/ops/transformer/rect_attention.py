@@ -8,11 +8,24 @@ the whole (H, C, S) score tensor in f32 (6.5 GB at 32 x 2048 x 24832), so
 this kernel walks the keys in blocks with the online softmax and keeps one
 (block_q, block_k) tile of scores.  Forward only: serving.
 
+The blocks are the ones asked for, whatever S is: the last key block of
+the view may be ragged.  What a grid step costs beside its two products is
+paid per ROW of the tile (two cross-lane reductions, the broadcasts of the
+running maximum and of the rescale, the state read and written), so a wide
+key block is what makes the products the larger part; the scores are ONE
+product over the head's and the shared key part, put side by side.
+Scores, running maximum and sum, exponentials and the accumulator are f32;
+only p is rounded to the values' dtype for its product.
+
 ``q_start`` is a traced scalar (scalar prefetch), so one compiled program
 serves every chunk of a prompt.  Key blocks that lie wholly after a query
 block's last position are neither fetched (their block index is clamped to
-the last one needed) nor computed.  The softmax scale is the caller's: it
-is folded into ``q``.
+the last one needed) nor computed; in the last block a query may see, the
+scores past it are masked, and the value rows past the LAST query, or past
+the view's end where the padded chunk runs beyond it, are zeroed in the
+kernel (0 * NaN = NaN, and what a ragged block holds past the view's end
+was never written), so the caller need define nothing past the last query.
+The softmax scale is the caller's: it is folded into ``q``.
 
 ``mla_decode_attention`` is the decode side of latent (MLA) pages: one
 query a lane against the gathered latent rows, with the up-projections
@@ -38,12 +51,13 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _kernel(q_start_ref, *refs, block_q, block_k, num_k_blocks, shared):
+def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
+            shared):
     if shared:
-        q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, m_scr, l_scr, acc_scr = \
-            refs
+        ks_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+        o_ref, m_scr, l_scr, acc_scr = refs
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -54,114 +68,127 @@ def _kernel(q_start_ref, *refs, block_q, block_k, num_k_blocks, shared):
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_lo = q_start_ref[0] + qi * block_q      # position of the first query
-    k_lo = ki * block_k
+    # the last position anybody sees: of the last query that is no padding,
+    # or of the view's last key where the (padded) chunk runs past it
+    q_last = jnp.minimum(q_start_ref[0] + queries, keys) - 1
+    k_lo = ki * block_k                       # position of the first key
 
     def accumulate(masked):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = _dot(q, k, ((1,), (1,)))                        # (bq, bk) f32
-        if shared:      # the key part all heads share: one more product
-            s = s + _dot(qs_ref[0], ks_ref[...], ((1,), (1,)))
+        k, v = k_ref[0], v_ref[0]
+        if shared:      # [k | k_shared]: ONE product over D + Ds
+            k = jnp.concatenate([k, ks_ref[...]], axis=1)
+        s = _dot(q_ref[0], k, ((1,), (1,)))                 # (bq, bk) f32
         if masked:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_lo + rows >= k_lo + cols, s, NEG_INF)
-        m_prev = m_scr[:, 0:1]
+            # a query sees the keys up to its own position, and none past
+            # `q_last`.  The value rows past it hold anything (past the
+            # view's end they were never written): 0 * NaN = NaN, so they
+            # must not reach the value product
+            sees = jnp.minimum(q_lo + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0), q_last) - k_lo
+            s = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1) <= sees, s, NEG_INF)
+            row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+            v = jnp.where(row <= q_last - k_lo, v, 0)
+        m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + _dot(p.astype(v.dtype), v,
                                                ((1,), (0,)))
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    # a key block wholly before the block's first query needs no mask (the
-    # softmax's elementwise passes bound this kernel, not the MXU: the
-    # mask is a quarter of them); one that straddles the diagonal does;
-    # one wholly after the last query is skipped
-    whole = k_lo + block_k - 1 <= q_lo
-    pl.when(whole)(lambda: accumulate(False))
-    pl.when(jnp.logical_and(jnp.logical_not(whole),
-                            k_lo <= q_lo + block_q - 1))(
-        lambda: accumulate(True))
+    # a block wholly before the first query (and inside the view: the
+    # common one of a long context) needs no mask; one that no query of
+    # the block may see is not computed
+    whole = k_lo + block_k - 1 <= jnp.minimum(q_lo, q_last)
+    pl.when(whole)(functools.partial(accumulate, False))
+    pl.when(jnp.logical_not(whole) & (
+        k_lo <= jnp.minimum(q_lo + block_q - 1, q_last)))(
+            functools.partial(accumulate, True))
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
-        l = l_scr[:, 0:1]
-        o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)) \
-            .astype(o_ref.dtype)
+        # every query sees key 0: the sum is never zero
+        o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
 
 
-def _fit(block, n):
-    """Largest block <= ``block`` that divides n, halving (n if smaller)."""
-    if n <= block:
-        return n
-    while n % block:
-        block //= 2
-    return block
+def _blocks(C, S, block_q=None, block_k=None):
+    """(rows, block_q, block_k): the C queries padded to ``rows`` (whole
+    tiles of 128) and walked ``block_q`` at a time, the keys ``block_k`` a
+    grid step.  No block is cut to a divisor of S: the last may be ragged.
+
+    Measured on the v5e at 32 x (64 | 64 / 128) over 24,832 keys and 64 x
+    (128 | 64 / 128) over 1,664, bf16: a (1024, 1024) tile is the fastest
+    that fits (a key block of 512 takes 1.6 times as long, one of 2,048 or
+    a query block of 512 a tenth longer).  In VMEM: the f32 scores 4 MB
+    and their exponentials 2 MB in bf16, k, v and the shared keys 0.25 MB
+    a block each (lanes padded to 128) twice over, q and the result 0.25
+    MB twice over, the running state 1.5 MB: ~10 MB, under the chip's
+    default scoped limit of 16."""
+    rows = -(-C // _MIN_ROWS) * _MIN_ROWS
+    bq = min(block_q or 1024, rows)
+    while rows % bq:
+        bq //= 2
+    return rows, bq, min(block_k or 1024, S)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_q", "block_k", "interpret"))
 def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
-                         block_q=512, block_k=512, interpret=None):
+                         block_q=None, block_k=None, interpret=None):
     """q: (H, C, D), scale folded in; k: (H, S, D); v: (H, S, Dv); query i
     stands at absolute position ``q_start + i`` and sees keys 0 ..
-    ``q_start + i`` (key j is position j).  ``q_shared`` (H, C, Ds) and
-    ``k_shared`` (S, Ds): a further part of the scores whose keys ALL heads
-    share (MLA's rotary key), ``q_shared . k_shared`` added to ``q . k``
-    without the shared keys ever being copied per head.  Returns
-    (H, C, Dv) in q's dtype.  Key blocks wholly after the last query's
-    position are never read; inside the last block read, rows past it are
-    masked in the scores but meet the zero weights in the value product,
-    so they must be finite (the caller zeroes what no query may see)."""
+    ``q_start + i`` (key j is position j; a query past the view's end, as
+    the padding of a chunk may be, sees all S).
+    ``q_shared`` (H, C, Ds) and ``k_shared`` (S, Ds): a further part of the
+    scores whose keys ALL heads share (MLA's rotary key): the scores are
+    ``[q | q_shared] . [k | k_shared]``, one product, the shared keys put
+    beside each head's in VMEM and never copied per head in HBM.  Returns
+    (H, C, Dv) in q's dtype.  Rows of k, v and k_shared past the last
+    query's position may hold anything: blocks wholly past it are never
+    read, and in the last block read the scores past it are masked and the
+    value rows zeroed."""
     H, C, D = q.shape
     _, S, Dv = v.shape
     shared = q_shared is not None
     assert k.shape == (H, S, D), (q.shape, k.shape, v.shape)
     if interpret is None:
         interpret = _interpret_default()
-    rows = -(-C // _MIN_ROWS) * _MIN_ROWS       # whole tiles of queries
+    rows, bq, bk = _blocks(C, S, block_q, block_k)
+    if shared:
+        Ds = q_shared.shape[-1]
+        assert q_shared.shape == (H, C, Ds) and k_shared.shape == (S, Ds)
+        q = jnp.concatenate([q, q_shared], axis=-1)     # C rows: cheap
     if rows != C:
-        pad = ((0, 0), (0, rows - C), (0, 0))
-        q = jnp.pad(q, pad)
-        q_shared = jnp.pad(q_shared, pad) if shared else None
-    bq, bk = _fit(block_q, rows), _fit(block_k, S)
-    assert S % bk == 0 and rows % bq == 0, (rows, S, bq, bk)
-    nk = S // bk
-
-    def last_needed(qi, qs):
-        # the last key block any query of block qi may see
-        return jnp.minimum((qs[0] + (qi + 1) * bq - 1) // bk, nk - 1)
+        q = jnp.pad(q, ((0, 0), (0, rows - C), (0, 0)))
 
     def q_map(h, qi, ki, qs):
         return h, qi, 0
 
     def kv_map(h, qi, ki, qs):
-        return h, jnp.minimum(ki, last_needed(qi, qs)), 0
+        # a block that no query of block qi may see is not fetched: the
+        # index stays at the last one needed
+        return h, jnp.minimum(
+            ki, (qs[0] + jnp.minimum((qi + 1) * bq, C) - 1) // bk), 0
 
-    in_specs = [pl.BlockSpec((1, bq, D), q_map),
+    in_specs = [pl.BlockSpec((1, bq, q.shape[-1]), q_map),
                 pl.BlockSpec((1, bk, D), kv_map),
                 pl.BlockSpec((1, bk, Dv), kv_map)]
     operands = [q, k, v]
     if shared:
-        Ds = q_shared.shape[-1]
-        assert q_shared.shape == (H, rows, Ds) and k_shared.shape == (S, Ds)
-        in_specs += [
-            pl.BlockSpec((1, bq, Ds), q_map),
-            pl.BlockSpec((bk, Ds), lambda h, qi, ki, qs: (
-                jnp.minimum(ki, last_needed(qi, qs)), 0))]
-        operands += [q_shared, k_shared]
+        in_specs.append(pl.BlockSpec((bk, Ds),
+                                     lambda *at: kv_map(*at)[1:]))
+        operands.append(k_shared)
     out = pl.pallas_call(
-        functools.partial(_kernel, block_q=bq, block_k=bk, num_k_blocks=nk,
-                          shared=shared),
+        functools.partial(_kernel, queries=C, keys=S, shared=shared),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(H, rows // bq, nk),
+            grid=(H, rows // bq, pl.cdiv(S, bk)),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, bq, Dv), q_map),
-            scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
-                            pltpu.VMEM((bq, 128), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32),
                             pltpu.VMEM((bq, Dv), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((H, rows, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -24,7 +24,7 @@ from deepspeed_tpu.moe.dropless import (STAT_NAMES, dropless_moe,
                                         held_layout, route_top_k)
 from deepspeed_tpu.moe.grouped_matmul import grouped_matmul
 from deepspeed_tpu.ops.transformer.rect_attention import (
-    mla_decode_attention, rect_flash_attention)
+    _blocks, mla_decode_attention, rect_flash_attention)
 from deepspeed_tpu.serving import CompilationCounter, InferenceEngine
 from deepspeed_tpu.serving import kv_cache
 from deepspeed_tpu.serving.decoder import UnsupportedForModel
@@ -101,31 +101,73 @@ def test_grouped_matmul_computes_the_active_tiles_and_no_others(n_active):
                                want[:n_active], rtol=1e-5, atol=1e-5)
 
 
+def _rect_case(q_start, C, S=64, block_k=16, widths=(16, 8, 16),
+               dtype=jnp.float32, tol=1e-5):
+    return pytest.param(q_start, C, S, block_k, widths, dtype, tol,
+                        id=f"{q_start}+{C}of{S}-k{block_k}-"
+                           f"{'|'.join(map(str, widths))}-{dtype.__name__}")
+
+
 @pytest.mark.parametrize("shared", [False, True], ids=["plain", "shared"])
-@pytest.mark.parametrize("q_start,C", [(0, 8), (5, 8), (24, 16), (37, 3)])
-def test_rectangle_attention_is_causal_attention_with_an_offset(q_start, C,
-                                                                shared):
+@pytest.mark.parametrize("q_start,C,S,block_k,widths,dtype,tol", [
+    # four key blocks: whole ones, the diagonal, skipped ones
+    _rect_case(0, 8), _rect_case(5, 8), _rect_case(24, 16),
+    _rect_case(37, 3),
+    # S is no multiple of the key block: the last block is ragged, and the
+    # last query stands inside it ...
+    _rect_case(60, 10, S=72), _rect_case(90, 12, S=104, block_k=32),
+    _rect_case(61, 11, S=72, block_k=32), _rect_case(97, 7, S=104),
+    # ... or before it, so that it is never read
+    _rect_case(40, 16, S=72), _rect_case(50, 8, S=104, block_k=32),
+    # fewer keys than one block
+    _rect_case(3, 9, S=12),
+    # the chunk, padded to its bucket, runs past the view's end: into a
+    # ragged last block, with a whole query block past it, past a whole one
+    _rect_case(60, 16, S=72), _rect_case(66, 16, S=72, block_k=32),
+    _rect_case(100, 16, S=104, block_k=32), _rect_case(52, 24),
+    # both models' head widths in bf16, held to the f32 result: scores,
+    # softmax and accumulator are f32, only p is rounded for its product
+    _rect_case(130, 40, S=200, block_k=64, widths=(64, 64, 128),
+               dtype=jnp.bfloat16, tol=6e-3),
+    _rect_case(130, 40, S=200, block_k=64, widths=(128, 64, 128),
+               dtype=jnp.bfloat16, tol=6e-3),
+])
+def test_rectangle_attention_is_causal_attention_with_an_offset(
+        q_start, C, S, block_k, widths, dtype, tol, shared):
     rng = np.random.default_rng(1)
-    H, S, D, Ds = 2, 64, 16, 8
-    q, k, v, qs, ks = (jnp.asarray(rng.normal(size=shape), jnp.float32)
-                       for shape in ((H, C, D), (H, S, D), (H, S, D),
+    H, (D, Ds, Dv) = 2, widths
+    q, k, v, qs, ks = (jnp.asarray(rng.normal(size=shape), dtype)
+                       for shape in ((H, C, D), (H, S, D), (H, S, Dv),
                                      (H, C, Ds), (S, Ds)))
-    # key blocks wholly after the last query's position are never read:
-    # poison them (C queries, not the rows the kernel pads them to)
-    last = -(-(q_start + C) // 16) * 16
-    k, v, ks = (a.at[..., last:, :].set(jnp.nan) for a in (k, v, ks))
+    q, qs = (a * jnp.asarray((D + Ds) ** -0.25, dtype) for a in (q, qs))
+    # nothing after the last query's position is anybody's to see: blocks
+    # wholly after it are never read, the rest of its own block is masked
+    # in the scores and zeroed in the values (what a ragged block holds
+    # past the view's end is NaN too, in interpret mode)
+    k, v, ks = (a.at[..., q_start + C:, :].set(jnp.nan) for a in (k, v, ks))
     out = np.asarray(rect_flash_attention(
         q, k, v, q_start, *((qs, ks) if shared else ()), block_q=8,
-        block_k=16))
+        block_k=block_k), np.float32)
     assert np.isfinite(out).all()
-    scores = np.einsum("hqd,hkd->hqk", q, np.nan_to_num(k))
+    q, k, v, qs, ks = (np.nan_to_num(np.asarray(a, np.float32))
+                       for a in (q, k, v, qs, ks))
+    scores = np.einsum("hqd,hkd->hqk", q, k)
     if shared:
-        scores = scores + np.einsum("hqd,kd->hqk", qs, np.nan_to_num(ks))
+        scores = scores + np.einsum("hqd,kd->hqk", qs, ks)
     seen = (q_start + np.arange(C))[:, None] >= np.arange(S)[None, :]
     probs = np.asarray(jax.nn.softmax(jnp.where(seen, scores, -np.inf), -1))
-    np.testing.assert_allclose(
-        out, np.einsum("hqk,hkd->hqd", probs, np.nan_to_num(v)),
-        rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out, np.einsum("hqk,hkd->hqd", probs, v),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("C,S", [(2048, 24832), (1024, 1664), (128, 24832),
+                                 (4, 1664)])
+def test_rectangle_attention_walks_the_blocks_it_was_asked_for(C, S):
+    """No divisor of the view's length shrinks a tile: 24,832 = 97 x 256
+    and 1,664 = 13 x 128 once made the key block 256 and 128."""
+    rows, block_q, block_k = _blocks(C, S)
+    assert rows == max(C, 128) and rows % block_q == 0
+    assert block_q == min(rows, 1024) and block_k == 1024
 
 
 def test_absorbed_decode_attention_is_attention_over_expanded_keys():
@@ -455,14 +497,20 @@ def test_grouped_matmul_compiles_for_v5e_under_its_own_name(v5e, tile_m,
     assert re.search(r"bf16\[[\d,]*\]\S* copy\(", text) is None
 
 
-@pytest.mark.parametrize("C", [2048, 128, 4])
-def test_rectangle_attention_compiles_for_v5e_under_its_own_name(v5e, C):
-    """At the published widths: 32 heads of 64 (+ 64 shared rotary) / 128
-    over 24,832 cached positions."""
+@pytest.mark.parametrize("H,S,D,C,dtype", [
+    (32, 24832, 64, 2048, jnp.bfloat16), (32, 24832, 64, 128, jnp.bfloat16),
+    (32, 24832, 64, 4, jnp.bfloat16), (64, 1664, 128, 1024, jnp.bfloat16),
+    (64, 1664, 128, 512, jnp.bfloat16), (32, 24832, 64, 2048, jnp.float32),
+    (64, 1664, 128, 1024, jnp.float32)])
+def test_rectangle_attention_compiles_for_v5e_under_its_own_name(v5e, H, S,
+                                                                 D, C, dtype):
+    """At the published widths: Mistral's 32 heads of 64 (+ 64 shared
+    rotary) / 128 over 24,832 cached positions, LongCat's 64 heads of 128
+    (+ 64) / 128 over 1,664: the blocks chosen fit the chip's VMEM, in the
+    bf16 both serve in and in f32."""
     text = _compiled(
         lambda q, k, v, s, qs, ks: rect_flash_attention(
             q, k, v, s, qs, ks, interpret=False),
-        v5e, ((32, C, 64), jnp.bfloat16), ((32, 24832, 64), jnp.bfloat16),
-        ((32, 24832, 128), jnp.bfloat16), ((), jnp.int32),
-        ((32, C, 64), jnp.bfloat16), ((24832, 64), jnp.bfloat16))
+        v5e, ((H, C, D), dtype), ((H, S, D), dtype), ((H, S, 128), dtype),
+        ((), jnp.int32), ((H, C, 64), dtype), ((S, 64), dtype))
     assert "%mla_prefill_attn" in text and "tpu_custom_call" in text

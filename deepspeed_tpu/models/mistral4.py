@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.models import rotary
 from deepspeed_tpu.models.latent_attention import (_rms_norm, _swiglu,
                                                    latent_attention)
 from deepspeed_tpu.moe.dropless import STAT_NAMES, dropless_moe
@@ -109,23 +110,12 @@ def _yarn_mscale(factor, mscale):
 
 def yarn_inv_freq(cfg):
     """YaRN's blend of the plain and the interpolated rotary frequencies,
-    (qk_rope_head_dim / 2,) float64, as DeepSeek-V3's code computes it."""
-    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
-    exponent = np.arange(0, dim, 2, dtype=np.float64) / dim
-    plain = 1.0 / base ** exponent
-    stretched = plain / cfg.rope_factor
-
-    def correction_dim(rotations):
-        return dim * math.log(cfg.rope_original_max_position_embeddings
-                              / (rotations * 2 * math.pi)) \
-            / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / max(high - low, 0.001), 0.0, 1.0)
-    keep_plain = 1.0 - ramp
-    return stretched * (1.0 - keep_plain) + plain * keep_plain
+    (qk_rope_head_dim / 2,) float64, as DeepSeek-V3's code computes it
+    (``models/rotary.py``, which ``models/mellum.py`` calls too)."""
+    return rotary.yarn_inv_freq(
+        cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+        cfg.rope_original_max_position_embeddings, cfg.rope_beta_fast,
+        cfg.rope_beta_slow)
 
 
 def softmax_scale(cfg):

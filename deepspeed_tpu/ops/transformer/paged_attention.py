@@ -23,6 +23,17 @@ page, rows of the buffer no copy filled) scores -1e30 whatever it holds and
 has its VALUES zeroed before the product, so NaN or inf there changes no
 output (``0 * NaN`` would).
 
+Grouped-query heads (``n_head`` a multiple of the heads a row holds): the
+``G`` query heads that share key head ``j`` are ``G`` rows of the
+block-diagonal query with ``q_h`` in the columns of head ``j = h // G``
+(``(32, 512)`` against rows of 512 for 32 query heads over 4 key heads of
+128; made outside the kernel, 32 KB a lane), so no key is repeated in memory
+or in VMEM, and the scale is the head's ``D^-0.5``.  A window (``starts``):
+a lane sees rows ``start .. length - 1`` only, copies only the pages that
+hold them, masks the rows of its first page below ``start`` and zeroes their
+values.  Without either the traced kernel is what it was (GPT-2's instance,
+its cells' yardstick).
+
 ``paged_latent_decode_attention`` is the same walk over pages of raw latent
 (MLA) rows, ``(L, NB, bs, stored)``: every head reads the SAME row, which is
 key and value at once, so there is one pool, one buffer a step, and the
@@ -62,25 +73,35 @@ def latent_reads_in_place(pool_shape, latent_rank):
     return reads_in_place(pool_shape) and latent_rank % _LANES == 0
 
 
-def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
-            q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, slot_ref, m_scr, l_scr, acc_scr, *,
-            n_head, pages, table_width, scale):
+def _kernel(layer_ref, tables_ref, lengths_ref, next_ref, *refs,
+            n_head, pages, table_width, scale, grouped=False,
+            windowed=False):
+    if windowed:        # prefetched too: the first row a lane sees
+        starts_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref,
+     k_buf, v_buf, sems, slot_ref, m_scr, l_scr, acc_scr) = refs
     b = pl.program_id(0)
     n_lanes = pl.num_programs(0)
     bs = k_buf.shape[1] // pages
     S = pages * bs                      # cached rows a step
-    HD = q_ref.shape[-1]
-    D = HD // n_head
+    HD = k_buf.shape[-1]                # a cached row: its heads side by side
+    D = o_ref.shape[-1] if grouped else HD // n_head
     layer = layer_ref[0]
     length = lengths_ref[b]
+
+    def first_page(lane, c):
+        """The first page of step ``c`` of ``lane``: a windowed lane's
+        steps begin at the page that holds its first row."""
+        if windowed:
+            return starts_ref[lane] // bs + c * pages
+        return c * pages
 
     def page_copies(lane, c, slot, act):
         """Start or wait for the copies of step ``c`` of ``lane``: the
         pages of that step the lane has filled, keys and values.  (A
         rolled loop: unrolled, sixteen pages at three sites were most of
         the time it takes to trace and lower the kernel.)"""
-        first = c * pages
+        first = first_page(lane, c)
         filled = (lengths_ref[lane] + bs - 1) // bs - first
 
         def page(i, carry):
@@ -107,7 +128,12 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
 
     @pl.when(length > 0)
     def _attend():
-        steps = (length + S - 1) // S
+        if windowed:
+            first_row = starts_ref[b]
+            steps = ((length + bs - 1) // bs - first_row // bs
+                     + pages - 1) // pages
+        else:
+            steps = (length + S - 1) // S
 
         @pl.when(b == next_ref[n_lanes])        # the first live lane
         def _first():
@@ -118,11 +144,19 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
         following = next_ref[b]                 # next live lane, or n_lanes
         head = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 1)
-        own = jnp.logical_and(col >= head * D, col < (head + 1) * D)
-        # (the select runs in 32 bits: the mask has that layout)
-        q_bd = jnp.where(own, jnp.broadcast_to(
-            q_ref[0].astype(jnp.float32), (n_head, HD)), 0.0) \
-            .astype(q_ref.dtype)
+        if grouped:
+            # the query arrives block-diagonal: q_h in key head h // G's
+            # columns of row h
+            group = n_head // (HD // D)
+            own = jnp.logical_and(col >= head // group * D,
+                                  col < (head // group + 1) * D)
+            q_bd = q_ref[0]
+        else:
+            own = jnp.logical_and(col >= head * D, col < (head + 1) * D)
+            # (the select runs in 32 bits: the mask has that layout)
+            q_bd = jnp.where(own, jnp.broadcast_to(
+                q_ref[0].astype(jnp.float32), (n_head, HD)), 0.0) \
+                .astype(q_ref.dtype)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -140,19 +174,38 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
                 page_copies(following, 0, 1 - slot, start)
 
             page_copies(b, c, slot, wait)
+            if windowed:
+                row0 = first_page(b, c) * bs    # the step's first row
 
-            @pl.when((c + 1) * S > length)      # rows no query may see
-            def _():
-                row = c * S + jax.lax.broadcasted_iota(jnp.int32, (S, HD), 0)
-                v_buf[slot] = jnp.where(row < length, v_buf[slot],
-                                        jnp.zeros((), v_buf.dtype))
+                # rows no query may see: past the length, below the start
+                @pl.when(jnp.logical_or(row0 + S > length, c == 0))
+                def _():
+                    row = row0 + jax.lax.broadcasted_iota(
+                        jnp.int32, (S, HD), 0)
+                    v_buf[slot] = jnp.where(
+                        jnp.logical_and(row < length, row >= first_row),
+                        v_buf[slot], jnp.zeros((), v_buf.dtype))
+            else:
+                @pl.when((c + 1) * S > length)      # rows no query may see
+                def _():
+                    row = c * S + jax.lax.broadcasted_iota(
+                        jnp.int32, (S, HD), 0)
+                    v_buf[slot] = jnp.where(row < length, v_buf[slot],
+                                            jnp.zeros((), v_buf.dtype))
 
             k, v = k_buf[slot], v_buf[slot]
             s = jax.lax.dot_general(
                 q_bd, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale      # (H, S)
-            pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (n_head, S), 1)
-            s = jnp.where(pos < length, s, NEG_INF)
+            if windowed:
+                pos = row0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (n_head, S), 1)
+                s = jnp.where(jnp.logical_and(pos < length,
+                                              pos >= first_row), s, NEG_INF)
+            else:
+                pos = c * S + jax.lax.broadcasted_iota(
+                    jnp.int32, (n_head, S), 1)
+                s = jnp.where(pos < length, s, NEG_INF)
             m_prev = m_scr[:, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -170,25 +223,39 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref,      # prefetched
         slot_ref[0] = (slot0 + steps) % 2
         # head h's output is the diagonal block of row h
         out = jnp.where(own, acc_scr[:] / l_scr[:, 0:1], 0.0)
-        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+        if grouped:     # (H, D): the one block of its row that is not zero
+            o_ref[0] = sum(out[:, j * D:(j + 1) * D]
+                           for j in range(HD // D)).astype(o_ref.dtype)
+        else:
+            o_ref[0] = jnp.sum(out, axis=0, keepdims=True) \
+                .astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n_head", "pages_per_step",
-                                             "interpret"))
+                                             "interpret", "name"))
 def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
-                           n_head, pages_per_step=None, interpret=None):
+                           n_head, starts=None, pages_per_step=None,
+                           interpret=None, name=KERNEL_NAME):
     """q: (B, H*D), one query a lane, heads side by side; k_pool / v_pool:
-    (L, NB, bs, H*D), left where they are; ``layer``: the layer attended
-    (traced or not); tables: (B, W) page ids in position order; lengths:
-    (B,) cached rows a lane may see (positions 0 .. length - 1; 0: an idle
-    lane, which reads nothing and gets zeros).  Returns (B, H*D) in q's
-    dtype: softmax(q_h . K_h^T * D^-0.5) . V_h per head.  Entries of
-    ``tables`` past a lane's filled pages are never read."""
+    (L, NB, bs, Hkv*D), left where they are, ``H`` a multiple of ``Hkv``
+    (query head h reads key head ``h // (H // Hkv)``); ``layer``: the layer
+    attended (traced or not); tables: (B, W) page ids in position order
+    (entry i holds rows ``i * bs .. (i + 1) * bs - 1``); lengths: (B,)
+    cached rows a lane may see (rows 0 .. length - 1; 0: an idle lane, which
+    reads nothing and gets zeros); starts: (B,) the first row a lane sees
+    (a window; None: row 0).  Returns (B, H*D) in q's dtype:
+    softmax(q_h . K_j^T * D^-0.5) . V_j per head.  Entries of ``tables``
+    past a lane's filled pages, and before the page of its first row, are
+    never read."""
     B, HD = q.shape
     L, NB, bs, row = k_pool.shape
     W = tables.shape[1]
-    assert row == HD and v_pool.shape == k_pool.shape, \
-        (q.shape, k_pool.shape, v_pool.shape)
+    D = HD // n_head
+    assert row % D == 0 and n_head % (row // D) == 0 \
+        and v_pool.shape == k_pool.shape, \
+        (q.shape, n_head, k_pool.shape, v_pool.shape)
+    grouped = row != HD
+    windowed = starts is not None
     assert tables.shape == (B, W) and lengths.shape == (B,)
     if interpret is None:
         interpret = _interpret_default()
@@ -199,41 +266,59 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     # 8 and 16 pages of 16 such rows a step: 71 and 90 % of the bytes' time
     # with every page of 28 lanes filled
     pages = pages_per_step or max(1, min(
-        W, _STEP_BYTES // (bs * HD * k_pool.dtype.itemsize)))
+        W, _STEP_BYTES // (bs * row * k_pool.dtype.itemsize)))
     lengths = lengths.astype(jnp.int32)
     lane = jnp.arange(B, dtype=jnp.int32)
     # the next live lane after each (B: none), and in [B] the first one
     live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
     following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
                                  live_from[:1]])
+    prefetched = [jnp.asarray(layer, jnp.int32).reshape(1),
+                  tables.astype(jnp.int32).reshape(-1), lengths, following]
+    if windowed:
+        prefetched.append(jnp.clip(starts.astype(jnp.int32), 0,
+                                   jnp.maximum(lengths - 1, 0)))
+    if grouped:
+        # block-diagonal here, not in the kernel: q_h tiled over the key
+        # heads' columns and kept in those of head h // G
+        G = n_head // (row // D)
+        mine = (jnp.arange(row) // D)[None, :] \
+            == (jnp.arange(n_head) // G)[:, None]
+        q_in = jnp.where(mine, jnp.tile(q.reshape(B, n_head, D),
+                                        (1, 1, row // D)), 0)
+        q_spec = pl.BlockSpec((1, n_head, row), lambda b, *_: (b, 0, 0))
+        out_spec = pl.BlockSpec((1, n_head, D), lambda b, *_: (b, 0, 0))
+        out_shape = (B, n_head, D)
+    else:
+        q_in = q.reshape(B, 1, HD)
+        q_spec = out_spec = pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0))
+        out_shape = (B, 1, HD)
     kernel = functools.partial(
         _kernel, n_head=n_head, pages=pages, table_width=W,
-        scale=(HD // n_head) ** -0.5)
+        scale=D ** -0.5, grouped=grouped, windowed=windowed)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetched),
             grid=(B,),
-            in_specs=[pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0)),
+            in_specs=[q_spec,
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0)),
+            out_specs=out_spec,
             scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, HD), k_pool.dtype),
-                pltpu.VMEM((2, pages * bs, HD), v_pool.dtype),
+                pltpu.VMEM((2, pages * bs, row), k_pool.dtype),
+                pltpu.VMEM((2, pages * bs, row), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((n_head, _LANES), jnp.float32),
                 pltpu.VMEM((n_head, _LANES), jnp.float32),
-                pltpu.VMEM((n_head, HD), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, HD), q.dtype),
+                pltpu.VMEM((n_head, row), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=KERNEL_NAME,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      tables.astype(jnp.int32).reshape(-1), lengths, following,
-      q.reshape(B, 1, HD), k_pool, v_pool)
+        name=name,
+    )(*prefetched, q_in, k_pool, v_pool)
     return out.reshape(B, HD)
 
 

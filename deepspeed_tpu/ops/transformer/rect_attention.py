@@ -27,6 +27,18 @@ kernel (0 * NaN = NaN, and what a ragged block holds past the view's end
 was never written), so the caller need define nothing past the last query.
 The softmax scale is the caller's: it is folded into ``q``.
 
+Grouped-query heads: ``k`` and ``v`` may carry fewer heads than ``q``
+(``H = G * Hkv``); query head ``h`` then reads key head ``h // G`` through
+the key blocks' index map, and no key is repeated in memory.  ``k_start``
+(a traced scalar beside ``q_start``) says that key j stands at absolute
+position ``k_start + j``: the view of a cache group that keeps a window
+begins at the oldest page the lane still holds.  ``window`` (static) bounds
+what a query sees from below: the keys at positions ``p - window + 1 .. p``
+of a query at ``p``; key blocks wholly below the first query of a query
+block's window are neither fetched nor computed, the edge block is masked
+and its value rows below that window zeroed.  Without the three the traced
+kernel is what it was (the latent models' instance, their cells' yardstick).
+
 ``mla_decode_attention`` is the decode side of latent (MLA) pages: one
 query a lane against the gathered latent rows, with the up-projections
 absorbed into the query and the output, in plain ``jax.numpy``.
@@ -52,7 +64,7 @@ def _dot(a, b, dims):
 
 
 def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
-            shared):
+            shared, offset=False, window=None):
     if shared:
         ks_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -70,8 +82,17 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
     q_lo = q_start_ref[0] + qi * block_q      # position of the first query
     # the last position anybody sees: of the last query that is no padding,
     # or of the view's last key where the (padded) chunk runs past it
-    q_last = jnp.minimum(q_start_ref[0] + queries, keys) - 1
-    k_lo = ki * block_k                       # position of the first key
+    if offset:      # key j stands at position k_start + j
+        q_last = jnp.minimum(q_start_ref[0] + queries,
+                             q_start_ref[1] + keys) - 1
+        k_lo = q_start_ref[1] + ki * block_k
+    else:
+        q_last = jnp.minimum(q_start_ref[0] + queries, keys) - 1
+        k_lo = ki * block_k                   # position of the first key
+    if window is not None:
+        # the lowest key the block's first and last query see
+        lo_first = jnp.minimum(q_lo, q_last) - (window - 1)
+        lo_last = jnp.minimum(q_lo + block_q - 1, q_last) - (window - 1)
 
     def accumulate(masked):
         k, v = k_ref[0], v_ref[0]
@@ -85,10 +106,22 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
             # must not reach the value product
             sees = jnp.minimum(q_lo + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0), q_last) - k_lo
-            s = jnp.where(jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) <= sees, s, NEG_INF)
-            row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-            v = jnp.where(row <= q_last - k_lo, v, 0)
+            if window is None:
+                s = jnp.where(jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1) <= sees, s, NEG_INF)
+                row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+                v = jnp.where(row <= q_last - k_lo, v, 0)
+            else:
+                # and none below its window; a row that sees nothing of
+                # this block is wiped by the rescale of the block in which
+                # it first sees a key (its own position, at the latest)
+                col = jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                s = jnp.where((col <= sees) & (col > sees - window), s,
+                              NEG_INF)
+                row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+                v = jnp.where((row <= q_last - k_lo)
+                              & (row >= lo_first - k_lo), v, 0)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -102,14 +135,24 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
     # common one of a long context) needs no mask; one that no query of
     # the block may see is not computed
     whole = k_lo + block_k - 1 <= jnp.minimum(q_lo, q_last)
-    pl.when(whole)(functools.partial(accumulate, False))
-    pl.when(jnp.logical_not(whole) & (
-        k_lo <= jnp.minimum(q_lo + block_q - 1, q_last)))(
-            functools.partial(accumulate, True))
+    if window is None:
+        pl.when(whole)(functools.partial(accumulate, False))
+        pl.when(jnp.logical_not(whole) & (
+            k_lo <= jnp.minimum(q_lo + block_q - 1, q_last)))(
+                functools.partial(accumulate, True))
+    else:
+        # nor is a block wholly below the first query's window computed,
+        # and only one at or above the last query's needs no mask
+        whole = whole & (k_lo >= lo_last)
+        pl.when(whole)(functools.partial(accumulate, False))
+        pl.when(jnp.logical_not(whole)
+                & (k_lo <= jnp.minimum(q_lo + block_q - 1, q_last))
+                & (k_lo + block_k - 1 >= lo_first))(
+                    functools.partial(accumulate, True))
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
-        # every query sees key 0: the sum is never zero
+        # every query sees its own position: the sum is never zero
         o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
 
 
@@ -134,13 +177,19 @@ def _blocks(C, S, block_q=None, block_k=None):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_q", "block_k", "interpret"))
+                   static_argnames=("window", "block_q", "block_k",
+                                    "interpret", "name"))
 def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
-                         block_q=None, block_k=None, interpret=None):
-    """q: (H, C, D), scale folded in; k: (H, S, D); v: (H, S, Dv); query i
-    stands at absolute position ``q_start + i`` and sees keys 0 ..
-    ``q_start + i`` (key j is position j; a query past the view's end, as
-    the padding of a chunk may be, sees all S).
+                         k_start=None, window=None, block_q=None,
+                         block_k=None, interpret=None, name=KERNEL_NAME):
+    """q: (H, C, D), scale folded in; k: (Hkv, S, D); v: (Hkv, S, Dv),
+    ``H`` a multiple of ``Hkv`` (query head h reads key head
+    ``h // (H // Hkv)``); query i stands at absolute position
+    ``q_start + i`` and sees keys 0 .. ``q_start + i`` (key j is position
+    j, or ``k_start + j`` where a traced ``k_start`` is given; with a
+    static ``window`` only the last ``window`` of them, its own position
+    included; a query past the view's end, as the padding of a chunk may
+    be, sees what the last real query sees).
     ``q_shared`` (H, C, Ds) and ``k_shared`` (S, Ds): a further part of the
     scores whose keys ALL heads share (MLA's rotary key): the scores are
     ``[q | q_shared] . [k | k_shared]``, one product, the shared keys put
@@ -150,9 +199,12 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
     read, and in the last block read the scores past it are masked and the
     value rows zeroed."""
     H, C, D = q.shape
-    _, S, Dv = v.shape
+    Hkv, S, Dv = v.shape
     shared = q_shared is not None
-    assert k.shape == (H, S, D), (q.shape, k.shape, v.shape)
+    assert k.shape == (Hkv, S, D) and H % Hkv == 0, \
+        (q.shape, k.shape, v.shape)
+    G = H // Hkv
+    offset = k_start is not None
     if interpret is None:
         interpret = _interpret_default()
     rows, bq, bk = _blocks(C, S, block_q, block_k)
@@ -168,9 +220,19 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
 
     def kv_map(h, qi, ki, qs):
         # a block that no query of block qi may see is not fetched: the
-        # index stays at the last one needed
-        return h, jnp.minimum(
-            ki, (qs[0] + jnp.minimum((qi + 1) * bq, C) - 1) // bk), 0
+        # index stays at the last one needed (and, under a window, at the
+        # first)
+        if not offset and window is None and G == 1:
+            return h, jnp.minimum(
+                ki, (qs[0] + jnp.minimum((qi + 1) * bq, C) - 1) // bk), 0
+        k0 = qs[1] if offset else 0
+        last = (qs[0] + jnp.minimum((qi + 1) * bq, C) - 1 - k0) // bk
+        at = jnp.minimum(ki, last)
+        if window is not None:
+            first = jnp.maximum(
+                (qs[0] + qi * bq - (window - 1) - k0) // bk, 0)
+            at = jnp.maximum(at, jnp.minimum(first, last))
+        return h // G, at, 0
 
     in_specs = [pl.BlockSpec((1, bq, q.shape[-1]), q_map),
                 pl.BlockSpec((1, bk, D), kv_map),
@@ -181,7 +243,8 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
                                      lambda *at: kv_map(*at)[1:]))
         operands.append(k_shared)
     out = pl.pallas_call(
-        functools.partial(_kernel, queries=C, keys=S, shared=shared),
+        functools.partial(_kernel, queries=C, keys=S, shared=shared,
+                          offset=offset, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(H, rows // bq, pl.cdiv(S, bk)),
@@ -194,8 +257,10 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=KERNEL_NAME,
-    )(jnp.asarray(q_start, jnp.int32).reshape(1), *operands)
+        name=name,
+    )(jnp.stack([jnp.asarray(q_start, jnp.int32),
+                 jnp.asarray(k_start, jnp.int32)]) if offset
+      else jnp.asarray(q_start, jnp.int32).reshape(1), *operands)
     return out[:, :C]
 
 

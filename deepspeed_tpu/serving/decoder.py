@@ -9,9 +9,17 @@ each, ``(H*D, H*D)`` for keys and values where it states none,
 latent attentions) and of what KIND they are (``cfg.cache_kind``, read
 through ``kv_cache.cache_kind``: ``"keys_values"`` where it states none,
 ``"rows"`` for raw rows; the engine never tells the kind from the number
-of rows), and hands the engine a decoder object
+of rows), hands the engine a decoder object
 (``cfg.decoder()``; a configuration without one is GPT-2,
-``models.generation.GPT2Decoder``) with:
+``models.generation.GPT2Decoder``)
+
+and, where its layers do not all cache alike, its cache GROUPS
+(``cfg.cache_groups``, read through ``kv_cache.cache_groups``: tuples
+``(name, layers, window)``; the layers that keep every position and the
+layers that keep a window of W, each group with its own pool tensors, pages
+and page table behind one ``alloc`` / ``free``; a configuration that states
+none has one group that keeps everything, and the first group stated always
+does).  The decoder object has:
 
 ``dtype``
     the compute dtype.
@@ -39,7 +47,10 @@ of rows), and hands the engine a decoder object
     ``prefill_<bucket>``; beside them, for every model, ``attn_pairs_<group>``
     / ``attn_keys_decode`` (what ONE attention of a block attended: the
     live lanes' positions, the host's arithmetic; a block of two
-    attentions reads that many keys twice) and ``clock_ms_<group>`` (when
+    attentions reads that many keys twice; a model of several cache groups
+    records ``attn_pairs_<cache group>_<group>`` /
+    ``attn_keys_<cache group>_decode`` instead, a window group at most its
+    window a query) and ``clock_ms_<group>`` (when
     it was fetched).
 ``n_layer``, ``scan_layers``
     how many blocks, and whether the engine runs them as ONE traced block
@@ -74,13 +85,31 @@ of rows), and hands the engine a decoder object
     filled pages where they lie (``ops/transformer/paged_attention.py``);
     every other program, pool and platform attends the gathered view with
     the ``jax.numpy`` core ``generate`` shares.
+    Grouped-query heads and windows: ``write_heads(i, rows)`` takes rows
+    (N, Hkv, D) of the CACHED heads, ``view_heads(i, Hkv)`` gives (B, Hkv,
+    K, D) whose row j stands at position ``cache.k_start + j``, and
+    ``attend_heads(q, n_head, p, name=None)`` takes ``n_head`` QUERY heads,
+    a multiple of the cached ones (query head h reads cached head
+    ``h // G``; no key is repeated in memory; ``p`` None: the result is not
+    projected, (B, T, H * D); ``name``: what the paged kernel is called in
+    the compiled program); in a group that keeps a window
+    (``cache.window``) each query sees its last ``window`` positions.
+    Cache groups: ``cache`` is the hook to layer ``l`` of the FIRST group;
+    ``cache.at(name, layer_in_group)`` is the hook to a layer of the group
+    called ``name``, and a model that loops over the layers of one kind
+    itself carries that group's pool through its loop with
+    ``cache.carry(name)`` / ``cache.restore(name, arrays)``
+    (``MellumDecoder``: its traced unit is one period of sliding layers and
+    a full one, ``n_layer`` the periods).
 ``final_norm(params, x)``, ``logits(params, xe (N, E)) -> (N, vocab) f32``
 
 What serves a model whose cache is not ``(keys, values)`` (by its stated
-kind) or whose blocks
-report counters: the dense decode program and the chunked prefill
+kind), whose blocks
+report counters or which caches in several groups: the dense decode program
+and the chunked prefill
 programs.  The other variants (``speculative``, ``sparse_context``,
-``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off) refuse
+``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off) know one
+group of one kind and refuse
 it by name with :class:`UnsupportedForModel`.
 """
 import functools
@@ -127,10 +156,23 @@ def decoder_for(cfg):
 
 
 def refuse_unless_plain(cfg, dec, variant):
-    """Keys and values, no counters: what every engine variant serves."""
-    from deepspeed_tpu.serving.kv_cache import (KEYS_VALUES, cache_kind,
-                                                cache_rows)
+    """Keys and values, no counters, one cache group: what every engine
+    variant serves."""
+    from deepspeed_tpu.serving.kv_cache import (KEYS_VALUES, cache_groups,
+                                                cache_kind, cache_rows)
 
+    groups = cache_groups(cfg)
+    if len(groups) > 1:
+        raise UnsupportedForModel(
+            f"{variant}: this engine variant knows ONE cache group, one "
+            f"page table a request; {type(dec).__name__} caches in "
+            f"{len(groups)} ("
+            + ", ".join(f"{g.name}: {g.n_layer} layers keeping "
+                        + ("every position" if g.window is None
+                           else f"a window of {g.window}") for g in groups)
+            + "). The dense decode program and the chunked prefill "
+            "programs serve it (docs/tutorials/serving.md, 'The "
+            "decoder-block contract').")
     if cache_kind(cfg) != KEYS_VALUES or dec.stat_names:
         raise UnsupportedForModel(
             f"{variant}: this engine variant serves models that cache "

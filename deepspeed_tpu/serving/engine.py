@@ -58,7 +58,7 @@ collectives.
 import functools
 import itertools
 import time
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
@@ -77,7 +77,8 @@ from deepspeed_tpu.runtime.resilience import chaos
 from deepspeed_tpu.serving.decoder import (decoder_for,
                                            refuse_unless_plain)
 from deepspeed_tpu.serving.kv_cache import (TRASH_BLOCK, PagedKVPool,
-                                            cache_rows)
+                                            cache_groups, cache_rows,
+                                            window_table_width)
 from deepspeed_tpu.serving.metrics import ServingMetrics
 from deepspeed_tpu.serving.reliability import (ABORT_BUDGET, ABORT_EXPIRED,
                                                ABORT_POISONED, ABORT_SHED,
@@ -137,49 +138,149 @@ def _pool_view(pool, scales, l, tables, n_head, quantized, out_dtype):
     return g.reshape(B, W * bs, H, D).transpose(0, 2, 1, 3)
 
 
-class _LayerCache:
-    """A block's hook to ONE layer of the pool: what the decoder-block
-    contract calls ``cache`` (``serving/decoder.py``).  Writes land at
-    this step's ``(blk, off)``, views gather the step's page tables; the
-    pool tensors threaded through the program are updated here and read
-    back by :func:`_paged_forward` after the block."""
+class _Group(NamedTuple):
+    """One cache group's part of a serving program's arguments
+    (``kv_cache.cache_groups``): its pool slots ``(k, v, k_scale,
+    v_scale)``, its page tables (B, Wg), where this step's rows land in it,
+    and, for a group whose table slides, ``base`` (B,): the position of the
+    table's first row (None: 0), and the ``window`` it keeps (None:
+    everything)."""
+    pools: tuple
+    tables: Any
+    blk: Any
+    off: Any
+    base: Any = None
+    window: Optional[int] = None
+    name: str = "full"
 
-    def __init__(self, pools, l, blk, off, gtables, quantized, dtype,
-                 positions, maxpos, row_valid, masks, lengths=None):
-        self.pools = list(pools)            # [k, v, k_scale, v_scale]
-        self.l, self.blk, self.off = l, blk, off
-        self.gtables, self.quantized, self.dtype = gtables, quantized, dtype
+
+class _GroupState:
+    """A :class:`_Group` inside :func:`_forward_groups`: the pools as the
+    blocks update them, the table the views gather, the two masks of that
+    view and, for the paged kernels, the rows a lane may see of it."""
+
+    def __init__(self, group, gtables, masks, lengths, starts):
+        self.pools = list(group.pools)      # [k, v, k_scale, v_scale]
+        self.blk, self.off = group.blk, group.off
+        self.base, self.window, self.name = \
+            group.base, group.window, group.name
+        self.gtables = gtables
+        self.valid_scores, self.valid_keys = masks
+        self.lengths, self.starts = lengths, starts
+
+
+def _gqa_core(q, keys, values, valid):
+    """The masked core for ``H = G * Hkv`` query heads over ``Hkv`` cached
+    heads, no key repeated: q (B, H, Q, D), keys / values (B, Hkv, K, D),
+    ``valid`` broadcast against (B, 1, Q, K).  Scores and softmax in f32,
+    masked positions exactly zero, as ``generation._attn_core``; returns
+    (B, Q, H * D), not projected."""
+    B, H, Q, D = q.shape
+    Hkv = keys.shape[1]
+    qg = q.reshape(B, Hkv, H // Hkv, Q, D)
+    s = jnp.einsum("bjgqd,bjkd->bjgqk", qg, keys,
+                   preferred_element_type=jnp.float32) * (D ** -0.5)
+    s = jnp.where(valid[:, :, None], s, -1e30)
+    probs = jax.nn.softmax(s, axis=-1).astype(values.dtype)
+    y = jnp.einsum("bjgqk,bjkd->bjgqd", probs, values)
+    return y.reshape(B, H, Q, D).transpose(0, 2, 1, 3).reshape(B, Q, H * D)
+
+
+class _LayerCache:
+    """A block's hook to ONE layer of ONE cache group of the pool: what the
+    decoder-block contract calls ``cache`` (``serving/decoder.py``).
+    Writes land at this step's ``(blk, off)``, views gather the step's page
+    tables; the pool tensors threaded through the program are updated here
+    and read back by :func:`_forward_groups` after the block.  A model of
+    several groups reaches the others through :meth:`at`."""
+
+    def __init__(self, states, state, l, quantized, dtype, positions,
+                 maxpos, row_valid):
+        self._states, self._state = states, state
+        self.l = l
+        self.quantized, self.dtype = quantized, dtype
         self.positions, self.maxpos = positions, maxpos
         self.row_valid = row_valid
-        self.valid_scores, self.valid_keys = masks
-        self.lengths = lengths
+
+    def at(self, group, l):
+        """The hook to layer ``l`` (counted within the group) of the cache
+        group named ``group``."""
+        return _LayerCache(self._states, self._named(group), l,
+                           self.quantized, self.dtype, self.positions,
+                           self.maxpos, self.row_valid)
+
+    def carry(self, group):
+        """The pool tensors of the group named ``group`` as they stand, a
+        tuple: what a loop of the model's own (``lax.scan`` over the layers
+        of one kind) carries, and hands back with :meth:`restore`."""
+        return _held(self._named(group).pools)
+
+    def restore(self, group, arrays):
+        st = self._named(group)
+        it = iter(arrays)
+        st.pools = [None if t is None else next(it) for t in st.pools]
+
+    def _named(self, group):
+        return next(st for st in self._states if st.name == group)
+
+    # this hook's group
+    pools = property(lambda self: self._state.pools)
+    blk = property(lambda self: self._state.blk)
+    off = property(lambda self: self._state.off)
+    gtables = property(lambda self: self._state.gtables)
+    valid_scores = property(lambda self: self._state.valid_scores)
+    valid_keys = property(lambda self: self._state.valid_keys)
+    lengths = property(lambda self: self._state.lengths)
+    window = property(lambda self: self._state.window)
+
+    @property
+    def k_start(self):
+        """(B,) the position of the first row of this group's views: 0
+        where the group keeps everything."""
+        base = self._state.base
+        return jnp.zeros_like(self.maxpos) if base is None else base
 
     # keys and values with heads (GPT-2): quantizable, (B, H, K, D) views
     def write_heads(self, i, rows):
+        """rows (N, Hkv, D): the cached heads, whatever the query's."""
         self.pools[i], self.pools[2 + i] = _pool_write(
             self.pools[i], self.pools[2 + i], self.l, self.blk, self.off,
             rows, self.quantized)
 
     def view_heads(self, i, n_head):
+        """(B, n_head, K, D) of cache tensor ``i`` in view order, ``n_head``
+        the CACHED heads; view row j stands at position ``k_start + j``."""
         return _pool_view(self.pools[i], self.pools[2 + i], self.l,
                           self.gtables, n_head, self.quantized, self.dtype)
 
-    def attend_heads(self, q, n_head, p):
+    def attend_heads(self, q, n_head, p, name=None):
         """Masked attention of q (B, H, T, D) over this layer's cached keys
         and values, through the output projection ``p["c_proj"]``:
-        (B, T, E).  One algorithm whose best form differs with the query
+        (B, T, E); with ``p`` None not projected, (B, T, H * D).  ``n_head``
+        is the QUERY's heads, a multiple of the cached ones (query head h
+        reads cached head ``h // G``, no key repeated in memory); a group
+        that keeps a window shows each query its last ``window`` positions.
+        One algorithm whose best form differs with the query
         count.  Where the program states ``lengths`` (one query a lane
         over a dense unquantized pool of whole-tile pages) and is lowered
         for a TPU, the paged kernel reads each lane's filled pages where
-        they lie (``ops/transformer/paged_attention.py``); everywhere else
+        they lie (``ops/transformer/paged_attention.py``; ``name``: what the
+        kernel is called in the compiled program, its own default where
+        None); everywhere else
         the shared ``jax.numpy`` core attends the gathered view, whose
-        rows past ``maxpos`` are zeroed first (:func:`_paged_forward`)."""
+        rows past ``maxpos`` are zeroed first (:func:`_forward_groups`)."""
+        D = q.shape[-1]
+        n_kv = self.pools[0].shape[3] // D
+
         def over_view(q):
             kview, vview = (jnp.where(self.valid_keys,
-                                      self.view_heads(i, n_head), 0)
+                                      self.view_heads(i, n_kv), 0)
                             for i in (0, 1))
-            return _attn_core(q, kview, vview, self.valid_scores, p,
-                              self.dtype)
+            if n_kv == n_head and p is not None:
+                return _attn_core(q, kview, vview, self.valid_scores, p,
+                                  self.dtype)
+            y = _gqa_core(q, kview, vview, self.valid_scores)
+            return y if p is None else _dense(y, p["c_proj"])
 
         if self.lengths is None:
             return over_view(q)
@@ -188,8 +289,11 @@ class _LayerCache:
             B = q.shape[0]
             y = paged_decode_attention(
                 q.reshape(B, -1), self.pools[0], self.pools[1], self.l,
-                self.gtables, self.lengths, n_head=n_head, interpret=False)
-            return _dense(y[:, None], p["c_proj"])
+                self.gtables, self.lengths, n_head=n_head,
+                starts=self._state.starts, interpret=False,
+                **({} if name is None else {"name": name}))
+            return y[:, None] if p is None else _dense(y[:, None],
+                                                      p["c_proj"])
 
         # known only when the program is lowered (a described chip, a
         # host-side run of the same program): only that branch is lowered
@@ -240,13 +344,28 @@ class _LayerCache:
 
 def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
                    quantized, sparse=None, allowed=None, row_valid=None):
+    """:func:`_forward_groups` of a model of ONE cache group that keeps
+    everything: what every variant beside the dense programs calls.
+    Returns the final-normed x, the pools and the counters."""
+    x, (pools,), stats = _forward_groups(
+        params, dec, (_Group(pools, tables, blk, off),), pos, maxpos, x,
+        quantized, sparse, allowed, row_valid)
+    return x, pools, stats
+
+
+def _forward_groups(params, dec, groups, pos, maxpos, x, quantized,
+                    sparse=None, allowed=None, row_valid=None):
     """Shared transformer pass of decode and chunked prefill: per layer
     the model's block (``serving/decoder.py``) writes this step's rows
     into the pool and attends over the gathered page view (or, one query a
     lane on a TPU, the pages themselves: ``attend_heads``) through a
-    :class:`_LayerCache`.  x: (B, T, E) with T == number of query tokens
+    :class:`_LayerCache`.  ``groups``: one :class:`_Group` a cache group of
+    the model (``kv_cache.cache_groups``), the first the one a block's
+    ``cache`` is a hook to (the others: ``cache.at``).  x: (B, T, E) with
+    T == number of query tokens
     per lane; pos: (B*T?,) absolute positions of the query tokens,
-    flattened to match blk/off.  Returns the final-normed x, the pools and
+    flattened to match blk/off.  Returns the final-normed x, the pools
+    group by group and
     the blocks' counters summed over the layers (None without).
 
     ``maxpos``: (B,) last VALID absolute position per lane.  View
@@ -259,6 +378,11 @@ def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
     bit-neutral (0 * garbage was already exactly +/-0), so the parity
     contract is untouched while per-request fault ISOLATION becomes
     unconditional.
+
+    A group with a ``base``: its table begins at the oldest page a lane
+    still holds, view row j stands at position ``base + j``; with a
+    ``window`` a query sees its last ``window`` positions only, and rows
+    below the FIRST query's window are zeroed like those past ``maxpos``.
 
     ``sparse`` (serving/sparse_context.py): ``(stables, sbase)`` — a
     (B, K) physical-page gather table plus the absolute view position of
@@ -275,60 +399,95 @@ def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
     ``row_valid`` (B, T): which query rows are real tokens (a routed block
     sends padding nowhere); None where the model does not ask."""
     B, T, _ = x.shape
-    W = tables.shape[1]
-    bs = pools[0].shape[2]
-    if sparse is None:
-        gtables = tables
-        validj = (jnp.arange(W * bs)[None, :]
-                  <= pos.reshape(B, T)[:, :, None]) \
-            .reshape(B, T, W * bs)[:, None]              # (B, 1, T, K)
-        validk = (jnp.arange(W * bs)[None, :] <= maxpos[:, None]) \
-            [:, None, :, None]                           # (B, 1, K, 1)
-    else:
-        gtables, sbase = sparse
-        K = gtables.shape[1]
-        view_pos = (sbase[:, :, None] + jnp.arange(bs)[None, None, :]) \
-            .reshape(B, K * bs)                          # (B, K*bs)
-        validj = view_pos[:, None, :] <= pos.reshape(B, T)[:, :, None]
-        if allowed is not None:
-            validj = validj & allowed
-        validj = validj[:, None]                         # (B, 1, T, K*bs)
-        validk = (view_pos <= maxpos[:, None])[:, None, :, None]
-    # one query a lane over the dense unquantized pool, its pages whole
-    # tiles: the rows each lane may see, none for a lane that is not live,
-    # for the paged kernel
-    lengths = None
-    if T == 1 and sparse is None and not quantized \
-            and reads_in_place(pools[0].shape):
-        lengths = maxpos + 1 if row_valid is None \
-            else jnp.where(row_valid[:, 0], maxpos + 1, 0)
+    assert sparse is None or len(groups) == 1
+
+    def state(group):
+        tables, pools = group.tables, group.pools
+        W = tables.shape[1]
+        bs = pools[0].shape[2]
+        starts = None
+        if sparse is not None:
+            gtables, sbase = sparse
+            K = gtables.shape[1]
+            view_pos = (sbase[:, :, None]
+                        + jnp.arange(bs)[None, None, :]) \
+                .reshape(B, K * bs)                      # (B, K*bs)
+            validj = view_pos[:, None, :] <= pos.reshape(B, T)[:, :, None]
+            if allowed is not None:
+                validj = validj & allowed
+            validj = validj[:, None]                     # (B, 1, T, K*bs)
+            validk = (view_pos <= maxpos[:, None])[:, None, :, None]
+        elif group.base is None and group.window is None:
+            gtables = tables
+            validj = (jnp.arange(W * bs)[None, :]
+                      <= pos.reshape(B, T)[:, :, None]) \
+                .reshape(B, T, W * bs)[:, None]          # (B, 1, T, K)
+            validk = (jnp.arange(W * bs)[None, :] <= maxpos[:, None]) \
+                [:, None, :, None]                       # (B, 1, K, 1)
+        else:
+            gtables = tables
+            base = 0 if group.base is None else group.base[:, None]
+            view_pos = base + jnp.arange(W * bs)[None, :]    # (B?, K)
+            qpos = pos.reshape(B, T)
+            validj = view_pos[:, None, :] <= qpos[:, :, None]
+            validk = view_pos <= maxpos[:, None]
+            if group.window is not None:
+                validj = validj & (view_pos[:, None, :]
+                                   > qpos[:, :, None] - group.window)
+                validk = validk & (view_pos
+                                   > qpos[:, :1] - group.window)
+            validj = validj[:, None]                     # (B, 1, T, K)
+            validk = jnp.broadcast_to(validk, (B, W * bs)) \
+                [:, None, :, None]                       # (B, 1, K, 1)
+        # one query a lane over the dense unquantized pool, its pages
+        # whole tiles: the rows each lane may see (of its table; from
+        # ``starts`` on under a window), none for a lane that is not
+        # live, for the paged kernel
+        lengths = None
+        if T == 1 and sparse is None and not quantized \
+                and reads_in_place(pools[0].shape):
+            lengths = maxpos + 1
+            if group.base is not None:
+                lengths = lengths - group.base
+            if group.window is not None:
+                starts = jnp.maximum(lengths - group.window, 0)
+            if row_valid is not None:
+                lengths = jnp.where(row_valid[:, 0], lengths, 0)
+        return _GroupState(group, gtables, (validj, validk), lengths,
+                           starts)
+
+    states = [state(g) for g in groups]
+    n_rows = sum(t is not None for t in groups[0].pools[:2])
 
     def layer(l, x, pools):
-        cache = _LayerCache(pools, l, blk, off, gtables, quantized, x.dtype,
-                            pos.reshape(B, T), maxpos, row_valid,
-                            (validj, validk), lengths)
+        for st, held in zip(states, pools):
+            st.pools = list(held)
+        cache = _LayerCache(states, states[0], l, quantized, x.dtype,
+                            pos.reshape(B, T), maxpos, row_valid)
         out = dec.block(params, l, x, cache)
         x, row = out if dec.stat_names else (out, None)
-        return x, tuple(cache.pools), row
+        return x, [tuple(st.pools) for st in states], row
 
     stats = None
-    n_rows = sum(t is not None for t in pools[:2])
+    pools = [g.pools for g in groups]
     if dec.scan_layers:
         # one traced block, the layer index a traced scalar: the model
         # reads its own layer out of stacked weights, the pool is carried
         # and updated in place, and the device trace has one operation a
         # kernel, not one a layer
         def body(carry, l):
-            x, pools, stats = carry
-            x, pools, row = layer(l, x, _pools_of(pools, n_rows, quantized))
-            return (x, _held(pools),
+            x, held, stats = carry
+            x, pools, row = layer(
+                l, x, [_pools_of(h, n_rows, quantized) for h in held])
+            return (x, [_held(p) for p in pools],
                     None if row is None else stats + row), None
 
         stats = jnp.zeros(len(dec.stat_names), jnp.int32) \
             if dec.stat_names else None
         (x, held, stats), _ = jax.lax.scan(
-            body, (x, _held(pools), stats), jnp.arange(dec.n_layer))
-        pools = _pools_of(held, n_rows, quantized)
+            body, (x, [_held(p) for p in pools], stats),
+            jnp.arange(dec.n_layer))
+        pools = [_pools_of(h, n_rows, quantized) for h in held]
     else:
         for l in range(dec.n_layer):
             x, pools, row = layer(l, x, pools)
@@ -353,6 +512,28 @@ def _held(pools):
 
 def _n_pool(n_rows, quantized):
     return n_rows * (2 if quantized else 1)
+
+
+def _n_pool_args(cfg, quantized):
+    """Pool tensors a dense serving program threads: every cache group's
+    (``kv_cache.cache_groups``)."""
+    return _n_pool(len(cache_rows(cfg)), quantized) * len(cache_groups(cfg))
+
+
+def _split_groups(cfg, args, quantized):
+    """The leading pool arguments of a dense serving program, group by
+    group, each as the four slots of :func:`_pools_of`, and how many
+    arguments they were."""
+    n_rows = len(cache_rows(cfg))
+    n = _n_pool(n_rows, quantized)
+    total = _n_pool_args(cfg, quantized)
+    return [_pools_of(args[at:at + n], n_rows, quantized)
+            for at in range(0, total, n)], total
+
+
+def _held_groups(pools):
+    """Every group's pool tensors in argument order."""
+    return tuple(t for p in pools for t in _held(p))
 
 
 def _stats_out(stats):
@@ -400,27 +581,35 @@ def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
     ``finite`` output (non-finite logits detector) rides the same
     batched fetch as the sampled tokens — per-request quarantine costs
     zero extra host syncs and zero recompiles."""
-    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+    dec, specs = decoder_for(cfg), cache_groups(cfg)
 
     def run(params, *args):
-        pools = _pools_of(args, n_rows, quantized)
-        tables, pos, tok, active, seeds, poison = args[-6:]
+        pools, n_pool = _split_groups(cfg, args, quantized)
+        tables, pos, tok, active, seeds, poison = args[n_pool:]
+        # a model of several cache groups: a table and a base a group
+        tables, bases = tables if len(specs) > 1 \
+            else ((tables,), (None,))
         S = tok.shape[0]
         x = dec.embed(params, tok, pos)[:, None, :]              # (S, 1, E)
         x = x + poison.astype(dec.dtype)[:, None, None]
-        blk = jnp.where(active, tables[jnp.arange(S), pos // bs],
-                        TRASH_BLOCK)
         off = pos % bs
-        x, pools, stats = _paged_forward(
-            params, dec, pools, tables, pos, pos, blk, off, x, quantized,
+        groups = []
+        for spec, held, table, base in zip(specs, pools, tables, bases):
+            page = pos // bs if base is None else (pos - base) // bs
+            blk = jnp.where(active, table[jnp.arange(S), page],
+                            TRASH_BLOCK)
+            groups.append(_Group(held, table, blk, off, base, spec.window,
+                                 spec.name))
+        x, pools, stats = _forward_groups(
+            params, dec, groups, pos, pos, x, quantized,
             row_valid=active[:, None])
         logits = dec.logits(params, x[:, 0])
         finite = jnp.isfinite(logits).all(axis=-1)
         nxt = _pick_next(logits, seeds, pos, temperature, top_k, top_p)
         nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-        return (*_held(pools), *_stats_out(stats), nxt, finite)
+        return (*_held_groups(pools), *_stats_out(stats), nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
+    return _shard_wrap(run, mesh, axis_name, _n_pool_args(cfg, quantized),
                        in_streams=(True,) * 6, n_out_streams=2)
 
 
@@ -485,23 +674,32 @@ def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
     its own table row / n_valid — non-owner shards get n_valid == 0, so
     their writes all land in the trash block and their (finite) outputs
     are ignored by the host."""
-    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
+    dec, specs = decoder_for(cfg), cache_groups(cfg)
 
     def run(params, *args):
-        pools = _pools_of(args, n_rows, quantized)
-        table_rows, tokens, start, n_valids, seed = args[-5:]
-        row = table_rows[0]
+        pools, n_pool = _split_groups(cfg, args, quantized)
+        table_rows, tokens, start, n_valids, seed = args[n_pool:]
+        # a model of several cache groups: a table and a base a group
+        table_rows, bases = table_rows if len(specs) > 1 \
+            else ((table_rows,), (None,))
         n_valid = n_valids[0]
         posns = start + jnp.arange(C)                      # (C,)
         x = dec.embed(params, tokens, posns)[None]         # (1, C, E)
         valid_i = jnp.arange(C) < n_valid
-        blk = jnp.where(valid_i, row[posns // bs], TRASH_BLOCK)
         off = posns % bs
+        groups = []
+        for spec, held, rows, base in zip(specs, pools, table_rows, bases):
+            row = rows[0]
+            page = posns // bs if base is None \
+                else jnp.clip((posns - base[0]) // bs, 0, row.shape[0] - 1)
+            blk = jnp.where(valid_i, row[page], TRASH_BLOCK)
+            groups.append(_Group(held, row[None], blk, off, base,
+                                 spec.window, spec.name))
         maxpos = (start + n_valid - 1)[None]             # (1,)
-        x, pools, stats = _paged_forward(
-            params, dec, pools, row[None], posns, maxpos, blk, off, x,
-            quantized, row_valid=valid_i[None] if dec.stat_names else None)
-        out = (*_held(pools), *_stats_out(stats))
+        x, pools, stats = _forward_groups(
+            params, dec, groups, posns, maxpos, x, quantized,
+            row_valid=valid_i[None] if dec.stat_names else None)
+        out = (*_held_groups(pools), *_stats_out(stats))
         if not final:
             return out
         xe = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
@@ -512,7 +710,7 @@ def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
                          temperature, top_k, top_p)
         return (*out, nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
+    return _shard_wrap(run, mesh, axis_name, _n_pool_args(cfg, quantized),
                        in_streams=(True, False, False, True, False),
                        n_out_streams=2 if final else 0)
 
@@ -604,6 +802,34 @@ def _make_sparse_prefill_chunk(cfg, C, W, K, bs, win, g, quantized, final,
                        n_out_streams=2 if final else 0)
 
 
+def group_table_widths(cfg, W, bs, prefill_chunk):
+    """Per cache group of a model (``kv_cache.cache_groups``) the width of
+    its page table in the decode program and in a chunk program: ``W``
+    (``max_blocks_per_seq``, the whole context) for a group that keeps
+    everything, what a window can need for one that keeps a window
+    (``kv_cache.window_table_width``)."""
+    return [(W, W) if g.window is None else tuple(
+        min(W, window_table_width(g.window, bs, queries))
+        for queries in (1, prefill_chunk)) for g in cache_groups(cfg)]
+
+
+def default_pool_blocks(cfg, shards, max_slots, W, bs, prefill_chunk):
+    """Pages a cache group of the engine's default pool, which never
+    evicts: a group that keeps everything gets the trash block(s) and
+    ``max_slots x max_blocks_per_seq`` pages; a group that keeps a window
+    gets what ``max_slots`` lanes hold BETWEEN programs, ``ceil(W / bs) +
+    1`` each whether they decode or wait between two chunks of a prompt
+    (the pages below the next query's window go back after EVERY program,
+    ``release_expired``), and on top what the ONE lane with a chunk in
+    flight holds beyond that, the chunk's own pages (there is one prefill
+    lane a step)."""
+    return [shards + max_slots * W if g.window is None
+            else 1 + max_slots * decode + (chunk - decode)
+            for g, (decode, chunk) in zip(
+                cache_groups(cfg),
+                group_table_widths(cfg, W, bs, prefill_chunk))]
+
+
 class InferenceEngine:
     """Continuous-batching serving engine (see module docstring).
 
@@ -648,13 +874,23 @@ class InferenceEngine:
         self.bs = int(kv_block_size)
         self.W = int(max_blocks_per_seq
                      or -(-int(cfg.n_positions) // self.bs))
-        if kv_blocks is None:
-            kv_blocks = shards + max_slots * self.W     # never evicts
-        self.pool = PagedKVPool(cfg, num_blocks=kv_blocks,
-                                block_size=self.bs, shards=shards,
-                                mesh=mesh, axis_name=axis_name,
-                                quantize_kv=quantize_kv)
         self.prefill_chunk = int(prefill_chunk)
+        # the model's cache groups (kv_cache.cache_groups) and, a group,
+        # the width of its table in the decode program and in a chunk
+        # program: the whole context for a group that keeps everything,
+        # what a window can need for one that keeps a window
+        self.groups = cache_groups(cfg)
+        self._widths = group_table_widths(cfg, self.W, self.bs,
+                                          self.prefill_chunk)
+        blocks = default_pool_blocks(cfg, shards, max_slots, self.W,
+                                     self.bs, self.prefill_chunk)
+        if kv_blocks is not None:
+            # the first group's count; a window group keeps its default
+            blocks = [int(kv_blocks)] + blocks[1:]
+        self.pool = PagedKVPool(
+            cfg, num_blocks=blocks[0] if len(blocks) == 1 else blocks,
+            block_size=self.bs, shards=shards, mesh=mesh,
+            axis_name=axis_name, quantize_kv=quantize_kv)
         self.temperature = float(temperature)
         self.top_k = int(top_k or 0)
         self.top_p = float(top_p or 0.0)
@@ -694,6 +930,13 @@ class InferenceEngine:
         self.pool.programs = self._programs
         S = self.max_slots
         self._tables = np.full((S, self.W), TRASH_BLOCK, np.int32)
+        # the further groups' decode tables and the position of each
+        # lane's first row in them (kv_cache: a window group's table
+        # slides)
+        self._gtables = [np.full((S, decode), TRASH_BLOCK, np.int32)
+                         for decode, _ in self._widths[1:]]
+        self._gbases = [np.zeros(S, np.int32) for _ in self._widths[1:]]
+        self._freed_in_step = 0
         self._pos = np.zeros(S, np.int32)
         self._tok = np.zeros(S, np.int32)
         self._active = np.zeros(S, bool)
@@ -1125,6 +1368,7 @@ class InferenceEngine:
         events = {"admitted": [], "finished": [], "evicted": [],
                   "cancelled": [], "expired": [], "budget": [],
                   "poisoned": []}
+        self._freed_in_step = 0
         rid = self.scheduler.chaos_cancel()
         if rid is not None and self.cancel(rid):
             events["cancelled"].append(rid)
@@ -1177,6 +1421,16 @@ class InferenceEngine:
             "short_ttft_p95": self.metrics.class_ttft_p95("short"),
         }
         if tr is not None:
+            if self._gtables:
+                # the pool by cache group after the step: live pages,
+                # usable pages, and the window pages the step returned
+                for g in self.pool.group_stats():
+                    tr.count(f"kv_pages_{g['name']}", self._lane_serve,
+                             g["blocks_in_use"])
+                    tr.count(f"kv_pool_pages_{g['name']}", self._lane_serve,
+                             g["blocks_total"])
+                tr.count("kv_window_pages_freed", self._lane_serve,
+                         self._freed_in_step)
             if self._gap is not None and qd == 0 \
                     and not self.scheduler.in_flight():
                 # this gap is want of demand, not the host's doing
@@ -1595,7 +1849,9 @@ class InferenceEngine:
             return (self.params, *self.pool.tensors.arrays, self._tables,
                     self._stables, self._sbase, self._pos, self._tok,
                     self._active, self._seeds, self._poison)
-        return (self.params, *self.pool.tensors.arrays, self._tables,
+        tables = self._tables if not self._gtables else (
+            (self._tables, *self._gtables), (None, *self._gbases))
+        return (self.params, *self.pool.all_arrays, tables,
                 self._pos, self._tok, self._active, self._seeds,
                 self._poison)
 
@@ -1617,7 +1873,7 @@ class InferenceEngine:
         return self._spec.lower(*args).compile().as_text()
 
     def n_pool_tensors(self) -> int:
-        return len(self.pool.tensors.arrays)
+        return len(self.pool.all_arrays)
 
     # -- internals ------------------------------------------------------
     def _buckets(self):
@@ -1634,8 +1890,9 @@ class InferenceEngine:
         raise AssertionError(f"chunk {n} > prefill_chunk")
 
     def _rebind(self, arrays):
-        # the pool's own slots (k [, v] [, scales]) in ``.arrays`` order
-        self.pool.tensors = self.pool.tensors.with_arrays(arrays)
+        # the pool's own slots (k [, v] [, scales]) in ``.arrays`` order,
+        # group by group
+        self.pool.rebind(arrays)
 
     def _shard_for_slot(self, slot):
         return slot // (self.max_slots // self.shards)
@@ -1703,6 +1960,9 @@ class InferenceEngine:
             return
         self._active[slot] = False
         self._tables[slot] = TRASH_BLOCK
+        for table, base in zip(self._gtables, self._gbases):
+            table[slot] = TRASH_BLOCK
+            base[slot] = 0
         self._pos[slot] = 0
         self._tok[slot] = 0
         if self.sparse is not None:
@@ -1765,11 +2025,46 @@ class InferenceEngine:
         if promote:
             self.scheduler.promote(req)
             slot = req.slot
-            self._tables[slot] = self.pool.table_row(req.rid, self.W)
+            self._refresh_tables(slot, req.rid)
             self._pos[slot] = len(req.full_tokens) - 1
             self._tok[slot] = req.generated[-1]
             self._seeds[slot] = req.seed
             self._active[slot] = True
+
+    def _refresh_tables(self, slot, rid):
+        """The lane's page tables as the pool has them now, a group."""
+        self._tables[slot] = self.pool.table_row(rid, self.W)
+        for g, (table, base) in enumerate(zip(self._gtables, self._gbases),
+                                          1):
+            table[slot] = self.pool.table_row(rid, table.shape[1], group=g)
+            base[slot] = self.pool.table_base(rid, g)
+
+    def _release_expired(self, req, next_pos):
+        """After a program of ``req``: the pages of its window groups that
+        lie wholly below the window of its next query (at ``next_pos``) go
+        back to their group's free list, where the next ``alloc`` of ANY
+        request finds them while this one still runs (programs run in the
+        order dispatched: whoever is given a page writes it after this
+        request last read it)."""
+        if not self._gtables:
+            return
+        freed = self.pool.release_expired(req.rid, next_pos)
+        if freed:
+            self._freed_in_step += freed
+            self.metrics.record_window_expired(freed)
+
+    def _attended(self, counter, first, n):
+        """What ``n`` queries in a row from position ``first`` attend, a
+        cache group, as counters of the program: ``counter`` alone for a
+        model of one group (each query every position up to its own),
+        ``<counter>_<group>`` for one of several (a window group at most
+        its window)."""
+        if len(self.groups) == 1:
+            return {counter: n * first + n * (n + 1) // 2}
+        q = np.arange(first, first + n, dtype=np.int64) + 1
+        return {f"{counter}_{g.name}": int(
+            (q if g.window is None else np.minimum(q, g.window)).sum())
+            for g in self.groups}
 
     def _dispatch(self, span, fn, args):
         """Every serving program goes to the device through here, and
@@ -1838,6 +2133,12 @@ class InferenceEngine:
         nv = np.zeros(self.shards, np.int32)
         rows[req.shard] = self.pool.table_row(req.rid, self.W)
         nv[req.shard] = n
+        if len(self.groups) > 1:    # a table and a base a group
+            more = [self.pool.table_row(req.rid, chunk, group=g)[None]
+                    for g, (_, chunk) in enumerate(self._widths[1:], 1)]
+            bases = [np.full(1, self.pool.table_base(req.rid, g), np.int32)
+                     for g in range(1, len(self.groups))]
+            rows = ((rows, *more), (None, *bases))
         return rows, nv
 
     def _prefill_tick(self, events):
@@ -1905,7 +2206,7 @@ class InferenceEngine:
                 self.mesh, self.axis_name)
             pf_name = f"prefill_chunk{bucket}" + ("_final" if final
                                                   else "")
-            pf_args = (self.params, *self.pool.tensors.arrays, rows,
+            pf_args = (self.params, *self.pool.all_arrays, rows,
                        tok_pad, np.int32(start), nv, np.int32(req.seed))
             group = "serving:prefill_final" if final \
                 else "serving:prefill"
@@ -1928,9 +2229,10 @@ class InferenceEngine:
         self.metrics.record_prefill(n)
         n_pool = self.n_pool_tensors()
         # (query, key) pairs this chunk attends: n queries from ``start``,
-        # each over every position up to its own
+        # each over every position up to its own (a cache group that keeps
+        # a window: over its window at most)
         self._note_program(f"prefill_{bucket}", out, n_pool,
-                           attn_pairs=n * start + n * (n + 1) // 2)
+                           **self._attended("attn_pairs", start, n))
         if final:
             # ONE batched fetch: the sampled token and the non-finite-
             # logits detector travel together (no extra host sync)
@@ -1947,10 +2249,12 @@ class InferenceEngine:
                 # radix tree — the next request sharing this prefix
                 # skips their prefill chunks entirely
                 self.pool.prefix_insert(req.rid, req.shard, req.prompt)
+            self._release_expired(req, total)
             self._on_new_token(req, first, events, promote=True)
         else:
             self._rebind(out[:n_pool])
             req.prefill_done = start + n
+            self._release_expired(req, start + n)
             if self.prefill_fairness:
                 # chunked-prefill fairness: after a quantum of chunks a
                 # huge prompt yields the lane IF anyone is waiting for
@@ -2110,7 +2414,7 @@ class InferenceEngine:
                     keep_blocks=self.sparse.g)
                 if freed:
                     self.metrics.record_window_expired(freed)
-            self._tables[slot] = self.pool.table_row(req.rid, self.W)
+            self._refresh_tables(slot, req.rid)
             if self.sparse is not None:
                 # host-side LUT maintenance: same no-mutation-before-
                 # fetch discipline as _pos/_tok (the previous dispatch's
@@ -2158,9 +2462,16 @@ class InferenceEngine:
             # x max_blocks_per_seq is the share of a fixed-shape view that
             # attention reading live pages only still reads
             keys = self._pos[list(running)] + 1
-            self._note_program("decode", out, n_pool,
-                               attn_keys=int(keys.sum()),
-                               attn_pages=int((-(-keys // self.bs)).sum()))
+            if len(self.groups) == 1:
+                counters = {"attn_keys": int(keys.sum()),
+                            "attn_pages": int((-(-keys // self.bs)).sum())}
+            else:       # a cache group: a window group its window at most
+                counters = {}
+                for g in self.groups:
+                    seen = keys if g.window is None \
+                        else np.minimum(keys, g.window)
+                    counters[f"attn_keys_{g.name}"] = int(seen.sum())
+            self._note_program("decode", out, n_pool, **counters)
         # kill-mid-decode chaos: the dispatch happened, NO host
         # bookkeeping has — the journal holds the last committed step
         chaos.serving_kill_step(self._step_idx)
@@ -2184,6 +2495,7 @@ class InferenceEngine:
                 continue
             self._pos[slot] += 1
             self._tok[slot] = int(toks[slot])
+            self._release_expired(req, int(self._pos[slot]))
             self._on_new_token(req, int(toks[slot]), events,
                                promote=False)
         return len(running)

@@ -34,6 +34,27 @@ a value.  One allocator and one page table serve every kind:
 the block ids, the trash block and the refcounts do not know what a row
 holds.
 
+Cache groups: a configuration may state, beside its rows, its cache GROUPS
+(``cfg.cache_groups``, read through :func:`cache_groups`): for each a name,
+how many layers write to it and what it keeps, every position or a window
+of the last ``W``.  A configuration that states none has ONE group of all
+its layers that keeps everything, and its pool, tables and programs are
+what they always were.  ``PagedKVPool`` holds each group's tensors
+``(L_group, NB_group, bs, row)``, free list and page table a request behind
+the calls it has: ``alloc(rid, shard, n_positions)`` covers every group or
+changes nothing, ``free(rid)`` returns all.  A window group's table is a
+SLIDING one: it begins at the oldest page the request still holds
+(:meth:`PagedKVPool.table_base` is that page's first position), and
+:meth:`PagedKVPool.release_expired` returns the pages that lie wholly below
+the next query's window to THAT group's free list, where the next
+``alloc``, of any request, finds them.  So a window layer pays for a window
+and not for the context: at most :func:`window_table_width` pages a lane
+(``ceil(W / bs) + 1`` while it decodes, ``ceil((W - 1 + chunk) / bs) + 1``
+in a prefill chunk).  The variants that know one group (``prefix_cache``,
+``quantize_kv``, ``shards``, ``window_expired_free``, the fleet hand-off)
+work on a one-group pool only: the engine refuses a model of several by
+name before it builds one.
+
 Block 0 of every shard is a reserved TRASH block: masked lanes (inactive
 slots, prefill padding) route their writes there, which keeps every
 scatter in the jit fully dense — no branches, no recompiles.
@@ -125,8 +146,41 @@ def cache_kind(cfg):
     return getattr(cfg, "cache_kind", KEYS_VALUES)
 
 
-def pool_shapes(cfg, num_blocks, block_size, quantized):
-    """The pool's four shapes ``(k, v, k_scale, v_scale)``; the scales
+class CacheGroup(NamedTuple):
+    """Layers of a model that cache alike: ``n_layer`` of them write to
+    this group's tensors, which keep every position (``window`` None) or
+    the last ``window`` positions a query can still see."""
+    name: str
+    n_layer: int
+    window: Optional[int] = None
+
+
+def cache_groups(cfg):
+    """The configuration's cache groups (``cfg.cache_groups``: tuples
+    ``(name, n_layer, window)``); one group of every layer that keeps every
+    position where it states none."""
+    own = getattr(cfg, "cache_groups", None)
+    if own is None:
+        return (CacheGroup("full", int(cfg.n_layer)),)
+    groups = tuple(CacheGroup(str(n), int(l), None if w is None else int(w))
+                   for n, l, w in own)
+    assert groups and len({g.name for g in groups}) == len(groups), groups
+    return groups
+
+
+def window_table_width(window, block_size, queries=1):
+    """Pages a lane of a window group can hold while ``queries`` queries
+    in a row attend: the ``window - 1`` positions before the first, the
+    queries' own, and (a final chunk reserves it) the position after the
+    last; a run of n positions touches at most ``ceil((n - 1) / bs) + 1``
+    pages.  One query, a decoding lane: ``ceil(W / bs) + 1``."""
+    return -(-(int(window) - 1 + int(queries)) // int(block_size)) + 1
+
+
+def pool_shapes(cfg, num_blocks, block_size, quantized, group=0):
+    """The four shapes ``(k, v, k_scale, v_scale)`` of cache group
+    ``group`` (an index into :func:`cache_groups`; ``num_blocks`` is that
+    group's); the scales
     are None unless ``quantized``, and ``v`` is None for a model that
     caches one row a token (:func:`cache_rows`; a model of two raw rows
     has its second in ``v``'s slot).  One rule for all: the dims a write
@@ -136,7 +190,8 @@ def pool_shapes(cfg, num_blocks, block_size, quantized):
     the layout asks here."""
     rows = cache_rows(cfg)
     assert 1 <= len(rows) <= 2, rows
-    index = (cfg.n_layer, int(num_blocks), int(block_size))
+    index = (cache_groups(cfg)[group].n_layer, int(num_blocks),
+             int(block_size))
     if cache_kind(cfg) == RAW_ROWS:
         # a row that does not fill whole 128-lane tiles is STORED padded to
         # the next multiple: the TPU's tiling pads it in memory either way
@@ -148,6 +203,24 @@ def pool_shapes(cfg, num_blocks, block_size, quantized):
     v = index + (rows[1],) if len(rows) == 2 else None
     scale = index + (cfg.n_head,) if quantized else None
     return k, v, scale, scale
+
+
+class _FurtherGroup:
+    """A cache group of a pool beyond its first: its tensors, free list
+    and, a request, the pages it holds from logical page ``first`` on (a
+    sliding table, no holes).  The pool's own attributes are group 0."""
+
+    def __init__(self, spec, num_blocks, tensors):
+        self.spec = spec
+        self.num_blocks = int(num_blocks)
+        self.tensors = tensors
+        self.free = list(range(1, self.num_blocks))     # 0: the trash block
+        self.blocks: Dict[int, List[int]] = {}
+        self.first: Dict[int, int] = {}
+
+    @property
+    def in_use(self):
+        return self.num_blocks - 1 - len(self.free)
 
 
 class PoolTensors(NamedTuple):
@@ -202,12 +275,26 @@ class PagedKVPool:
 
     ``num_blocks`` is the TOTAL block count across shards (must divide by
     ``shards``); one block per shard is reserved as trash, so the usable
-    capacity is ``num_blocks - shards`` blocks.
+    capacity is ``num_blocks - shards`` blocks.  For a model of several
+    cache groups (:func:`cache_groups`) it is a sequence, one count a
+    group, the first of which keeps every position; the attributes and the
+    methods that take no ``group`` speak of that first group, as they do of
+    a one-group pool's only one (the variants that know one group use
+    them), and :attr:`all_arrays`, :meth:`alloc`, :meth:`free`,
+    :meth:`release_expired`, :meth:`table_row`, :meth:`occupancy`,
+    :meth:`group_stats` of all.
     """
 
     def __init__(self, cfg, *, num_blocks, block_size=16, shards=1,
                  mesh=None, axis_name="data", quantize_kv=False,
                  dtype=None):
+        self.groups = cache_groups(cfg)
+        counts = [num_blocks] if np.ndim(num_blocks) == 0 \
+            else list(num_blocks)
+        assert len(counts) == len(self.groups), (counts, self.groups)
+        assert self.groups[0].window is None, \
+            "the first cache group stated keeps every position"
+        num_blocks = int(counts[0])
         assert num_blocks % shards == 0, \
             f"num_blocks={num_blocks} must divide shards={shards}"
         assert num_blocks // shards >= 2, \
@@ -242,6 +329,16 @@ class PagedKVPool:
             tensors = [None if t is None else jax.device_put(t, split)
                        for t in tensors]
         self.tensors = PoolTensors(*tensors)
+        self._further: List[_FurtherGroup] = []
+        for g, count in enumerate(counts[1:], 1):
+            assert shards == 1 and not self.quantized, \
+                "a pool of several cache groups is unsharded, unquantized"
+            assert count >= 2, count
+            self._further.append(_FurtherGroup(
+                self.groups[g], count, PoolTensors(*(
+                    None if shape is None else jnp.zeros(shape, self.dtype)
+                    for shape in pool_shapes(cfg, count, self.block_size,
+                                             False, g)))))
 
         # host-side allocator: per-shard sorted free lists (popping the
         # smallest id keeps runs deterministic), local block ids — the
@@ -312,12 +409,21 @@ class PagedKVPool:
         need = self.blocks_needed(n_positions) - len(have)
         while need > len(self._free[shard]) and self._reclaim_block(shard):
             pass
-        if need > len(self._free[shard]):
+        # every group covers the growth or none changes
+        further = [self.blocks_needed(n_positions) - g.first.get(rid, 0)
+                   - len(g.blocks.get(rid, ())) for g in self._further]
+        if need > len(self._free[shard]) or any(
+                n > len(g.free) for n, g in zip(further, self._further)):
             if not have:
                 self._drop(rid)
             return False
         for _ in range(max(0, need)):
             have.append(self._free[shard].pop(0))
+        for n, g in zip(further, self._further):
+            g.first.setdefault(rid, 0)
+            held = g.blocks.setdefault(rid, [])
+            for _ in range(max(0, n)):
+                held.append(g.free.pop(0))
         self._positions[rid] = max(self._positions.get(rid, 0),
                                    int(n_positions))
         return True
@@ -341,6 +447,35 @@ class PagedKVPool:
             else:
                 recycled.append(b)
         self._free[shard] = sorted(self._free[shard] + recycled)
+        self._free_further(rid)
+
+    def _free_further(self, rid):
+        """Every further group's pages of ``rid`` back to its free list."""
+        for g in self._further:
+            g.free = sorted(g.free + g.blocks.pop(rid, []))
+            g.first.pop(rid, None)
+
+    def release_expired(self, rid: int, next_pos: int) -> int:
+        """Return to their group's free list the pages of ``rid`` that lie
+        wholly below the window of a query at ``next_pos`` (the next the
+        request will ask, so below every remaining query's): its table in
+        that group then begins at the first page still held.  Groups that
+        keep every position return nothing.  The count of pages returned."""
+        freed = 0
+        for g in self._further:
+            held = g.blocks.get(rid)
+            if g.spec.window is None or not held:
+                continue
+            keep_from = max(0, int(next_pos) - g.spec.window + 1) \
+                // self.block_size
+            gone = min(max(0, keep_from - g.first[rid]), len(held))
+            if gone:
+                g.free = sorted(g.free + held[:gone])
+                del held[:gone]
+                g.first[rid] += gone
+                freed += gone
+        self.window_frees += freed
+        return freed
 
     def window_expired_free(self, rid: int, first_active_block: int, *,
                             keep_blocks: int = 0) -> int:
@@ -376,13 +511,25 @@ class PagedKVPool:
         self._shard_of.pop(rid, None)
         self._positions.pop(rid, None)
         self._shared.pop(rid, None)
+        self._free_further(rid)
 
-    def table_row(self, rid: int, width: int) -> np.ndarray:
+    def table_base(self, rid: int, group: int) -> int:
+        """The position of the first row of ``rid``'s table in ``group``:
+        0 in a group that keeps everything, the first position of the
+        oldest page still held in one that keeps a window."""
+        if group == 0:
+            return 0
+        return self._further[group - 1].first.get(rid, 0) * self.block_size
+
+    def table_row(self, rid: int, width: int, group: int = 0) -> np.ndarray:
         """LOCAL block ids of ``rid`` padded with the trash block to the
         fixed table width (the decode jit's static W).  Window-expired
         holes (``None``) map to the trash block too — their positions
-        are masked out by the policy before they could be gathered."""
-        blocks = self._blocks.get(rid, [])
+        are masked out by the policy before they could be gathered.
+        ``group``: the cache group's table (a window group's begins at
+        :meth:`table_base`)."""
+        blocks = self._blocks.get(rid, []) if group == 0 \
+            else self._further[group - 1].blocks.get(rid, [])
         assert len(blocks) <= width, \
             f"rid {rid} holds {len(blocks)} blocks > table width {width}"
         row = np.full(width, TRASH_BLOCK, np.int32)
@@ -590,13 +737,31 @@ class PagedKVPool:
         from deepspeed_tpu.runtime.memory_accounting import kv_pool_bytes
 
         cfg = self.cfg
-        if cache_kind(cfg) != KEYS_VALUES:       # raw rows: as allocated
+        if cache_kind(cfg) != KEYS_VALUES or self._further:
+            # raw rows, several groups: as allocated
             return sum(t.size * t.dtype.itemsize
-                       for t in self.tensors.arrays) // self.shards
+                       for t in self.all_arrays) // self.shards
         return kv_pool_bytes(
             cfg.n_layer, self.num_blocks, cfg.n_head, self.block_size,
             cfg.head_dim, kv_dtype=np.dtype(self.dtype).name,
             quantized=self.quantized, shards=self.shards)
+
+    @property
+    def all_arrays(self):
+        """Every group's pool tensors, group by group in ``.arrays``
+        order: what the serving programs thread and donate."""
+        return self.tensors.arrays + tuple(
+            a for g in self._further for a in g.tensors.arrays)
+
+    def rebind(self, arrays):
+        """The same slots, holding ``arrays`` (in :attr:`all_arrays`
+        order)."""
+        n = len(self.tensors.arrays)
+        self.tensors = self.tensors.with_arrays(arrays[:n])
+        for g in self._further:
+            m = len(g.tensors.arrays)
+            g.tensors = g.tensors.with_arrays(arrays[n:n + m])
+            n += m
 
     @property
     def usable_blocks(self) -> int:
@@ -607,11 +772,36 @@ class PagedKVPool:
         """DISTINCT blocks not on a free list — refcount-shared blocks
         count ONCE no matter how many page tables map them, and
         cache-resident blocks (refs == 0, awaiting reclaim) count too:
-        they genuinely occupy pool capacity."""
+        they genuinely occupy pool capacity.  Of the first group; every
+        group's: :meth:`group_stats`."""
         return self.usable_blocks - sum(len(f) for f in self._free)
 
-    def occupancy(self) -> float:
-        return self.blocks_in_use / max(1, self.usable_blocks)
+    def group_stats(self) -> list:
+        """Per cache group: its name, window, layers, usable and live
+        pages, and the bytes a page costs it (its layers' rows)."""
+        page = self.block_size * sum(
+            t.shape[3] * t.dtype.itemsize for t in self.tensors.arrays)
+        rows = [(self.groups[0], self.usable_blocks, self.blocks_in_use)] \
+            + [(g.spec, g.num_blocks - 1, g.in_use) for g in self._further]
+        return [{"name": spec.name, "window": spec.window,
+                 "layers": spec.n_layer, "blocks_total": total,
+                 "blocks_in_use": used,
+                 "occupancy": used / max(1, total),
+                 "block_bytes": page * spec.n_layer}
+                for spec, total, used in rows]
+
+    def occupancy(self, group=None) -> float:
+        """Live over usable: pages of one ``group`` (an index), or with
+        None BYTES over all groups, which for a one-group pool is its
+        pages' share."""
+        if group is not None:
+            return self.group_stats()[group]["occupancy"]
+        if not self._further:
+            return self.blocks_in_use / max(1, self.usable_blocks)
+        stats = self.group_stats()
+        return sum(g["blocks_in_use"] * g["block_bytes"] for g in stats) \
+            / max(1, sum(g["blocks_total"] * g["block_bytes"]
+                         for g in stats))
 
     def fragmentation(self) -> float:
         """Internal fragmentation: fraction of MAPPED pool positions not
@@ -646,4 +836,5 @@ class PagedKVPool:
             "prefix_cow_splits": self.cow_splits,
             "prefix_cache_reclaims": self.cache_reclaims,
             "window_expired_frees": self.window_frees,
+            "groups": self.group_stats(),
         }

@@ -412,6 +412,96 @@ def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(program, v5e):
         assert not made, f"decode gathers every lane's pages: {made}"
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
+    """Mellum's sliding and full layers cache in TWO groups
+    (``kv_cache.cache_groups``), each with its own pool tensors: at the
+    published widths and the cell's sizes (32 slots x 518 pages of 64,
+    chunks of 2,048; the cell's two periods, one traced period)
+    both groups' scatters update the donated pools in place, no program
+    relays a pool tensor or holds a layer of one on its own, none copies a
+    stack of weights, and under the scan a kernel is ONE operation a KIND
+    of layer: the sliding layers are a loop of their own inside the
+    period."""
+    import re
+
+    from deepspeed_tpu.models.mellum import MellumConfig, MellumModel
+    from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving.kv_cache import pool_shapes
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs, C = 32, 518, 64, 2048
+    cfg = MellumConfig(num_hidden_layers=8, pallas_interpret=False)
+    model = MellumModel(cfg)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree_util.tree_map(
+        lambda l: struct(l.shape, l.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    blocks = serving.default_pool_blocks(cfg, 1, S, W, bs, C)
+    widths = serving.group_table_widths(cfg, W, bs, C)
+    assert blocks == [1 + S * W, 1 + S * 17 + 32] \
+        and widths == [(W, W), (17, 49)]
+    shapes = [pool_shapes(cfg, n, bs, False, g)[:2]
+              for g, n in enumerate(blocks)]
+    assert shapes == [((2, blocks[0], bs, 512),) * 2,
+                      ((6, blocks[1], bs, 512),) * 2]
+    tensors = [struct(shape, cfg.dtype) for pair in shapes for shape in pair]
+    if program == "decode":
+        jitted = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0,
+                                           None, "data")
+        streams = [((struct((S, W), jnp.int32), struct((S, 17), jnp.int32)),
+                    (None, struct((S,), jnp.int32)))] + [
+            struct((S,), dtype) for dtype in
+            (jnp.int32, jnp.int32, jnp.bool_, jnp.int32, jnp.float32)]
+    else:
+        jitted = serving._make_prefill_chunk(cfg, C, W, bs, False, False,
+                                             0.0, 0, 0.0, None, "data")
+        streams = [((struct((1, W), jnp.int32), struct((1, 49), jnp.int32)),
+                    (None, struct((1,), jnp.int32))),
+                   struct((C,), jnp.int32), struct((), jnp.int32),
+                   struct((1,), jnp.int32), struct((), jnp.int32)]
+    compiled = jitted.lower(params, *tensors, *streams).compile()
+    text = compiled.as_text()
+    for pair in shapes:
+        dims = ",".join(str(d) for d in pair[0])
+        relays = [line.strip()[:120] for line in text.splitlines()
+                  if re.search(rf"= \w+\[{dims}\]\S* copy\(", line)]
+        assert not relays, f"{program} relays a pool: {relays}"
+        layer = ",".join(str(d) for d in pair[0][1:])
+        assert not [line for line in text.splitlines()
+                    if re.search(rf"= \(?\w+\[{layer}\]", line)], \
+            f"{program} holds a layer of a pool"
+    # all four pool tensors come back in the buffers that were donated
+    assert hc.aliased_outputs(text) >= {0, 1, 2, 3}
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    stacks = {",".join(map(str, l.shape)) for l in
+              jax.tree_util.tree_leaves(params) if l.ndim >= 3}
+    copied = [line.strip()[:120] for line in text.splitlines()
+              if any(re.search(rf"= bf16\[{dims}\]\S* (copy|fusion)\(", line)
+                     for dims in stacks)]
+    assert not copied, f"{program} copies a stack of weights: {copied}"
+    # one operation a kernel and KIND of layer, under its stable name
+    calls = [re.match(r"\s*%(\S+?)(?:\.\d+)? = ", line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    kind = "decode" if program == "decode" else "prefill"
+    attn = "gqa_paged_decode_attn" if program == "decode" \
+        else "gqa_prefill_attn"
+    assert sorted(calls) == sorted(
+        [f"{attn}_full", f"{attn}_window"]
+        + [f"moe_grouped_matmul_{kind}_{call}" for call in ("up", "down")]
+        * 2), calls
+    if program == "decode":
+        # both groups' pages are read where they lie: no view of every
+        # lane's pages is gathered (2.2 GB a full layer)
+        view = re.compile(rf"= \w+\[{S},({W * bs}|{17 * bs}),")
+        made = [line.strip()[:120] for line in text.splitlines()
+                if view.search(line)]
+        assert not made, f"decode gathers every lane's pages: {made}"
+
+
 def test_decode_program_keeps_the_view_where_pages_are_not_whole_tiles(v5e):
     """gpt2-xl's row of 25 heads x 64 is 12.5 lanes wide: Mosaic would
     refuse to slice it, so the engine, which sees the pool's shape, builds

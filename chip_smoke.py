@@ -23,7 +23,6 @@ import argparse
 import functools
 import gc
 import json
-import os
 import sys
 import time
 import traceback
@@ -226,18 +225,6 @@ def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
         lambda q, k, v: scaled_dot_product_attention(
             q, k, v, causal=True, use_pallas=False),
         q, k, v, cot)
-
-    # the same with lse/delta carried as compact rows: the switch a user
-    # can set (DSTPU_FLASH_LSE2D, read when the call is traced)
-    os.environ["DSTPU_FLASH_LSE2D"] = "1"
-    try:
-        seen["flash_causal_compact_lse"] = _compare(
-            lambda q, k, v: flash_attention(q, k, v, causal=True),
-            lambda q, k, v: scaled_dot_product_attention(
-                q, k, v, causal=True, use_pallas=False),
-            q, k, v, cot)
-    finally:
-        del os.environ["DSTPU_FLASH_LSE2D"]
 
     # flash, in-kernel dropout: no reference draws the same mask, so the
     # checks are determinism per seed and the linear-in-v identity, which

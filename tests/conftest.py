@@ -67,10 +67,7 @@ _SLOW_TESTS = {
     "test_flash_attention.py": {
         "test_dropout_causal_blocks_consistent",
         "test_dropout_gradients_multiblock", "test_dropout_mean_preserving",
-        "test_flash_backward_matches_reference",
-        "test_flash_bias_constant_no_grad",
-        "test_flash_bias_matches_reference",
-        "test_flash_multiblock_causal_grad"},
+        "test_flash_bias_constant_no_grad"},
     "test_generation.py": {
         "test_greedy_generation_matches_transformers",
         "test_greedy_matches_full_forward",

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import deepspeed_tpu.ops.transformer.flash_attention as fa
 from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 from deepspeed_tpu.ops.transformer.functional import scaled_dot_product_attention
 
@@ -23,98 +24,178 @@ def _rand_qkv(rng, b, h, s, d, dtype=jnp.float32):
     return q, k, v
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("s,d", [(128, 64), (256, 64)])
-def test_flash_forward_matches_reference(causal, s, d):
-    q, k, v = _rand_qkv(jax.random.PRNGKey(0), 2, 2, s, d)
-    ref = scaled_dot_product_attention(q, k, v, causal=causal, use_pallas=False)
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+def _dropped_reference(seed, rate, causal):
+    """The jnp attention with the KERNEL's keep mask: the hash is plain
+    uint32 arithmetic, so the same function draws it here."""
+    def ref(q, k, v):
+        b, h, s_q, d = q.shape
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * d ** -0.5
+        if causal:
+            logits = jnp.where(jnp.tril(jnp.ones((s_q, s_q), bool)), logits,
+                               -1e30)
+        keep = jnp.stack([fa._dropout_keep(
+            np.asarray([seed]), i, 0, 0, s_q, k.shape[2], rate)
+            for i in range(b * h)]).reshape(b, h, s_q, -1)
+        probs = jnp.where(keep, jax.nn.softmax(logits, axis=-1) / (1 - rate),
+                          0.0)
+        return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    return ref
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_matches_reference(causal):
-    s, d = 128, 64
-    q, k, v = _rand_qkv(jax.random.PRNGKey(1), 1, 2, s, d)
-
-    def loss_ref(q, k, v):
-        o = scaled_dot_product_attention(q, k, v, causal=causal, use_pallas=False)
-        return jnp.sum(jnp.sin(o))
-
-    def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, interpret=True)
-        return jnp.sum(jnp.sin(o))
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_fl, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5, rtol=5e-4)
+def _padding_bias(rng, b, h, s):
+    bias = np.zeros((b, 1, 1, s), np.float32)
+    for row in range(b):
+        bias[row, ..., int(rng.integers(s // 3, s)):] = -1e9
+    return bias
 
 
-def test_flash_multiblock_causal_grad():
-    # multiple q/k blocks exercises the block-skip logic under causality
-    s, d = 256, 64
-    q, k, v = _rand_qkv(jax.random.PRNGKey(2), 1, 1, s, d)
-
-    def loss_fl(args):
-        o = flash_attention(*args, causal=True, block_q=128, block_k=128,
-                            interpret=True)
-        return jnp.mean(o ** 2)
-
-    def loss_ref(args):
-        o = scaled_dot_product_attention(*args, causal=True, use_pallas=False)
-        return jnp.mean(o ** 2)
-
-    g_fl = jax.grad(loss_fl)((q, k, v))
-    g_ref = jax.grad(loss_ref)((q, k, v))
-    for a, b in zip(g_fl, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-6, rtol=5e-4)
+def _full_bias(rng, b, h, s):
+    return rng.standard_normal((b, h, s, s)).astype(np.float32)
 
 
-@pytest.mark.parametrize("kind", ["key", "full"])
-def test_flash_bias_matches_reference(kind):
-    """Additive bias (HF extended mask / full scores bias) in-kernel must
-    match the jnp reference path, forward and q/k/v gradients."""
-    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
-    from deepspeed_tpu.ops.transformer.functional import (
-        scaled_dot_product_attention)
+# name -> (b, h, s, d), kernel arguments, bias maker
+FLASH_CASES = {
+    f"{'causal' if causal else 'full'}-{s}-{d}":
+        ((1, 2 if s == 128 else 1, s, d), {"causal": causal}, None)
+    for causal in (True, False) for s in (128, 640, 1024, 1536)
+    for d in (64, 128)}
+FLASH_CASES.update({
+    # a grid of several blocks at a short length: the running state, the
+    # skipped steps and the clamped fetches (what the deleted block knobs
+    # of the backward used to reach)
+    "causal-256-in-blocks-of-128":
+        ((1, 2, 256, 64), {"causal": True, "block_q": 128, "block_k": 128},
+         None),
+    "full-512x256-in-blocks-of-128":
+        ((1, 1, 512, 64), {"block_q": 128, "block_k": 128, "s_k": 256},
+         None),
+    "key-padding-bias": ((2, 3, 256, 64), {}, _padding_bias),
+    "key-padding-bias-in-blocks-of-128":
+        ((2, 2, 256, 64), {"block_q": 128, "block_k": 128}, _padding_bias),
+    "full-bias": ((2, 2, 256, 64), {}, _full_bias),
+    "full-bias-causal-in-blocks-of-128":
+        ((1, 2, 256, 64), {"causal": True, "block_q": 128, "block_k": 128},
+         _full_bias),
+    "dropout": ((1, 2, 256, 64), {"dropout_rate": 0.25, "dropout_seed": 7},
+                None),
+    "dropout-causal-in-blocks-of-128":
+        ((1, 2, 256, 64), {"causal": True, "dropout_rate": 0.25,
+                           "dropout_seed": 11, "block_q": 128,
+                           "block_k": 128}, None),
+})
 
-    rng = np.random.default_rng(3)
-    B, H, S, D = 2, 3, 256, 64
-    q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-    if kind == "key":
-        # key-padding: mask out the tail keys of each batch row
-        bias = np.zeros((B, 1, 1, S), np.float32)
-        bias[0, ..., 200:] = -1e9
-        bias[1, ..., 100:] = -1e9
+
+@pytest.mark.parametrize("case", FLASH_CASES)
+def test_flash_matches_f32_reference(case):
+    """Output AND dq/dk/dv against the f32 jnp attention: every walk the
+    wrapper derives (one block, the staircase of a diagonal tile at 640 /
+    1024, a grid of three blocks of 512 at 1536), both head sizes, the
+    biases, and dropout with the kernel's own mask drawn outside it, which
+    fails unless the forward and both sweeps regenerate the same one."""
+    (b, h, s, d), kw, make_bias = FLASH_CASES[case]
+    kw = dict(kw)
+    s_k = kw.pop("s_k", s)
+    rng = np.random.default_rng(sum(map(ord, case)))
+    q = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b, h, s_k, d)), jnp.float32)
+            for _ in range(2))
+    bias = None if make_bias is None else jnp.asarray(make_bias(rng, b, h, s))
+    if "dropout_rate" in kw:
+        reference = _dropped_reference(kw["dropout_seed"], kw["dropout_rate"],
+                                       kw.get("causal", False))
     else:
-        bias = rng.standard_normal((B, H, S, S)).astype(np.float32)
-    bias = jnp.asarray(bias)
+        def reference(q, k, v):
+            return scaled_dot_product_attention(
+                q, k, v, bias=bias, causal=kw.get("causal", False),
+                use_pallas=False)
 
-    ref = scaled_dot_product_attention(q, k, v, bias=bias, use_pallas=False)
-    got = flash_attention(q, k, v, bias=bias, interpret=True,
-                          block_q=128, block_k=128)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, bias=bias, interpret=True, **kw)
 
-    def loss_ref(q, k, v):
-        return scaled_dot_product_attention(
-            q, k, v, bias=bias, use_pallas=False).sum()
+    def with_grads(attn):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out, *vjp(jnp.cos(out)))
 
-    def loss_flash(q, k, v):
-        return flash_attention(q, k, v, bias=bias, interpret=True,
-                               block_q=128, block_k=128).sum()
+    for name, got, want in zip(("out", "dq", "dk", "dv"),
+                               with_grads(kernel), with_grads(reference)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), err_msg=name,
+            **(dict(atol=2e-5, rtol=2e-5) if name == "out"
+               else dict(atol=5e-5, rtol=5e-4)))
 
-    gr = jax.grad(loss_ref, (0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_flash, (0, 1, 2))(q, k, v)
-    for a, b in zip(gr, gf):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-2, atol=2e-2)
+
+@pytest.mark.parametrize("s,block,causal,want", [
+    (1024, 256, True, (10, 4, 6)),      # the training cells' walk
+    (1024, 128, True, (36, 8, 28)),
+    (1024, 512, True, (3, 2, 1)),
+    (1024, 1024, True, (1, 1, 0)),
+    (1536, 512, True, (6, 3, 3)),
+    (1024, 256, False, (16, 0, 0)),
+])
+def test_flash_tiles_counts_the_walk(s, block, causal, want):
+    assert fa.flash_tiles(s, s, block, block, causal) == want
+    # nothing above the diagonal is visited, only diagonal tiles are masked
+    for q in range(0, s, block):
+        for k in range(0, s, block):
+            kind = fa._tile_kind(q, q + block, k, k + block, causal)
+            assert kind == ("full" if not causal or k < q else
+                            "mask" if k == q else "skip")
+
+
+@pytest.mark.parametrize("keys_first", [False, True])
+def test_a_diagonal_tile_is_walked_as_the_tiles_counted(keys_first):
+    """The kernels' loop bounds are the counter's tiles: every piece of
+    the staircase is a run of 'full' tiles or one 'mask' tile, and the
+    pieces' area is what flash_tiles visits."""
+    walk = fa._walk(1024, 256, 1024, True, keys_first)
+    visited, masked, _ = fa.flash_tiles(1024, 1024, 256, 256, True)
+    pieces = [p for _, row in walk for p in row]
+    assert sum(hi - lo for lo, hi, _ in pieces) == visited * 256
+    assert sum(m for _, _, m in pieces) == masked
+    assert all(hi - lo == 256 for lo, hi, m in pieces if m)
+    lo, row = walk[1]
+    assert lo == 256 and row == (
+        [(256, 512, True), (512, 1024, False)] if keys_first
+        else [(0, 256, False), (256, 512, True)])
+    assert fa._walk(512, 256, 384, False) == [
+        (0, [(0, 384, False)]), (256, [(0, 384, False)])]
+
+
+def test_lse_of_rows_whose_later_blocks_are_skipped():
+    """Query block 0 of a grid of several sees key block 0 alone: its
+    statistics are written after the skipped steps, from the state the one
+    visited step left, one value a row."""
+    rng = np.random.default_rng(4)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 384, 64)), jnp.float32)
+               for _ in range(3))
+    out, lse = fa._flash_fwd(
+        q, k, v, None, None, scale=0.125, causal=True, bias_kind="none",
+        num_heads=1, dropout_rate=0.0, block_q=128, block_k=128,
+        interpret=True)
+    assert lse.shape == (2, 1, 384) and lse.dtype == jnp.float32
+    logits = jnp.einsum("bqd,bkd->bqk", q, k) * 0.125
+    logits = jnp.where(jnp.tril(jnp.ones((384, 384), bool)), logits, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse[:, 0]),
+        np.asarray(jax.scipy.special.logsumexp(logits, axis=-1)),
+        atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(jnp.einsum(
+            "bqk,bkd->bqd", jax.nn.softmax(logits, axis=-1), v)),
+        atol=2e-5, rtol=2e-5)
+
+
+def test_nothing_of_the_kernel_is_read_from_the_environment():
+    """Block sizes are derived from the lengths; the knobs are gone."""
+    import inspect
+
+    source = inspect.getsource(fa)
+    assert "environ" not in source and "DSTPU_" not in source
+    assert not hasattr(fa, "_lse_2d")
+    # 640 is one block walked in rows of 128, 1536 three blocks of 512
+    assert (fa._fit_block(fa._BLOCK, 640), fa._fit_block(fa._SUB, 640)) \
+        == (640, 128)
+    assert fa._fit_block(fa._BLOCK, 1536) == 512
 
 
 def test_flash_bias_constant_no_grad():
@@ -264,33 +345,6 @@ def test_dropout_gradients_multiblock():
             .astype(jnp.float32).sum()
 
     check_grads(f, (q, k, v), order=1, modes=["rev"], rtol=2e-2, atol=2e-2)
-
-
-def test_lse_compact_wire_format_matches(monkeypatch):
-    """DSTPU_FLASH_LSE2D=1 carries lse/delta as compact (bh, s_q) tiles
-    instead of 128-lane broadcasts; outputs and gradients must be
-    bit-identical to the legacy layout (it is pure wire format)."""
-    import deepspeed_tpu.ops.transformer.flash_attention as fa
-
-    rng = np.random.default_rng(21)
-    q = jnp.asarray(rng.standard_normal((1, 2, 256, 64)) * 0.3, jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 2, 256, 64)) * 0.3, jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 2, 256, 64)) * 0.3, jnp.float32)
-
-    def run():
-        def f(q, k, v):
-            return fa.flash_attention(
-                q, k, v, causal=True, block_q=128, block_k=128,
-                interpret=True).astype(jnp.float32).sum()
-        return f(q, k, v), jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-
-    monkeypatch.delenv("DSTPU_FLASH_LSE2D", raising=False)
-    base_loss, base_g = run()
-    monkeypatch.setenv("DSTPU_FLASH_LSE2D", "1")
-    new_loss, new_g = run()
-    np.testing.assert_array_equal(np.asarray(base_loss), np.asarray(new_loss))
-    for a, b in zip(base_g, new_g):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_auto_dispatch_chooses_by_lowering_platform():

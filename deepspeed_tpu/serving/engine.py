@@ -551,11 +551,16 @@ def _pick_next(logits, seeds, pos, temperature, top_k, top_p):
     )(logits, keys).astype(jnp.int32)
 
 
-def _shard_wrap(core, mesh, axis_name, n_pool, in_streams, n_out_streams):
+def _shard_wrap(core, name, mesh, axis_name, n_pool, in_streams,
+                n_out_streams):
     """jit(shard_map(core)) with pool tensors split on the block axis,
     per-slot streams split on the slot axis and params replicated; plain
     jit when mesh is None.  ``in_streams``/``n_out_streams`` mark which
-    trailing args / leading-after-pool outputs carry the slot axis."""
+    trailing args / leading-after-pool outputs carry the slot axis.
+    ``name`` is the program's ONE name: the jit's (``jit_<name>`` in the
+    profiler's module line, the compile log and the persistent cache) and,
+    read back from the jit's ``__name__``, the program registry's."""
+    core.__name__ = core.__qualname__ = name
     donate = tuple(range(1, 1 + n_pool))
     if mesh is None:
         return jax.jit(core, donate_argnums=donate)
@@ -609,7 +614,8 @@ def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
         nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
         return (*_held_groups(pools), *_stats_out(stats), nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool_args(cfg, quantized),
+    return _shard_wrap(run, "decode_step", mesh, axis_name,
+                       _n_pool_args(cfg, quantized),
                        in_streams=(True,) * 6, n_out_streams=2)
 
 
@@ -662,7 +668,8 @@ def _make_spec_verify(cfg, K, W, bs, quantized, mesh, axis_name):
         nxt = jnp.where(active[:, None], nxt, 0)
         return (*_held(pools), nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
+    return _shard_wrap(run, "spec_verify", mesh, axis_name,
+                       _n_pool(n_rows, quantized),
                        in_streams=(True,) * 6, n_out_streams=2)
 
 
@@ -710,7 +717,8 @@ def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
                          temperature, top_k, top_p)
         return (*out, nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool_args(cfg, quantized),
+    return _shard_wrap(run, f"prefill_chunk{C}" + ("_final" if final else ""),
+                       mesh, axis_name, _n_pool_args(cfg, quantized),
                        in_streams=(True, False, False, True, False),
                        n_out_streams=2 if final else 0)
 
@@ -746,7 +754,8 @@ def _make_sparse_decode_step(cfg, W, K, bs, quantized, temperature, top_k,
         nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
         return (*_held(pools), nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
+    return _shard_wrap(run, "sparse_decode_step", mesh, axis_name,
+                       _n_pool(n_rows, quantized),
                        in_streams=(True,) * 8, n_out_streams=2)
 
 
@@ -796,7 +805,9 @@ def _make_sparse_prefill_chunk(cfg, C, W, K, bs, win, g, quantized, final,
                          temperature, top_k, top_p)
         return (*out, nxt, finite)
 
-    return _shard_wrap(run, mesh, axis_name, _n_pool(n_rows, quantized),
+    return _shard_wrap(run, f"sparse_prefill_chunk{C}"
+                       + ("_final" if final else ""), mesh, axis_name,
+                       _n_pool(n_rows, quantized),
                        in_streams=(True, True, True, False, False, True,
                                    False),
                        n_out_streams=2 if final else 0)
@@ -963,15 +974,15 @@ class InferenceEngine:
                                     np.int32)
             self._sbase = np.full((S, self.sparse.K),
                                   int(self.sparse.sentinel), np.int32)
-            self._decode_name = "sparse_decode_step"
             self._decode = _make_sparse_decode_step(
                 cfg, self.W, self.sparse.K, self.bs, self.pool.quantized,
                 self.temperature, self.top_k, self.top_p, mesh, axis_name)
         else:
-            self._decode_name = "decode_step"
             self._decode = _make_decode_step(
                 cfg, self.W, self.bs, self.pool.quantized,
                 self.temperature, self.top_k, self.top_p, mesh, axis_name)
+        # a program's one name is its jit's (_shard_wrap)
+        self._decode_name = self._decode.__name__
 
     @property
     def program_registry(self):
@@ -1154,6 +1165,11 @@ class InferenceEngine:
         self._run = None
         self._gap = None
         self._gap_idle = False
+        # one level down (_mark): the open phase span, the a0 it will end
+        # with, and when the last fetch returned (step_host_us)
+        self._phase = None
+        self._phase_a0 = -1
+        self._fetched_at = None
         # per program dispatched since the last fetch, while a tracer is
         # armed: (group, counters known on the host, the model's counters
         # still on the device or None) -- see _note_program
@@ -1190,6 +1206,10 @@ class InferenceEngine:
             self._tracer.intern("run_decode", args=("lanes",))
             self._tracer.intern("run_prefill", args=("bucket",))
             self._tracer.intern("run_prefill_decode", args=("lanes",))
+            self._tracer.intern("dispatch", args=("bucket",))
+            for phase in ("tables", "fetch", "tokens"):
+                self._tracer.intern(phase, args=("lanes",))
+            self._tracer.intern("step_host_us", args=("us",))
         # measured HBM accounting (ISSUE 15): per-jit memory_analysis()
         # registered capture-by-shape alongside MFU, sharing its lazy
         # compile cache — one compile per jit, zero on the decode path
@@ -1351,8 +1371,16 @@ class InferenceEngine:
         fetch, and the journal's step-boundary commit."""
         self._step_idx += 1
         tr = self._tracer
-        _step = tr.span("serving_step", self._lane_serve) \
-            if tr is not None else None
+        # serving_step, prefill_tick and decode_step time a call from its
+        # own boundary (their readers: decode_step_ms, prefill_tick_ms and
+        # their twins).  Each begins and ends at the instant of a mark,
+        # after the phase that ends there and before the one that begins,
+        # so that the profiler's annotations nest
+        now = None
+        if tr is not None:
+            now = self._end_phase()
+            _step = tr.span("serving_step", self._lane_serve, t0=now)
+        self._mark("step_begin", at=now)
         slow = chaos.serving_slow_step_s(self._step_idx) \
             + chaos.fleet_slow_replica_s(self._replica_index,
                                          self._step_idx)
@@ -1373,16 +1401,22 @@ class InferenceEngine:
         if rid is not None and self.cancel(rid):
             events["cancelled"].append(rid)
         self._enforce_deadlines(events)
-        if tr is None:
-            self._prefill_tick(events)
-            decoded = self._decode_tick(events)
-        else:
-            _tick = tr.span("prefill_tick", self._lane_serve)
-            self._prefill_tick(events)
-            _tick.end()
-            _tick = tr.span("decode_step", self._lane_serve)
-            decoded = self._decode_tick(events)
-            _tick.end(a0=decoded)
+        if tr is not None:
+            now = self._end_phase()
+            _tick = tr.span("prefill_tick", self._lane_serve, t0=now)
+        self._mark("prefill_prep", at=now)
+        self._prefill_tick(events)
+        if tr is not None:
+            now = self._end_phase()
+            _tick.end(at=now)
+            _tick = tr.span("decode_step", self._lane_serve, t0=now)
+        self._mark("tables", len(self.scheduler.running), at=now)
+        decoded = self._decode_tick(events)
+        if tr is not None:
+            now = self._end_phase()
+            _tick.end(a0=decoded, at=now)
+        self._mark("step_end", at=now)
+        if tr is not None:
             for rid_ in events["admitted"]:
                 tr.instant("admit", self._lane_serve, a0=rid_)
         self.reliability.on_step_end()
@@ -1435,7 +1469,9 @@ class InferenceEngine:
                     and not self.scheduler.in_flight():
                 # this gap is want of demand, not the host's doing
                 self._gap_idle = True
-            _step.end(a0=self._step_idx)
+            now = self._end_phase()
+            _step.end(a0=self._step_idx, at=now)
+            self._mark("caller", at=now)
         if self.telemetry is not None and not self._warming:
             self.telemetry.on_step(self._step_idx, self._last_metrics)
         return events
@@ -2066,7 +2102,45 @@ class InferenceEngine:
             (q if g.window is None else np.minimum(q, g.window)).sum())
             for g in self.groups}
 
-    def _dispatch(self, span, fn, args):
+    def _mark(self, name, a0=-1, at=None):
+        """The serve thread's ONE phase cursor, a level below ``host_gap``
+        / ``run_*``: with a tracer armed the open phase span ends at
+        ``at`` (now when None) and the span ``name`` opens at that very
+        instant, to end with ``a0`` at the next mark, so the phases
+        partition the thread's time by construction.  Disarmed this is
+        the ``is None`` test alone.
+
+        ``step_begin`` (entry of ``step()``: chaos hooks, watchdog, the
+        deadline sweep), ``prefill_prep`` (``_prefill_tick`` up to its
+        dispatch: admission, the chunk's pages, padding and arguments),
+        ``dispatch`` (the call that sends a program and the host's work
+        while it is in flight, ``_rebind`` and ``_note_program``, up to
+        whatever is marked next; a0 the bucket of a chunk, 0 for a
+        decode or verify program), ``tables`` (the decode tick up to its
+        dispatch: growth, table rows, arguments; a0 the lanes running at
+        its entry), ``fetch`` (``jax.device_get`` alone; a0 the lanes
+        decoded under it), ``tokens`` (from its return to the end of the
+        tick: the bookkeeping a lane), ``step_end`` (metrics and reports
+        up to the end of ``serving_step``), ``caller`` (from there to the
+        entry of the next ``step()``: the hub's ``on_step`` and the
+        caller's own time, its ``submit()`` calls among it)."""
+        tr = self._tracer
+        if tr is not None:
+            at = self._end_phase(at)
+            self._phase = tr.span(name, self._lane_serve, t0=at)
+            self._phase_a0 = a0
+
+    def _end_phase(self, at=None):
+        """End the open phase span, if any, at ``at`` (now when None:
+        read once, returned).  Armed only."""
+        if at is None:
+            at = self._tracer.clock()
+        phase, self._phase = self._phase, None
+        if phase is not None:
+            phase.end(a0=self._phase_a0, at=at)
+        return at
+
+    def _dispatch(self, span, fn, args, bucket=0):
         """Every serving program goes to the device through here, and
         every result comes back through :meth:`_fetch`: between them a
         traced engine knows whether anything of its own is unfetched.
@@ -2075,15 +2149,22 @@ class InferenceEngine:
         what opens it (``span``: ``run_decode``, ``run_prefill`` for a
         final chunk, ``run_prefill_decode`` for a non-final chunk, which
         runs on under whatever is dispatched next); the rest of the
-        serve thread's time is ``host_gap``.  Disarmed this is the call
-        alone."""
+        serve thread's time is ``host_gap``.  The call, and what the
+        host does before it marks anything else, is the phase
+        ``dispatch`` (:meth:`_mark`), which begins at the instant the
+        stretch does and lies inside it, in the ring and among the
+        profiler's annotations: the phase before is left before the gap
+        is, and this one entered after the stretch.  Disarmed this is the
+        call alone."""
         tr = self._tracer
-        if tr is not None and self._run is None:
-            now = tr.clock()
-            if self._gap is not None:
-                self._gap.end(a0=0 if self._gap_idle else 1, at=now)
-                self._gap = None
-            self._run = tr.span(span, self._lane_serve, t0=now)
+        if tr is not None:
+            now = self._end_phase()
+            if self._run is None:
+                if self._gap is not None:
+                    self._gap.end(a0=0 if self._gap_idle else 1, at=now)
+                    self._gap = None
+                self._run = tr.span(span, self._lane_serve, t0=now)
+            self._mark("dispatch", bucket, at=now)
         return fn(*args)
 
     def _note_program(self, group, out, n_pool, **counters):
@@ -2101,15 +2182,29 @@ class InferenceEngine:
         """The step's ONE batched fetch.  It waits for every program
         still in flight, so it ends the open ``run_*`` span (a0: the
         bucket of a final chunk that ran alone, else the lanes decoded
-        under it) and opens the next ``host_gap`` at the same instant."""
+        under it) and opens the next ``host_gap`` at the same instant.
+        The wait itself is the phase ``fetch``, which ends at that
+        instant too, where ``tokens`` begins (:meth:`_mark`)."""
         pending, self._stats_pending = self._stats_pending, []
+        self._mark("fetch", lanes)
         fetched, stats = jax.device_get(
             (arrays, [row for _, _, row in pending if row is not None]))
         tr = self._tracer
         if tr is not None:
+            began = self._phase.t0
+            now = self._end_phase()
             run, self._run = self._run, None
-            now = run.end(a0=bucket if run.name == "run_prefill"
-                          else lanes)
+            run.end(a0=bucket if run.name == "run_prefill" else lanes,
+                    at=now)
+            # the serve thread's time since the last fetch returned that
+            # was NOT spent waiting in this one (this fetch's return less
+            # the last one's less this wait: the last one's return to this
+            # one's entry), unless the engine stood empty in between: the
+            # host's share of a step, exact a step
+            if self._fetched_at is not None and not self._gap_idle:
+                tr.count("step_host_us", self._lane_serve, int(round(
+                    1e6 * (began - self._fetched_at))), at=now)
+            self._fetched_at = now
             # every program this fetch waited for: its counters as
             # zero-length spans ``<counter>_<group>`` (a0 the value), and
             # under ``clock_ms_<group>`` the tracer's clock, so that a
@@ -2126,6 +2221,7 @@ class InferenceEngine:
                              at=now)
             self._gap = tr.span("host_gap", self._lane_serve, t0=now)
             self._gap_idle = False
+            self._mark("tokens", lanes, at=now)
         return fetched
 
     def _prefill_args(self, req, n):
@@ -2192,8 +2288,6 @@ class InferenceEngine:
             srows[req.shard], sbases[req.shard] = \
                 self.sparse.prefill_active_row(rows[req.shard], start, n,
                                                bucket)
-            pf_name = f"sparse_prefill_chunk{bucket}" \
-                + ("_final" if final else "")
             pf_args = (self.params, *self.pool.tensors.arrays, rows,
                        srows, sbases, tok_pad, np.int32(start), nv,
                        np.int32(req.seed))
@@ -2204,12 +2298,11 @@ class InferenceEngine:
                 self.cfg, bucket, self.W, self.bs, self.pool.quantized,
                 final, self.temperature, self.top_k, self.top_p,
                 self.mesh, self.axis_name)
-            pf_name = f"prefill_chunk{bucket}" + ("_final" if final
-                                                  else "")
             pf_args = (self.params, *self.pool.all_arrays, rows,
                        tok_pad, np.int32(start), nv, np.int32(req.seed))
             group = "serving:prefill_final" if final \
                 else "serving:prefill"
+        pf_name = fn.__name__       # [sparse_]prefill_chunk<bucket>[_final]
         # bucketed prefill programs at the same schedule slot must post
         # identical collective sequences (uniform_group) — a divergence
         # between buckets would deadlock a multi-host SPMD dispatch
@@ -2224,7 +2317,8 @@ class InferenceEngine:
             register_by_shape(self.telemetry.mfu, pf_name, fn, pf_args)
             mem_acc.register_by_shape(self._memacct, pf_name, fn, pf_args)
         out = self._dispatch(
-            "run_prefill" if final else "run_prefill_decode", fn, pf_args)
+            "run_prefill" if final else "run_prefill_decode", fn, pf_args,
+            bucket=bucket)
         req.work_done += n
         self.metrics.record_prefill(n)
         n_pool = self.n_pool_tensors()
@@ -2332,16 +2426,16 @@ class InferenceEngine:
         spec_args = (self.params, *self.pool.tensors.arrays,
                      self._tables, self._pos, toks_in, nvalid,
                      self._active, self._poison)
-        self._register_serving_program("spec_verify", self._spec,
+        self._register_serving_program(self._spec.__name__, self._spec,
                                        spec_args)
         if tel is not None:
             from deepspeed_tpu.runtime import memory_accounting as mem_acc
             from deepspeed_tpu.telemetry import register_by_shape
 
-            register_by_shape(tel.mfu, "spec_verify", self._spec,
+            register_by_shape(tel.mfu, self._spec.__name__, self._spec,
                               spec_args)
             mem_acc.register_by_shape(
-                self._memacct, "spec_verify", self._spec, spec_args,
+                self._memacct, self._spec.__name__, self._spec, spec_args,
                 expect_label="serving draft-verify step: donated "
                 "in-place KV block pool + argmax continuations")
         out = self._dispatch("run_decode", self._spec, spec_args)

@@ -1,20 +1,22 @@
 """Host-side span tracer: preallocated ring buffer, Chrome-trace export.
 
 The hot path never touches a device or forces a transfer (the graftlint
-host-sync bar): one clock read at ``begin()``, one clock read plus a
-handful of scalar array writes at ``complete()``/``instant()``.  The
-event payload is five preallocated numpy columns (timestamp, duration,
-interned name id, lane id, two integer args) written at a wrapping ring
-index under a lock (the async checkpoint-commit thread and the training
-thread share one tracer).
+host-sync bar): one clock read at ``begin()``, one clock read plus
+one row store at ``complete()``/``instant()``.  The event payload is
+one preallocated buffer of packed 41-byte rows (timestamp, duration,
+interned name id, lane id, phase, two integer args), a row written by ONE
+``struct`` store at a wrapping ring index under a lock (the async
+checkpoint-commit thread and the training thread share one tracer; a
+row is one or two cache lines where a column each was seven).
 
 ``span()`` opens a span that is two things at once: the ring event, and
 a ``jax.profiler.TraceAnnotation`` named ``dstpu:<lane>/<name>``, begun
 together and ended together by ``Span.end()``.  Inside a profiler
 session that puts the program's span on the device trace's clock, on
-the calling thread's line above the device's op line; outside one the
-annotation is a flag test and one small object.  It is host-side too: a
-``TraceMe``, no device call.
+the calling thread's line above the device's op line; outside one
+none is made (``TraceAnnotation.is_enabled()``: a flag test), for
+nothing would see it.  It is host-side too: a ``TraceMe``, no device
+call.
 
 Disarmed is exactly free: engines hold ``self._tracer = None`` and every
 instrumentation site is a single attribute-load-and-``is None`` branch —
@@ -37,6 +39,7 @@ side).
 """
 import json
 import os
+import struct
 import threading
 import time
 
@@ -45,7 +48,16 @@ import numpy as np
 _PH_SPAN = 0
 _PH_INSTANT = 1
 
-DEFAULT_CAPACITY = 65536
+# one event of the ring, as it is stored and as the read side views it
+_ROW = struct.Struct("<ddiibqq")
+_ROW_DTYPE = np.dtype([("ts", "<f8"), ("dur", "<f8"), ("name", "<i4"),
+                       ("lane", "<i4"), ("ph", "i1"), ("a0", "<i8"),
+                       ("a1", "<i8")])
+assert _ROW.size == _ROW_DTYPE.itemsize == 41
+
+# a traced serving step records ~25 events (docs/tutorials/observability.md,
+# the overhead contract): a benchmark run of ~4,500 steps fits twice over
+DEFAULT_CAPACITY = 1 << 18
 MIN_CAPACITY = 256
 ANNOTATION_PREFIX = "dstpu:"
 
@@ -71,35 +83,31 @@ class Span:
             at = tr.clock()
         tr._record(_PH_SPAN, self.name, self.lane, self.t0, at - self.t0,
                    a0, a1)
-        self._annotation.__exit__(None, None, None)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         return at
 
 
 class Tracer:
     """Ring-buffer span/instant recorder (see module docstring).
 
-    ``capacity`` bounds host memory (5 numpy columns, ~34 B/event); once
-    exceeded the OLDEST events are overwritten and ``dropped`` counts
-    them — the tracer never grows and never throws on overflow.
+    ``capacity`` bounds host memory (41 B/event: ~11 MB at the default);
+    once exceeded the OLDEST events are overwritten and ``dropped``
+    counts them — the tracer never grows and never throws on overflow.
     """
 
     def __init__(self, capacity=DEFAULT_CAPACITY, clock=time.perf_counter):
         capacity = max(MIN_CAPACITY, int(capacity))
         self.capacity = capacity
         self.clock = clock
-        self._ts = np.zeros(capacity, np.float64)
-        self._dur = np.zeros(capacity, np.float64)
-        self._name = np.zeros(capacity, np.int32)
-        self._lane = np.zeros(capacity, np.int32)
-        self._ph = np.zeros(capacity, np.int8)
-        self._a0 = np.full(capacity, -1, np.int64)
-        self._a1 = np.full(capacity, -1, np.int64)
+        self._rows = bytearray(capacity * _ROW.size)
         self._n = 0                     # total events ever recorded
         self._names = []                # id -> name
         self._name_ids = {}             # name -> id
         self._arg_labels = {}           # name id -> (label0, label1)
         self._lanes = []                # id -> lane name
         self._lane_ids = {}             # lane name -> id
+        self._lane_labels = []          # id -> "dstpu:<lane>/"
         self._lock = threading.Lock()
         # resolved when a tracer is armed, never on a disarmed path
         from jax.profiler import TraceAnnotation
@@ -116,6 +124,7 @@ class Tracer:
                 lid = len(self._lanes)
                 self._lanes.append(str(name))
                 self._lane_ids[name] = lid
+                self._lane_labels.append(f"{ANNOTATION_PREFIX}{name}/")
             return lid
 
     def intern(self, name, args=()):
@@ -144,9 +153,10 @@ class Tracer:
         """Open a span in the ring AND in the profiler's trace (module
         docstring); ``t0`` hands over the instant a previous span ended
         at.  Close it with :meth:`Span.end`, also from another call."""
-        # a TraceAnnotation begins when it is made: __enter__ adds nothing
-        annotation = self._annotate(
-            f"{ANNOTATION_PREFIX}{self._lanes[lane]}/{name}")
+        # a TraceAnnotation begins when it is made: __enter__ adds nothing;
+        # one made outside a profiler session is never seen, so none is
+        annotation = self._annotate(self._lane_labels[lane] + name) \
+            if self._annotate.is_enabled() else None
         return Span(self, name, lane, self.clock() if t0 is None else t0,
                     annotation)
 
@@ -168,14 +178,13 @@ class Tracer:
                 nid = len(self._names)
                 self._names.append(str(name))
                 self._name_ids[name] = nid
-            i = self._n % self.capacity
-            self._ts[i] = ts
-            self._dur[i] = dur
-            self._name[i] = nid
-            self._lane[i] = lane
-            self._ph[i] = ph
-            self._a0[i] = a0
-            self._a1[i] = a1
+            at = (self._n % self.capacity) * _ROW.size
+            try:
+                _ROW.pack_into(self._rows, at, ts, dur, nid, lane, ph, a0,
+                               a1)
+            except struct.error:        # an arg that is no plain integer
+                _ROW.pack_into(self._rows, at, ts, dur, nid, lane, ph,
+                               int(a0), int(a1))
             self._n += 1
 
     # -- read side ------------------------------------------------------
@@ -195,21 +204,20 @@ class Tracer:
         seconds; ``a0``/``a1`` are the caller's integer args, -1 =
         unset)."""
         with self._lock:
-            n = min(self._n, self.capacity)
-            start = self._n - n
-            idx = [(start + k) % self.capacity for k in range(n)]
-            out = []
-            for i in idx:
-                out.append({
-                    "name": self._names[self._name[i]],
-                    "lane": self._lanes[self._lane[i]],
-                    "ph": "X" if self._ph[i] == _PH_SPAN else "i",
-                    "ts": float(self._ts[i]),
-                    "dur": float(self._dur[i]),
-                    "a0": int(self._a0[i]),
-                    "a1": int(self._a1[i]),
-                })
-            return out
+            rows = self._retained()
+            names, lanes = list(self._names), list(self._lanes)
+        return [{"name": names[nid], "lane": lanes[lane],
+                 "ph": "X" if ph == _PH_SPAN else "i",
+                 "ts": ts, "dur": dur, "a0": a0, "a1": a1}
+                for ts, dur, nid, lane, ph, a0, a1 in rows.tolist()]
+
+    def _retained(self):
+        """A copy of the retained rows, oldest first (hold the lock)."""
+        rows = np.frombuffer(self._rows, _ROW_DTYPE)
+        if self._n <= self.capacity:
+            return rows[:self._n].copy()
+        head = self._n % self.capacity
+        return np.concatenate((rows[head:], rows[:head]))
 
     def reset(self):
         with self._lock:
@@ -240,8 +248,7 @@ class Tracer:
         (temp file + rename) so a crash mid-export never leaves a torn
         trace.  Returns ``path``."""
         with self._lock:
-            n = min(self._n, self.capacity)
-            start = self._n - n
+            rows = self._retained().tolist()
             trace_events = [{
                 "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
                 "args": {"name": process_name},
@@ -253,21 +260,17 @@ class Tracer:
                 trace_events.append({
                     "ph": "M", "name": "thread_sort_index", "pid": pid,
                     "tid": lid, "args": {"sort_index": lid}})
-            for k in range(n):
-                i = (start + k) % self.capacity
-                nid = int(self._name[i])
-                ts_us = self._ts[i] * 1e6
+            for ts, dur, nid, lane, ph, a0, a1 in rows:
                 base = {"name": self._names[nid], "cat": "telemetry",
-                        "pid": pid, "tid": int(self._lane[i]),
-                        "args": self._event_args(nid, int(self._a0[i]),
-                                                 int(self._a1[i]))}
-                if self._ph[i] == _PH_INSTANT:
+                        "pid": pid, "tid": lane,
+                        "args": self._event_args(nid, a0, a1)}
+                if ph == _PH_INSTANT:
                     trace_events.append(dict(base, ph="i", s="t",
-                                             ts=round(ts_us, 3)))
+                                             ts=round(ts * 1e6, 3)))
                 else:
                     trace_events.append(dict(
-                        base, ph="X", ts=round(ts_us, 3),
-                        dur=round(self._dur[i] * 1e6, 3)))
+                        base, ph="X", ts=round(ts * 1e6, 3),
+                        dur=round(dur * 1e6, 3)))
             payload = {"traceEvents": trace_events,
                        "displayTimeUnit": "ms",
                        "otherData": {"dropped_events": self.dropped}}

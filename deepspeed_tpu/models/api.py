@@ -12,6 +12,7 @@ The TPU engine is functional: a model is anything exposing
 
 ``FlaxModel`` adapts a flax linen module + loss head to this contract.
 """
+import functools
 from typing import Any, Callable, Optional
 
 
@@ -97,12 +98,58 @@ def chunked_lm_cross_entropy(hidden, wte, labels, chunk_tokens: int = 2048,
     (round-4 profile; the reference leans on fused CUDA softmax-xent kernels
     for the same reason, csrc/transformer/softmax_kernels.cu).
 
+    Where the chunks are cut: on a mesh whose 'data' axis is larger than one
+    and still the partitioner's to place (Auto), and divides the rows (dim 0
+    of ``hidden``), each chip walks chunks of ITS OWN rows' tokens (a
+    shard_map over 'data' alone; every other axis stays the partitioner's).
+    Across 'data' then go one (sum of token losses, count of counted tokens)
+    pair a chip, and backward the ``wte`` gradient, once a call. Chunks cut
+    through the flattened global batch hold tokens of several chips, and the
+    partitioner then moves every chunk's hidden rows or float32 logits
+    across 'data' (gpt2-xl on four chips: 824 MB a chunk, forward and
+    recomputation; PERF.md §6, PR 40). Everywhere else (no mesh, one device,
+    rows the axis does not divide, 'data' Manual as inside the 1-bit Adam
+    wire step, where the tokens are local already) the chunks are cut through
+    the flattened batch. Either way the loss is the global sum over the
+    global count, never a mean of the chips' means.
+
     hidden: (..., E) activations entering the LM head (already shifted);
     wte: (V, E) tied embedding; labels: (...) int targets aligned to hidden;
     valid_vocab: when wte carries MXU-alignment pad rows (V > true vocab),
     columns >= valid_vocab are masked out of the softmax so padding stays an
     invisible layout detail.
     """
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    sums = functools.partial(
+        _chunked_nll_sums, chunk_tokens=chunk_tokens,
+        ignore_index=ignore_index, valid_vocab=valid_vocab)
+    rows = P(mesh_lib.DATA_AXIS)
+    chips = mesh_lib.auto_axis_size(mesh_lib.DATA_AXIS)
+    if hidden.ndim > 1 and chips > 1 and hidden.shape[0] % chips == 0:
+        # Nothing communicates inside the map: a chip gets its own copy of
+        # wte and returns one (sum, count) pair, and the partitioner adds up
+        # the pairs and, backward, the copies' gradients.
+        nll_sum, cnt = jax.shard_map(
+            lambda x, w, y: jnp.stack(sums(x, w[0], y))[None],
+            in_specs=rows, out_specs=rows,
+            axis_names={mesh_lib.DATA_AXIS}, check_vma=False,
+        )(hidden, jnp.broadcast_to(wte, (chips, *wte.shape)), labels).sum(0)
+    else:
+        nll_sum, cnt = sums(hidden, wte, labels)
+    loss = nll_sum / jnp.maximum(cnt, 1.0)
+    return loss, {"loss": loss}
+
+
+def _chunked_nll_sums(hidden, wte, labels, chunk_tokens, ignore_index,
+                      valid_vocab):
+    """(sum of the counted tokens' losses, their count), both float32, of
+    :func:`chunked_lm_cross_entropy`: the scan over chunks of the flattened
+    tokens it is handed."""
     import jax
     import jax.numpy as jnp
 
@@ -143,8 +190,6 @@ def chunked_lm_cross_entropy(hidden, wte, labels, chunk_tokens: int = 2048,
         nll = (logz - gold) * mc
         return (nll_sum + jnp.sum(nll), cnt + jnp.sum(mc)), None
 
-    (nll_sum, cnt), _ = jax.lax.scan(
+    return jax.lax.scan(
         jax.checkpoint(body), (jnp.float32(0.0), jnp.float32(0.0)),
-        (xs, ys, valids))
-    loss = nll_sum / jnp.maximum(cnt, 1.0)
-    return loss, {"loss": loss}
+        (xs, ys, valids))[0]

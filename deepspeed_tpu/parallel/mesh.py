@@ -159,6 +159,19 @@ def shards_evenly(shape, spec) -> bool:
     return True
 
 
+def auto_axis_size(axis) -> int:
+    """Size of ``axis`` in the active mesh where it is still Auto, the
+    partitioner's to place; 1 where there is no mesh, the axis is absent, or
+    a shard_map already maps it (Manual: the data is device-local there, and
+    a second map over it cannot nest)."""
+    import jax
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or axis not in mesh.auto_axes:
+        return 1
+    return mesh.shape[axis]
+
+
 def per_shard(fn, in_specs, out_spec):
     """``fn`` wrapped to run once per shard of the active mesh — for Mosaic
     (Pallas TPU) kernels, which GSPMD cannot partition: on a mesh of more

@@ -16,8 +16,8 @@ Same mathematics.
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.transformer.rect_attention import (
-    mla_decode_attention, rect_flash_attention)
+from deepspeed_tpu.ops.transformer.rect_attention import \
+    rect_flash_attention
 
 
 def _rope_interleaved(x, cos, sin):
@@ -50,7 +50,7 @@ def _swiglu(x, p):
 
 
 def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
-                     latent_scale=1.0, row=0, paged_decode=False):
+                     latent_scale=1.0, row=0):
     """One latent attention over x (B, T, E), through the engine's cache
     hook (``serving/decoder.py``), output projection included.
 
@@ -71,14 +71,12 @@ def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
         cached, so prefill and decode read it alike.
     ``row``
         which of the block's raw cache rows this attention writes and
-        reads (``cache.write_rows`` / ``view_rows``).
-    ``paged_decode``
-        decode attends through ``cache.attend_rows``: the engine reads
-        each lane's filled pages where they lie where it can
-        (``ops/transformer/paged_attention.py``) and the gathered view
-        elsewhere.  False: always the gathered view (``mistral4``, whose
-        programs are left as they are until its cell is measured with
-        it)."""
+        reads (``cache.write_rows`` / ``attend_rows`` / ``view_rows``).
+
+    Decode (one query a lane) attends through ``cache.attend_rows``: the
+    engine reads each lane's filled pages where they lie where it can
+    (``ops/transformer/paged_attention.py``) and the gathered view
+    elsewhere; prefill gathers the view itself."""
     B, T, _ = x.shape
     H, R = cfg.num_attention_heads, cfg.kv_lora_rank
     Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -98,23 +96,19 @@ def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
                                sin).astype(x.dtype)
     cache.write_rows(row, jnp.concatenate([c_kv, k_rope], axis=-1)
                      .reshape(B * T, R + Dr))
-    paged = paged_decode and T == 1
-    # (B, S, R + Dr padded to lanes)
-    latent = None if paged else cache.view_rows(row)
 
     w_kvb = p["kv_b"].reshape(R, H, Dn + Dv)
     if T == 1:
         # decode, absorbed: scores over the latent rows themselves
         q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_kvb[..., :Dn])
-        o_lat = cache.attend_rows(row, q_lat, q_rope[:, 0], R) if paged \
-            else mla_decode_attention(q_lat, q_rope[:, 0], latent,
-                                      cache.maxpos + 1, R)
+        o_lat = cache.attend_rows(row, q_lat, q_rope[:, 0], R)
         out = jnp.einsum("bhc,chv->bhv", o_lat, w_kvb[..., Dn:]) \
             .reshape(B, 1, H * Dv)
     else:
         # prefill, expanded: one sequence, keys and values of every
         # cached position; the kernel reads none past the last query
         assert B == 1, "chunked prefill attends one sequence a program"
+        latent = cache.view_rows(row)   # (1, S, R + Dr padded to lanes)
         S = latent.shape[1]
         seen = (jnp.arange(S) <= cache.maxpos[0])[:, None]
         rows = jnp.where(seen, latent[0], 0)

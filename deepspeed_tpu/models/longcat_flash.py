@@ -276,7 +276,7 @@ class LongCatFlashDecoder:
             h = h + latent_attention(
                 cfg, sp, _rms_norm(h, sp["norm_in"], cfg.rms_norm_eps),
                 cache, q_scale=query_scale(cfg), cos=cos, sin=sin,
-                latent_scale=latent_scale(cfg), row=i, paged_decode=True)
+                latent_scale=latent_scale(cfg), row=i)
             u = _rms_norm(h, sp["norm_post"], cfg.rms_norm_eps)
             if i == 0:      # the shortcut: routed here, joined below
                 routed, stats = self._routed(

@@ -73,6 +73,14 @@ def latent_reads_in_place(pool_shape, latent_rank):
     return reads_in_place(pool_shape) and latent_rank % _LANES == 0
 
 
+def latent_pages_per_step(pool_shape, itemsize, table_width):
+    """The pages of latent rows a step of the walk copies: a MiB of them in
+    each of the two buffers, no more than a lane's table holds."""
+    _, _, bs, stored = pool_shape
+    return max(1, min(table_width,
+                      _LATENT_STEP_BYTES // (bs * stored * itemsize)))
+
+
 def _kernel(layer_ref, tables_ref, lengths_ref, next_ref, *refs,
             n_head, pages, table_width, scale, grouped=False,
             windowed=False):
@@ -452,8 +460,8 @@ def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
     # query is padded, not the rows cut
     q = jnp.concatenate([q_lat, q_rope], axis=-1)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, stored - q.shape[-1])))
-    pages = pages_per_step or max(1, min(
-        W, _LATENT_STEP_BYTES // (bs * stored * pool.dtype.itemsize)))
+    pages = pages_per_step or latent_pages_per_step(
+        pool.shape, pool.dtype.itemsize, W)
     lengths = lengths.astype(jnp.int32)
     lane = jnp.arange(B, dtype=jnp.int32)
     # the next live lane after each (B: none), and in [B] the first one

@@ -317,6 +317,20 @@ def test_latent_pages_are_one_tensor_of_whole_lanes(toy):
     assert kv_cache.cache_rows(gpt2) == (32, 32)
 
 
+def _assert_best_of_the_reference(arch, params, config, prompts, served):
+    """Every served token is the reference's best of its row (f32 on both
+    sides)."""
+    weights = arch.reference_weights(params, config)
+    for prompt, tokens in zip(prompts, served):
+        assert (tokens[:len(prompt)] == prompt).all()
+        rows = np.arange(len(prompt) - 1, len(tokens) - 1)
+        logits = np.asarray(arch.reference_logits(
+            weights, config, tokens[None], rows)[0])
+        best = logits.max(-1)
+        served_logits = logits[np.arange(len(rows)), tokens[rows + 1]]
+        np.testing.assert_allclose(served_logits, best, rtol=0, atol=1e-4)
+
+
 def test_engine_serves_what_the_reference_computes(arch, toy):
     """Prefill in chunks of 8 and decode through latent pages, prompts on
     both sides of the original rotary context (16): every served token is
@@ -325,15 +339,54 @@ def test_engine_serves_what_the_reference_computes(arch, toy):
     engine = _engine(toy, prefill_chunk=8)
     engine.warmup()
     prompts = _prompts((5, 21, 30, 9))
-    weights = arch.reference_weights(params, TOY)
-    for prompt, tokens in zip(prompts, _serve(engine, prompts, 6)):
-        assert (tokens[:len(prompt)] == prompt).all()
-        rows = np.arange(len(prompt) - 1, len(tokens) - 1)
-        logits = np.asarray(arch.reference_logits(
-            weights, TOY, tokens[None], rows)[0])
-        best = logits.max(-1)
-        served = logits[np.arange(len(rows)), tokens[rows + 1]]
-        np.testing.assert_allclose(served, best, rtol=0, atol=1e-4)
+    _assert_best_of_the_reference(arch, params, TOY, prompts,
+                                  _serve(engine, prompts, 6))
+
+
+def test_engine_decodes_through_the_paged_latent_kernel(arch, monkeypatch):
+    """The decode program on the branch a TPU takes (the kernel over the
+    latent pages where they lie, in interpret mode here) serves what the
+    float32 reference computes, as it does over the gathered view:
+    staggered arrivals, mixed lengths, idle lanes.  The smallest pool the
+    compiled kernel would take: a latent of 128, pages of 8 rows
+    (``latent_reads_in_place``)."""
+    from deepspeed_tpu.ops.transformer.paged_attention import \
+        paged_latent_decode_attention
+    from deepspeed_tpu.serving import engine as serving
+
+    wide = dict(TOY, kv_lora_rank=128)
+    model = arch.build_model(wide, TILES)
+    params = arch.init_params(model, 5)
+    calls = []
+
+    def on_the_kernel(*args, **kw):
+        calls.append(args[2].shape)
+        return paged_latent_decode_attention(
+            *args, **{**kw, "interpret": True})
+
+    monkeypatch.setattr(serving, "paged_latent_decode_attention",
+                        on_the_kernel)
+    monkeypatch.setattr(serving.jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    # a program traced by an earlier test holds the other branch, and this
+    # one must not be left for a later test
+    serving._make_decode_step.cache_clear()
+    try:
+        engine = InferenceEngine(model, params, max_slots=3, kv_block_size=8,
+                                 max_blocks_per_seq=8, prefill_chunk=8)
+        prompts, rids = _prompts((5, 21, 30, 9), seed=8), []
+        for prompt, n in zip(prompts, (6, 9, 12, 5)):
+            rids.append(engine.submit(prompt, max_new_tokens=n))
+            engine.step()
+            engine.step()
+        engine.serve()
+    finally:
+        serving._make_decode_step.cache_clear()
+    assert calls == [(2, 1 + 3 * 8, 8, 256)], \
+        "one traced block: the decode program alone takes the kernel, once"
+    _assert_best_of_the_reference(
+        arch, params, wide, prompts,
+        [np.asarray(engine.result(rid)) for rid in rids])
 
 
 def test_chunked_prefill_serves_what_unchunked_prefill_serves(toy):

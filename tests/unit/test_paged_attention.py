@@ -22,8 +22,9 @@ import pytest
 from deepspeed_tpu.models.generation import _attn_core, generate
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.ops.transformer.paged_attention import (
-    KERNEL_NAME, LATENT_KERNEL_NAME, latent_reads_in_place,
-    paged_decode_attention, paged_latent_decode_attention, reads_in_place)
+    KERNEL_NAME, LATENT_KERNEL_NAME, latent_pages_per_step,
+    latent_reads_in_place, paged_decode_attention,
+    paged_latent_decode_attention, reads_in_place)
 from deepspeed_tpu.ops.transformer.rect_attention import mla_decode_attention
 from deepspeed_tpu.serving import engine as serving
 from deepspeed_tpu.serving.kv_cache import TRASH_BLOCK
@@ -227,8 +228,15 @@ def test_engine_decodes_through_the_kernel(monkeypatch):
 # ---------------------------------------------------------------------------
 # pages of latent rows: one pool, every head reads the same row
 # ---------------------------------------------------------------------------
-# heads, latent rank, rotary size, stored row, rows a page, pages a lane
-LATENT = {"toy": (4, 16, 8, 128, 4, 64), "r128": (8, 128, 64, 256, 16, 16)}
+# heads, latent rank, rotary size, stored row, rows a page, pages a lane;
+# "m4": mistral-small-4-ep4's row and pages, a table of three steps a lane
+LATENT = {"toy": (4, 16, 8, 128, 4, 64), "r128": (8, 128, 64, 256, 16, 16),
+          "m4": (32, 256, 64, 384, 64, 64)}
+
+
+def _rows(width):
+    """The rows a lane's table covers."""
+    return LATENT[width][4] * LATENT[width][5]
 
 
 def _latent_case(rng, width, lengths, dtype=jnp.float32):
@@ -244,6 +252,10 @@ def _latent_case(rng, width, lengths, dtype=jnp.float32):
 
 
 def _latent_kernel(q_lat, q_rope, pool, tables, lengths, **kw):
+    # the pages a step of the compiled path: of the pool held in bf16, as
+    # every cell holds it (the f32 cases here would halve them)
+    kw.setdefault("pages_per_step", latent_pages_per_step(
+        pool.shape, 2, tables.shape[1]))
     return np.asarray(paged_latent_decode_attention(
         q_lat, q_rope, pool, LAYER, tables, lengths,
         latent_rank=q_lat.shape[-1], interpret=True, **kw), np.float32)
@@ -257,11 +269,19 @@ def _latent_over_view(q_lat, q_rope, pool, tables, lengths):
         q_lat, q_rope, view, lengths, q_lat.shape[-1]), np.float32)
 
 
-@pytest.mark.parametrize("length", [1, 15, 16, 17, 255, ROWS])
-@pytest.mark.parametrize("width", list(LATENT))
+# ends inside a page, on a page's edge, on a step's; a lane of 0 in each
+LATENT_LENGTHS = {"toy": [1, 15, 16, 17, 255, ROWS],
+                  "r128": [1, 15, 16, 17, 255, ROWS],
+                  "m4": [1, 1280, 1344, 2600, 4096]}
+
+
+@pytest.mark.parametrize("width,length", [
+    pytest.param(width, length, id=f"{width}-{length}")
+    for width, lengths in LATENT_LENGTHS.items() for length in lengths])
 def test_latent_equals_mla_decode_attention_over_the_view(width, length):
     rng = np.random.default_rng(length)
-    lengths = [length, 0, ROWS + 1 - length, 0, int(rng.integers(1, ROWS))]
+    rows = _rows(width)
+    lengths = [length, 0, rows + 1 - length, 0, int(rng.integers(1, rows))]
     case = _latent_case(rng, width, lengths)
     got, want = _latent_kernel(*case), _latent_over_view(*case)
     live = np.asarray(lengths) > 0
@@ -292,11 +312,12 @@ def test_latent_bf16_probabilities_meet_bf16_rows():
 @pytest.mark.parametrize("width", list(LATENT))
 def test_latent_rows_past_a_lane_change_nothing(width, poison):
     rng = np.random.default_rng(11)
-    lengths = [1, 0, 17, ROWS - 1, 130]
+    rows = _rows(width)
+    lengths = [1, 0, 17, rows - 1, 130]
     q_lat, q_rope, pool, tables, lens = _latent_case(rng, width, lengths)
     bs = pool.shape[2]
     clean = _latent_kernel(q_lat, q_rope, pool, tables, lens)
-    seen = np.arange(ROWS)[None, :] < np.asarray(lens)[:, None]
+    seen = np.arange(rows)[None, :] < np.asarray(lens)[:, None]
     filled = np.zeros(pool.shape[1:3], bool)      # (NB, bs); trash: unseen
     filled[np.asarray(tables)] = seen.reshape(len(lengths), -1, bs)
     assert not filled[TRASH_BLOCK].any() and not filled.all()
@@ -305,13 +326,18 @@ def test_latent_rows_past_a_lane_change_nothing(width, poison):
         _latent_kernel(q_lat, q_rope, pool, tables, lens), clean)
 
 
-@pytest.mark.parametrize("pool_shape, rank, whole", [
-    ((4, 1665, 64, 640), 512, True),    # longcat-flash-chat-ep32's cell
-    ((5, 6209, 64, 384), 256, True),    # mistral-small-4-ep4's
-    ((2, 25, 8, 128), 16, False),       # a latent of a part of a lane
-    ((2, 25, 4, 256), 128, False)])     # a page of 4 rows
-def test_latent_only_whole_tiles_are_read_in_place(pool_shape, rank, whole):
+# a cell's pool, its latent, and where the pool is read in place its tables'
+# width and the pages a step: a MiB of bf16 rows
+@pytest.mark.parametrize("pool_shape, rank, walk", [
+    ((4, 1665, 64, 640), 512, (26, 12)),    # longcat-flash-chat-ep32's cell
+    ((5, 6209, 64, 384), 256, (388, 21)),   # mistral-small-4-ep4's
+    ((2, 25, 8, 128), 16, None),            # a latent of a part of a lane
+    ((2, 25, 4, 256), 128, None)])          # a page of 4 rows
+def test_latent_only_whole_tiles_are_read_in_place(pool_shape, rank, walk):
+    whole = walk is not None
     assert latent_reads_in_place(pool_shape, rank) is whole
+    if whole:
+        assert latent_pages_per_step(pool_shape, 2, walk[0]) == walk[1]
     stored = pool_shape[3]
     q_lat = jnp.zeros((3, 4, rank), jnp.bfloat16)
     q_rope = jnp.zeros((3, 4, min(64, stored - rank)), jnp.bfloat16)

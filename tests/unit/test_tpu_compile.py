@@ -348,8 +348,36 @@ def test_serving_programs_update_the_pool_in_place(program, pool, v5e):
     assert hc.aliased_outputs(text) >= set(range(len(tensors)))
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill1024"])
-def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(program, v5e):
+def _longcat_cell():
+    from deepspeed_tpu.models.longcat_flash import (LongCatFlashConfig,
+                                                    LongCatFlashModel)
+    return LongCatFlashModel(LongCatFlashConfig(
+        num_layers=2, vocab_size=16384, experts_held=(0, 16),
+        pallas_interpret=False))
+
+
+def _mistral4_cell():
+    from deepspeed_tpu.models.mistral4 import Mistral4Config, Mistral4Model
+    return Mistral4Model(Mistral4Config(
+        num_hidden_layers=5, vocab_size=32768, experts_held=(0, 32),
+        pallas_interpret=False))
+
+
+# the model as its cell cuts it; slots, pages a lane, rows a page, the
+# prefill chunk; the pool's tensors and the lanes a row is stored in
+LATENT_CELLS = {
+    "longcat": (_longcat_cell, (64, 26, 64, 1024), 2, 640),
+    "mistral4": (_mistral4_cell, (16, 388, 64, 2048), 1, 384),
+}
+
+
+@pytest.mark.parametrize("cell,program", [
+    pytest.param("longcat", "decode", id="decode"),
+    pytest.param("longcat", "prefill", id="prefill1024"),
+    pytest.param("mistral4", "decode", id="mistral4-decode"),
+    pytest.param("mistral4", "prefill", id="mistral4-prefill2048")])
+def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(cell, program,
+                                                              v5e):
     """LongCat-Flash's double block caches TWO latent rows a token, 576
     values each: ``kv_cache.pool_shapes`` states them as two tensors of 640
     stored lanes (whole 128-lane tiles), and at the published widths and
@@ -357,19 +385,21 @@ def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(program, v5e):
     the four blocks, one traced block either way) no program relays either
     tensor, holds a layer of it on its own, or returns the pool in other
     buffers than the donated ones.  Stored unpadded, 576 wide, the compiler
-    unpads and pads the whole pool around every program (PR 30)."""
+    unpads and pads the whole pool around every program (PR 30).
+
+    ``mistral-small-4-ep4``'s ONE row of 320 stored as 384 likewise, at its
+    cell's sizes (16 slots x 388 pages of 64, chunks of 2,048, five layers
+    of 32 held experts): both models' decode goes through
+    ``cache.attend_rows`` and reads each lane's filled pages in place."""
     import re
 
-    from deepspeed_tpu.models.longcat_flash import (LongCatFlashConfig,
-                                                    LongCatFlashModel)
     from deepspeed_tpu.serving import engine as serving
     from deepspeed_tpu.serving.kv_cache import pool_shapes
     from tools.graftlint import hlo_contracts as hc
 
-    S, W, bs, C = 64, 26, 64, 1024
-    cfg = LongCatFlashConfig(num_layers=2, vocab_size=16384,
-                             experts_held=(0, 16), pallas_interpret=False)
-    model = LongCatFlashModel(cfg)
+    build, (S, W, bs, C), n_tensors, stored = LATENT_CELLS[cell]
+    model = build()
+    cfg = model.config
 
     def struct(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -377,9 +407,11 @@ def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(program, v5e):
     params = jax.tree_util.tree_map(
         lambda l: struct(l.shape, l.dtype),
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    k, v, k_scale, v_scale = pool_shapes(cfg, 1 + S * W, bs, False)
-    assert k == v == (2, 1 + S * W, bs, 640) and k_scale is v_scale is None
-    tensors = [struct(k, cfg.dtype), struct(v, cfg.dtype)]
+    shapes = [shape for shape in pool_shapes(cfg, 1 + S * W, bs, False)
+              if shape is not None]
+    k = (cfg.n_layer, 1 + S * W, bs, stored)
+    assert shapes == [k] * n_tensors
+    tensors = [struct(k, cfg.dtype)] * n_tensors
     if program == "decode":
         jitted = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0,
                                            None, "data")
@@ -401,24 +433,24 @@ def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(program, v5e):
     assert not [line for line in text.splitlines()
                 if re.search(rf"= \(?\w+\[{layer}\]", line)], \
         f"{program} holds a layer of the pool"
-    assert hc.aliased_outputs(text) >= {0, 1}
+    assert hc.aliased_outputs(text) >= set(range(n_tensors))
     # nor a weight: the stacks are read where they lie, a matrix a slice
-    # (``q_b``, ``kv_a``, ``kv_b`` are held (out, in) for that)
+    # (LongCat's ``q_b``, ``kv_a``, ``kv_b`` are held (out, in) for that)
     stacks = {",".join(map(str, l.shape)) for l in
               jax.tree_util.tree_leaves(params) if l.ndim >= 3}
     copied = [line.strip()[:120] for line in text.splitlines()
               if any(re.search(rf"= bf16\[{dims}\]\S* (copy|fusion)\(", line)
                      for dims in stacks)]
     assert not copied, f"{program} copies a stack of weights: {copied}"
-    # the kernels take heads of (128 | 64) and the 640-lane row as they are
-    assert "moe_grouped_matmul_" + ("decode" if program == "decode"
-                                    else "prefill") in text
+    # the kernels take heads of (128 | 64) and the stored row as they are
+    assert "moe_grouped_matmul_" + program in text
     assert ("mla_prefill_attn" in text) == (program != "decode")
     # decode reads each lane's filled pages where they lie: no view of
     # every lane's W pages is gathered (nor selected against its mask)
     assert ("paged_latent_decode_attn" in text) == (program == "decode")
     if program == "decode":
-        view = re.compile(rf"= \w+\[({S},{W * bs}|{W * bs},{S}),640\]")
+        view = re.compile(rf"= \(?\w+\[({S},{W * bs}|{W * bs},{S}"
+                          rf"|{S * W},{bs}),{stored}\]")
         made = [line.strip()[:120] for line in text.splitlines()
                 if view.search(line)]
         assert not made, f"decode gathers every lane's pages: {made}"

@@ -46,8 +46,11 @@ def build_model(config, overrides):
 
 
 def init_params(model, seed):
-    """The served weights: made on the device in one jitted call from the
-    seed, float32 as ``InferenceEngine`` holds them."""
+    """The model's weights: made on the device in one jitted call from the
+    seed, float32 as a trainer keeps them.  ``InferenceEngine`` holds and
+    serves a bf16 copy of them (PR 33: every weight bf16, the LayerNorm
+    leaves f32; ``assumed.served_weight_dtype`` of the configuration's
+    file), and the plain reference reads this f32 tree."""
     ids = np.zeros((1, 8), np.int32)
     return jax.jit(model.init)(jax.random.PRNGKey(seed),
                                {"input_ids": ids, "labels": ids})

@@ -84,5 +84,20 @@ class Cell:
                                    "metric").read
 
 
-def load_benchmark(path=None):
-    return _load_json(path or os.path.join(CHECKOUT, "BENCHMARK.json"))
+def load_benchmark(path=None, withheld=False):
+    """``BENCHMARK.json`` (or the file at ``path``).  ``withheld=True``
+    adds the cells of ``benchmark/withheld/*.json``: cells whose files are
+    all here and tested, but which ``BENCHMARK.json`` does not enter,
+    because no judged number of theirs can be told under a bound yet.
+    Each file says why and holds, under the keys of ``BENCHMARK.json``, the
+    entries a later benchmark PR puts back.  The tools and the tests read a
+    withheld cell this way; ``run.py`` runs what ``BENCHMARK.json`` has."""
+    benchmark = _load_json(path or os.path.join(CHECKOUT, "BENCHMARK.json"))
+    held = os.path.join(BENCH_DIR, "withheld")
+    if withheld and os.path.isdir(held):
+        for name in sorted(os.listdir(held)):
+            if name.endswith(".json"):
+                entries = _load_json(os.path.join(held, name))
+                for key in ("workloads", "end_to_end", "per_layer"):
+                    benchmark[key] = benchmark[key] + entries.get(key, [])
+    return benchmark

@@ -73,12 +73,23 @@ def requests(traffic, vocab_size, seed, horizon_s):
 
     ``shared_prefix`` (optional): ``{"tokens": n, "sessions": k}`` makes
     every prompt begin with one of ``k`` seeded prefixes of ``n`` tokens,
-    and ``prompt_len`` then counts the tokens after it."""
+    and ``prompt_len`` then counts the tokens after it.
+
+    ``lengths_seed`` (optional, an integer): the lengths are drawn from
+    THAT seed and not from the run's, so every run of the mix serves the
+    same lengths in the same order, and the run's seed draws the token ids
+    (and the weights).  For a backlog that a window does not empty: which
+    requests the window takes up is then the mix's and not the seed's, where
+    a seed-drawn order moved the rate by 1.7 % from seed to seed (PERF.md
+    section 6, PR 45).  A mix that does not state it draws as it always
+    did."""
     rng = np.random.default_rng(seed)
     due = _due_times(traffic["arrivals"], rng, horizon_s)
     n = len(due)
-    prompt_len = _lengths(traffic["prompt_len"], rng, n)
-    new_tokens = _lengths(traffic["new_tokens"], rng, n)
+    lengths_rng = np.random.default_rng(int(traffic["lengths_seed"])) \
+        if "lengths_seed" in traffic else rng
+    prompt_len = _lengths(traffic["prompt_len"], lengths_rng, n)
+    new_tokens = _lengths(traffic["new_tokens"], lengths_rng, n)
     prompts = [rng.integers(0, vocab_size, k).astype(np.int32)
                for k in prompt_len]
     shared = traffic.get("shared_prefix")

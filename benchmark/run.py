@@ -5,9 +5,10 @@
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
 ``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``,
-traced, ``breakdown``, and last ``compared``: each number the verdict
-compared with its limit (also the last lines of standard error).  Earlier
-lines are JSON too, and are information.
+traced, ``breakdown``, a serving run's ``requests_counted``, and last
+``compared``: each number the verdict compared with its limit (also the
+last lines of standard error).  Earlier lines are JSON too, and are
+information.
 The run measures on the TPUs of the machine it is started on and on
 nothing else: without them, or without the program, it prints no result
 and exits with a code other than 0.
@@ -50,8 +51,12 @@ def result_line(cell, run, device, trace):
         for spec in cell.end_to_end:
             value = run["end_to_end"][spec["name"]]
             if value is None:
-                raise RuntimeError(f"the run could not measure "
-                                   f"{spec['name']}: no result")
+                counted = run.get("requests_counted")
+                raise RuntimeError(
+                    f"the run could not measure {spec['name']}: no result"
+                    + (f" ({counted} requests counted; a judged percentile "
+                       f"needs ten beyond its rank)"
+                       if counted is not None else ""))
             metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
     line = {"correct": bool(run["correct"]),
             "attempted": int(run["attempted"]), "failed": int(run["failed"]),
@@ -64,6 +69,8 @@ def result_line(cell, run, device, trace):
         line["device"]["window_s"] = reduction["window_s"]
         line["breakdown"] = {"device_ops": reduction["device_ops"],
                              "idle_gaps": reduction["idle_gaps"]}
+    if "requests_counted" in run:       # what a percentile was taken over
+        line["requests_counted"] = int(run["requests_counted"])
     # last: each number the verdict compared, [as read, its limit]
     line["compared"] = run["compared"]
     return line

@@ -86,7 +86,7 @@ def readings(cell, devices, seconds, seeds, log=print):
 def main(workload, seconds, seeds):
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-    cell = cells.Cell(cells.load_benchmark(), workload)
+    cell = cells.Cell(cells.load_benchmark(withheld=True), workload)
     devices = device_lib.require_tpu(cell.chips)
     enable_compile_cache()
     rule = cell.architecture().served_check(cell.config)["rule"]

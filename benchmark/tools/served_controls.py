@@ -80,7 +80,7 @@ class WithControls:
 def main(workload, seconds, seeds):
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-    cell = cells.Cell(cells.load_benchmark(), workload)
+    cell = cells.Cell(cells.load_benchmark(withheld=True), workload)
     devices = device_lib.require_tpu(cell.chips)
     enable_compile_cache()
     driver, arch = cell.driver(), cell.architecture()

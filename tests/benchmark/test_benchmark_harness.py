@@ -254,6 +254,61 @@ def test_stratified_lengths_and_paced_arrivals_carry_the_same_work():
         assert np.mean(block) == pytest.approx(20, abs=1.0)
 
 
+def _lens(load):
+    return np.array([len(p) for p in load["prompts"]])
+
+
+def test_lengths_seed_gives_every_run_the_same_lengths_in_the_same_order():
+    """A mix that states ``lengths_seed`` draws its lengths from that and
+    the token ids from the run's seed; one that does not draws both from
+    the run's, to the draw as before the key was there."""
+    mix = {"arrivals": {"process": "backlog", "requests": 96},
+           "prompt_len": {"dist": "uniform", "min": 64, "max": 512,
+                          "stratified": 16},
+           "new_tokens": {"dist": "uniform", "min": 8, "max": 32,
+                          "stratified": 16}}
+    fixed = dict(mix, lengths_seed=0)
+    a, b = (traffic.requests(fixed, 1000, seed=s, horizon_s=10)
+            for s in (1, 2**31 + 7))
+    assert (_lens(a) == _lens(b)).all()
+    assert (a["new_tokens"] == b["new_tokens"]).all()
+    assert any((p != q).any() for p, q in zip(a["prompts"], b["prompts"]))
+    # still the stratified draw: a sixteenth of the range a request a block
+    for block in _lens(a).reshape(6, 16):
+        assert sorted((block - 64) * 16 // 449) == list(range(16))
+    other = traffic.requests(dict(mix, lengths_seed=1), 1000, 1, 10)
+    assert (_lens(other) != _lens(a)).any()
+    # without the key: the run's seed draws the lengths, as it always did
+    c, d = (traffic.requests(mix, 1000, seed=s, horizon_s=10) for s in (1, 2))
+    assert (_lens(c) != _lens(d)).any()
+    rng = np.random.default_rng(1)
+    assert (_lens(c) == traffic._lengths(mix["prompt_len"], rng, 96)).all()
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in _benchmark()["workloads"]
+                                  if w["traffic"].startswith("serve-")])
+def test_a_backlog_a_window_does_not_empty_is_taken_up_in_one_order(name):
+    """Where a window takes up only part of a backlog of long requests,
+    WHICH requests it takes up may not hang on the run's seed (the
+    long-document cell read 1.8 % from seed to seed by that, and 0.4 % with
+    the order fixed; PERF.md section 6, PR 45): the two cells the check
+    refused state ``lengths_seed``.  The other two are as the check measured
+    them: the offline cell's window counts 1,040 short requests, 65 blocks;
+    the repository cell waits for a machine to show the same on."""
+    cell = cells.Cell(_benchmark(), name)
+    mix = cell.traffic
+    assert mix["arrivals"]["process"] == "backlog"
+    a, b = (traffic.requests(mix, 1000, seed=s, horizon_s=33)
+            for s in (11, 2**31 + 12))
+    same = (_lens(a) == _lens(b)).all() \
+        and (a["new_tokens"] == b["new_tokens"]).all()
+    assert same == ("lengths_seed" in mix)
+    assert same == (name in ("mistral-small-4-ep4.serve-long-docs",
+                             "longcat-flash-chat-ep32.serve-long-answers"))
+    assert any(len(p) != len(q) or (p != q).any()
+               for p, q in zip(a["prompts"], b["prompts"]))
+
+
 def test_train_batches_are_seeded_and_distinct():
     job = {"gradient_accumulation": 2, "micro_batch_per_chip": 3,
            "seq_len": 16, "distinct_batches": 4}
@@ -272,6 +327,10 @@ def test_train_batches_are_seeded_and_distinct():
 # ---------------------------------------------------------------------------
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# what ``reduced`` may never name: a hidden, intermediate, latent, state or
+# projection size, a head size, an expansion factor, experts per token
+WIDTHS = ("hidden_size", "intermediate_size", "n_embd", "n_inner", "_dim",
+          "_rank", "head_size", "expansion", "experts_per_tok")
 
 
 def test_benchmark_json_contract():
@@ -295,7 +354,11 @@ def test_benchmark_json_contract():
         with open(os.path.join(CHECKOUT, c["file"])) as f:
             held = json.load(f)
         assert held["source"] == c["source"]
-        assert held["reduced"] == c["reduced"] == []
+        # a configuration may be cut (depth, the experts or vocabulary
+        # held): the file and the entry agree on what, and no cut is a width
+        assert held["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        assert not [k for k in c["reduced"]
+                    if any(w in k for w in WIDTHS)], c["name"]
     for w in cells_:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])

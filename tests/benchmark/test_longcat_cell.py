@@ -132,15 +132,16 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     entry = {c["name"]: c for c in b["configs"]}["longcat-flash-chat-ep32"]
     assert entry["reduced"] == _config()["reduced"]
     assert entry["source"] == _config()["source"]
-    assert b["workloads"][-1]["name"] == CELL       # entered at the end
-    assert len(b["workloads"][-1]["why"]) <= 200 and len(entry["why"]) <= 200
+    workload = {w["name"]: w for w in b["workloads"]}[CELL]    # by name
+    assert len(workload["why"]) <= 200 and len(entry["why"]) <= 200
     cell = cells.Cell(b, CELL)
     assert cell.chips == 1
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s",
                                                     "setup_s"]
     names = [m["name"] for m in cell.per_layer]
-    assert set(names) == {"compiles_in_window", *ALIASES, *COUNTER_METRICS,
-                          *ROOFLINES}
+    # the names this file knows are there; a later PR may enter more
+    assert {"compiles_in_window", *ALIASES, *COUNTER_METRICS,
+            *ROOFLINES} <= set(names)
     for m in cell.per_layer:
         assert m["moves"] in ("serve_tokens_per_s", "setup_s")
         assert callable(cell.reader(m["name"]))
@@ -420,8 +421,10 @@ def test_the_toy_cell_names_the_real_cells_metrics():
     toy = cells.Cell(cells.load_benchmark(os.path.join(
         REHEARSAL, "BENCHMARK.json")), "longcat-tiny.serve-tiny-answers",
         root=REHEARSAL)
-    assert [m["name"] for m in toy.per_layer] \
-        == [m["name"] for m in real.per_layer]
+    # every name the toy cell rehearses is the real cell's; a later PR may
+    # enter more in the real one
+    assert {m["name"] for m in toy.per_layer} \
+        <= {m["name"] for m in real.per_layer}
 
 
 def test_rehearsal_cell_is_correct_with_no_compilation(traced):

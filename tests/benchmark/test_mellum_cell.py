@@ -171,15 +171,15 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s",
                                                     "setup_s"]
     names = [m["name"] for m in cell.per_layer]
-    assert set(names) == {"compiles_in_window", *ALIASES, *POOL_METRICS,
-                          *COUNTER_METRICS, *ROOFLINES}
+    # the names this file knows are there; a later PR may enter more
+    assert {"compiles_in_window", *ALIASES, *POOL_METRICS, *COUNTER_METRICS,
+            *ROOFLINES} <= set(names)
     assert all(_reader(name)({}) is None for name in NOT_ENTERED)
     for m in cell.per_layer:
         assert m["moves"] in ("serve_tokens_per_s", "setup_s")
         assert callable(cell.reader(m["name"]))
         assert cell.reader(m["name"])({}) is None, m["name"]
-        if m["name"] != "compiles_in_window":
-            assert m["workloads"] == [CELL]
+        assert CELL in m["workloads"]
     # the traffic the issue gives, letter for letter
     traffic = cell.traffic
     assert traffic["driver"] == "serve" and traffic["what"]
@@ -394,15 +394,20 @@ def test_the_toy_cell_names_the_real_cells_metrics():
     real = cells.Cell(cells.load_benchmark(), CELL)
     toy = cells.Cell(cells.load_benchmark(os.path.join(
         REHEARSAL, "BENCHMARK.json")), TOY_CELL, root=REHEARSAL)
-    assert [m["name"] for m in toy.per_layer] \
-        == [m["name"] for m in real.per_layer]
+    # every name the toy cell rehearses is the real cell's; a later PR may
+    # enter more in the real one
+    assert {m["name"] for m in toy.per_layer} \
+        <= {m["name"] for m in real.per_layer}
 
 
 def test_rehearsal_cell_is_correct_with_no_compilation(traced):
     cell, run, logged = traced
     assert run["correct"], logged
     assert run["attempted"] > 5 and run["failed"] == 0
-    assert logged["reference"]["requests_checked"] in (4, 5)
+    # eight and the longest: with four, the ~45 judged rows of this toy
+    # routed model left the 0.95 share to luck (three rows where bf16 and
+    # f32 chose another expert failed a sound run, about every second one)
+    assert logged["reference"]["requests_checked"] in (8, 9)
     assert run["observed"]["compiles_in_window"] == 0
     assert run["end_to_end"]["serve_tokens_per_s"] > 0
 

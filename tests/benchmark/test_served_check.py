@@ -371,8 +371,10 @@ def test_the_control_tool_reads_program_and_control_in_one_run(devices):
                                                   "BENCHMARK.json"))
     cell = cells.Cell(benchmark, "gpt2-tiny.serve-tiny-backlog",
                       root=REHEARSAL)
+    # three seconds of wall clock: in one, a loaded machine finishes so few
+    # requests that the control's worst row can lie inside the limit
     program, control, control_held = tool.readings(
-        cell, devices[:1], 1.0, [3], log=lambda line: None)
+        cell, devices[:1], 3.0, [3], log=lambda line: None)
     assert program[0]["worst_spacings_below_best"] <= 4.0
     assert control[0]["rows_judged"] == program[0]["rows_judged"] > 0
     assert control[0]["worst_spacings_below_best"] > 4.0

@@ -1,8 +1,8 @@
 """Latent (MLA) attention over the serving engine's raw cache rows, and the
 small pieces the DeepSeek-V3 family's blocks share (RMSNorm, interleaved
 RoPE, SwiGLU).  ONE function for every model of the family the engine
-serves (``models/mistral4.py``, ``models/longcat_flash.py``): what differs
-between them is an argument, not a copy.
+serves (``models/mistral4.py``, ``models/longcat_flash.py``,
+``models/motif.py``): what differs between them is an argument, not a copy.
 
 ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> H heads of (nope | rope);
 ``[c_kv | k_r] = x W_kva``, ``c_kv = RMSNorm(c_kv)``, ``k_r = RoPE(k_r)``
@@ -50,15 +50,20 @@ def _swiglu(x, p):
 
 
 def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
-                     latent_scale=1.0, row=0):
+                     latent_scale=1.0, row=0, kv_heads=None,
+                     prefill_name=None, decode_name=None):
     """One latent attention over x (B, T, E), through the engine's cache
-    hook (``serving/decoder.py``), output projection included.
+    hook (``serving/decoder.py``), output projection included where ``p``
+    holds one.
 
     ``cfg`` states the sizes under the family's names
     (``num_attention_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``,
     ``qk_rope_head_dim``, ``v_head_dim``, ``rms_norm_eps``,
     ``pallas_interpret``); ``p`` holds ``q_a``, ``q_a_norm``, ``q_b``,
-    ``kv_a``, ``kv_a_norm``, ``kv_b``, ``o``.  What differs by model:
+    ``kv_a``, ``kv_a_norm``, ``kv_b`` and, where the heads are to be
+    projected here, ``o`` (without it the heads come back side by side,
+    (B, T, H * Dv): a model that does something to them first projects them
+    itself).  What differs by model:
 
     ``q_scale``
         what the query is multiplied by, once, in f32: the softmax scale
@@ -72,6 +77,17 @@ def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
     ``row``
         which of the block's raw cache rows this attention writes and
         reads (``cache.write_rows`` / ``attend_rows`` / ``view_rows``).
+    ``kv_heads``
+        how many key/value heads ``kv_b`` up-projects the latent to (None:
+        one a query head): query head h reads key/value head
+        ``h // (H // kv_heads)``, in both kernels, no key repeated.
+    ``prefill_name``, ``decode_name``
+        what the two kernels are called in the compiled program (None:
+        their own names).
+
+    The WINDOW is the cache group's (``cache.window``, None in a group that
+    keeps everything): each query then sees its last ``window`` positions,
+    of a view whose first row stands at ``cache.k_start``.
 
     Decode (one query a lane) attends through ``cache.attend_rows``: the
     engine reads each lane's filled pages where they lie where it can
@@ -97,20 +113,37 @@ def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
     cache.write_rows(row, jnp.concatenate([c_kv, k_rope], axis=-1)
                      .reshape(B * T, R + Dr))
 
-    w_kvb = p["kv_b"].reshape(R, H, Dn + Dv)
+    Hkv = H if kv_heads is None else kv_heads
+    w_kvb = p["kv_b"].reshape(R, Hkv, Dn + Dv)
+    names = lambda name: {} if name is None else {"name": name}  # noqa: E731
     if T == 1:
-        # decode, absorbed: scores over the latent rows themselves
-        q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_kvb[..., :Dn])
-        o_lat = cache.attend_rows(row, q_lat, q_rope[:, 0], R)
-        out = jnp.einsum("bhc,chv->bhv", o_lat, w_kvb[..., Dn:]) \
-            .reshape(B, 1, H * Dv)
+        # decode, absorbed: scores over the latent rows themselves (a head
+        # a key/value head keeps the products it always had, so that the
+        # two models that state no ``kv_heads`` compile to what they did)
+        if kv_heads is None:
+            q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_kvb[..., :Dn])
+        else:
+            q_lat = jnp.einsum(
+                "bkgd,ckd->bkgc", q_nope[:, 0].reshape(B, Hkv, H // Hkv, Dn),
+                w_kvb[..., :Dn]).reshape(B, H, R)
+        o_lat = cache.attend_rows(row, q_lat, q_rope[:, 0], R,
+                                  **names(decode_name))
+        if kv_heads is None:
+            out = jnp.einsum("bhc,chv->bhv", o_lat, w_kvb[..., Dn:])
+        else:
+            out = jnp.einsum("bkgc,ckv->bkgv",
+                             o_lat.reshape(B, Hkv, H // Hkv, R),
+                             w_kvb[..., Dn:])
+        out = out.reshape(B, 1, H * Dv)
     else:
         # prefill, expanded: one sequence, keys and values of every
         # cached position; the kernel reads none past the last query
         assert B == 1, "chunked prefill attends one sequence a program"
         latent = cache.view_rows(row)   # (1, S, R + Dr padded to lanes)
         S = latent.shape[1]
-        seen = (jnp.arange(S) <= cache.maxpos[0])[:, None]
+        windowed = cache.window is not None
+        at = jnp.arange(S) + cache.k_start[0] if windowed else jnp.arange(S)
+        seen = (at <= cache.maxpos[0])[:, None]
         rows = jnp.where(seen, latent[0], 0)
         # head-major straight out of the products; the rotary key is
         # one (S, Dr) array for all heads, never copied per head
@@ -119,6 +152,8 @@ def latent_attention(cfg, p, x, cache, *, q_scale, cos, sin,
         out = rect_flash_attention(
             q_nope[0].transpose(1, 0, 2), k_nope, values, pos[0, 0],
             q_rope[0].transpose(1, 0, 2), rows[:, R:R + Dr],
-            interpret=cfg.pallas_interpret)
+            **({"k_start": cache.k_start[0], "window": cache.window}
+               if windowed else {}),
+            interpret=cfg.pallas_interpret, **names(prefill_name))
         out = out.transpose(1, 0, 2).reshape(1, T, H * Dv)
-    return out @ p["o"]
+    return out @ p["o"] if "o" in p else out

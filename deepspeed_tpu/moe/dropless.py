@@ -41,16 +41,21 @@ STAT_NAMES = ("moe_routed_rows", "moe_held_rows", "moe_busiest_scaled_rows",
 ZERO_STAT_NAME = "moe_zero_rows"
 
 
+SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+          "sigmoid": jax.nn.sigmoid}
+
+
 def route_top_k(x, router, top_k, *, norm_topk_prob=True, scaling=1.0,
-                choice_bias=None):
-    """Softmax over all choices in f32, the ``top_k`` largest (of
+                choice_bias=None, score="softmax"):
+    """The router's scores of all choices in f32 (``score``: a softmax over
+    them, or a sigmoid of each: :data:`SCORES`), the ``top_k`` largest (of
     ``probs + choice_bias`` where a bias (E,) is given: it moves the choice,
-    the weights stay the probabilities), their weights renormalised to sum
+    the weights stay the scores), their weights renormalised to sum
     1 (``norm_topk_prob``) and scaled.
     x: (T, H); router: (H, E).  Returns weights (T, k) f32, ids (T, k)."""
     logits = jnp.dot(x, router.astype(x.dtype),
                      preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = SCORES[score](logits)
     if choice_bias is None:
         weights, ids = jax.lax.top_k(probs, top_k)
     else:
@@ -103,7 +108,8 @@ def held_layout(ids, valid, experts_held, tile_m):
 def dropless_moe(x, router, experts, *, top_k, experts_held, tile_m,
                  valid=None, norm_topk_prob=True, scaling=1.0,
                  interpret=None, first_matrix=0, kernel_name=KERNEL_NAME,
-                 choice_bias=None, zero_experts=0, live_tiles=False):
+                 choice_bias=None, zero_experts=0, live_tiles=False,
+                 score="softmax", activation=None):
     """The held experts' part of the routed sum over x (T, H), plus the
     identity term where the router scores zero-compute experts.
 
@@ -118,6 +124,12 @@ def dropless_moe(x, router, experts, *, top_k, experts_held, tile_m,
     ``zero_experts``: the router's last columns that are zero-compute
     experts (ids ``router.shape[1] - zero_experts`` and up): each such
     choice adds ``weight * x``.  ``choice_bias``: see :func:`route_top_k`.
+
+    ``score``: the router's scoring function (:func:`route_top_k`).
+    ``activation``: what stands between the two grouped matmuls, a function
+    ``(gate_up (M, 2 I) f32, matrix (M,)) -> (M, I) f32`` of the first one's
+    rows and the matrix each row met (an activation with parameters an
+    expert reads its own by it); None: SiLU(gate) * up.
 
     ``live_tiles``: the row buffer is sized for the worst case, ``T * k``
     rows and a tile's slack an expert; where few choices can fall on the
@@ -136,7 +148,8 @@ def dropless_moe(x, router, experts, *, top_k, experts_held, tile_m,
     count = experts_held[1]
     weights, ids = route_top_k(x, router, top_k,
                                norm_topk_prob=norm_topk_prob,
-                               scaling=scaling, choice_bias=choice_bias)
+                               scaling=scaling, choice_bias=choice_bias,
+                               score=score)
     lay = held_layout(ids, valid, experts_held, tile_m)
     worst = lay["tile_expert"].shape[0]
     inner = experts["down"].shape[1]
@@ -155,8 +168,11 @@ def dropless_moe(x, router, experts, *, top_k, experts_held, tile_m,
                                  interpret=interpret,
                                  name=kernel_name + "_up") \
             .astype(jnp.float32)
-        hidden = (jax.nn.silu(gate_up[:, :inner]) * gate_up[:, inner:]) \
-            .astype(x.dtype)
+        if activation is None:
+            hidden = jax.nn.silu(gate_up[:, :inner]) * gate_up[:, inner:]
+        else:
+            hidden = activation(gate_up, jnp.repeat(matrix, tile_m))
+        hidden = hidden.astype(x.dtype)
         out_rows = grouped_matmul(hidden, experts["down"], matrix,
                                   lay["n_tiles"], tile_m=tile_m,
                                   interpret=interpret,

@@ -38,7 +38,7 @@ its cells' yardstick).
 (MLA) rows, ``(L, NB, bs, stored)``: every head reads the SAME row, which is
 key and value at once, so there is one pool, one buffer a step, and the
 query arrives whole (``[q . W_uk | q_rope | zeros]`` a head), no
-block-diagonal.
+block-diagonal; ``starts`` is the same window over it.
 """
 import functools
 
@@ -331,9 +331,10 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
 
 
 def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
-                   q_ref, hbm, o_ref,
-                   buf, sems, slot_ref, m_scr, l_scr, acc_scr, *,
-                   pages, table_width):
+                   *refs, pages, table_width, windowed=False):
+    if windowed:        # prefetched too: the first row a lane sees
+        starts_ref, *refs = refs
+    q_ref, hbm, o_ref, buf, sems, slot_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     n_lanes = pl.num_programs(0)
     bs = buf.shape[1] // pages
@@ -343,10 +344,17 @@ def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
     layer = layer_ref[0]
     length = lengths_ref[b]
 
+    def first_page(lane, c):
+        """The first page of step ``c`` of ``lane``: a windowed lane's
+        steps begin at the page that holds its first row."""
+        if windowed:
+            return starts_ref[lane] // bs + c * pages
+        return c * pages
+
     def page_copies(lane, c, slot, act):
         """Start or wait for the copies of step ``c`` of ``lane``: the
         pages of that step the lane has filled."""
-        first = c * pages
+        first = first_page(lane, c)
         filled = (lengths_ref[lane] + bs - 1) // bs - first
 
         def page(i, carry):
@@ -370,7 +378,12 @@ def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
 
     @pl.when(length > 0)
     def _attend():
-        steps = (length + S - 1) // S
+        if windowed:
+            first_row = starts_ref[b]
+            steps = ((length + bs - 1) // bs - first_row // bs
+                     + pages - 1) // pages
+        else:
+            steps = (length + S - 1) // S
 
         @pl.when(b == next_ref[n_lanes])        # the first live lane
         def _first():
@@ -397,20 +410,36 @@ def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
                 page_copies(following, 0, 1 - slot, start)
 
             page_copies(b, c, slot, wait)
+            if windowed:
+                row0 = first_page(b, c) * bs    # the step's first row
 
-            @pl.when((c + 1) * S > length)      # rows no query may see
-            def _():
-                row = c * S + jax.lax.broadcasted_iota(
-                    jnp.int32, (S, stored), 0)
-                buf[slot] = jnp.where(row < length, buf[slot],
-                                      jnp.zeros((), buf.dtype))
+                # rows no query may see: past the length, below the start
+                @pl.when(jnp.logical_or(row0 + S > length, c == 0))
+                def _():
+                    row = row0 + jax.lax.broadcasted_iota(
+                        jnp.int32, (S, stored), 0)
+                    buf[slot] = jnp.where(
+                        jnp.logical_and(row < length, row >= first_row),
+                        buf[slot], jnp.zeros((), buf.dtype))
+            else:
+                @pl.when((c + 1) * S > length)      # rows no query may see
+                def _():
+                    row = c * S + jax.lax.broadcasted_iota(
+                        jnp.int32, (S, stored), 0)
+                    buf[slot] = jnp.where(row < length, buf[slot],
+                                          jnp.zeros((), buf.dtype))
 
             rows = buf[slot]                    # keys and values at once
             s = jax.lax.dot_general(
                 q, rows, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)              # (H, S)
-            pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
-            s = jnp.where(pos < length, s, NEG_INF)
+            if windowed:
+                pos = row0 + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
+                s = jnp.where(jnp.logical_and(pos < length,
+                                              pos >= first_row), s, NEG_INF)
+            else:
+                pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
+                s = jnp.where(pos < length, s, NEG_INF)
             m_prev = m_scr[:, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -430,10 +459,11 @@ def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
 
 
 @functools.partial(jax.jit, static_argnames=("latent_rank", "pages_per_step",
-                                             "interpret"))
+                                             "interpret", "name"))
 def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
-                                  *, latent_rank, pages_per_step=None,
-                                  interpret=None):
+                                  *, latent_rank, starts=None,
+                                  pages_per_step=None, interpret=None,
+                                  name=LATENT_KERNEL_NAME):
     """One query a lane over ITS pages of latent rows, up-projections
     absorbed: what ``rect_attention.mla_decode_attention`` computes over the
     gathered view, read where the rows lie.
@@ -442,9 +472,12 @@ def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
     pool: (L, NB, bs, stored) rows ``[c_kv | k_rope | zeros]``, left where
     they are; ``layer``: the layer attended (traced or not); tables: (B, W)
     page ids in position order; lengths: (B,) cached rows a lane may see
-    (0: an idle lane, which reads nothing and gets zeros).  Returns
+    (0: an idle lane, which reads nothing and gets zeros); starts: (B,) the
+    first row a lane sees (a window; None: row 0).  Returns
     (B, H, R) in the query's dtype: ``softmax(scores) . c_kv``.  Entries of
-    ``tables`` past a lane's filled pages are never read."""
+    ``tables`` past a lane's filled pages, and before the page of its first
+    row, are never read.  ``name``: what the kernel is called in the
+    compiled program and the device trace."""
     B, H, R = q_lat.shape
     L, NB, bs, stored = pool.shape
     W = tables.shape[1]
@@ -468,11 +501,18 @@ def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
     live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
     following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
                                  live_from[:1]])
-    kernel = functools.partial(_latent_kernel, pages=pages, table_width=W)
+    prefetched = [jnp.asarray(layer, jnp.int32).reshape(1),
+                  tables.astype(jnp.int32).reshape(-1), lengths, following]
+    windowed = starts is not None
+    if windowed:
+        prefetched.append(jnp.clip(starts.astype(jnp.int32), 0,
+                                   jnp.maximum(lengths - 1, 0)))
+    kernel = functools.partial(_latent_kernel, pages=pages, table_width=W,
+                               windowed=windowed)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetched),
             grid=(B,),
             in_specs=[pl.BlockSpec((1, H, stored), lambda b, *_: (b, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -488,6 +528,5 @@ def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=LATENT_KERNEL_NAME,
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      tables.astype(jnp.int32).reshape(-1), lengths, following, q, pool)
+        name=name,
+    )(*prefetched, q, pool)

@@ -264,19 +264,23 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
     return out[:, :C]
 
 
-def mla_decode_attention(q_lat, q_rope, latent, n_keys, latent_rank):
+def mla_decode_attention(q_lat, q_rope, latent, n_keys, latent_rank,
+                         starts=None):
     """One query a lane over gathered latent rows, up-projections absorbed.
 
     q_lat: (B, H, R) = q_nope . W_uk, scale folded in; q_rope: (B, H, Dr);
     latent: (B, S, >= R + Dr) rows ``[c_kv | k_rope | zeros]`` in position
     order (the pool stores a row padded to whole lanes, and the view is not
     cut back: the query is padded instead); n_keys: (B,) keys a lane may
-    see (positions 0 .. n_keys - 1).  Returns (B, H, R):
+    see (rows 0 .. n_keys - 1 of the view; from row ``starts`` (B,) on
+    where given: a window).  Returns (B, H, R):
     ``softmax(scores) . c_kv``, to be taken through W_uv by the caller.
     Scores and softmax in f32, masked keys exactly zero."""
     with jax.named_scope("mla_decode_attn"):
         S, stored = latent.shape[1:]
         seen = jnp.arange(S)[None, :] < n_keys[:, None]          # (B, S)
+        if starts is not None:
+            seen = seen & (jnp.arange(S)[None, :] >= starts[:, None])
         # 0 * NaN = NaN: a masked row must not reach the value product
         latent = jnp.where(seen[:, :, None], latent, 0)
         q = jnp.concatenate([q_lat, q_rope], axis=-1)

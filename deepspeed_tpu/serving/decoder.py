@@ -60,8 +60,11 @@ does).  The decoder object has:
     int).
 ``embed(params, tokens, positions) -> (..., E)``
 ``block(params, l, x, cache) -> x`` (or ``(x, stats)`` with ``stat_names``)
-    block ``l`` over x (B, T, E), residuals included; the model reads its
-    own layer's weights out of ``params``.  ``cache`` is
+    block ``l`` over x (B, T, E), residuals included (the last dim is the
+    MODEL's: the engine reads ``B, T, _ = x.shape``, so a model that
+    carries several residual streams side by side, ``MotifDecoder``'s
+    (B, T, 4 E), embeds into them and contracts them in ``final_norm``);
+    the model reads its own layer's weights out of ``params``.  ``cache`` is
     the engine's hook to layer ``l`` of the pool (``engine._LayerCache``):
     ``positions`` (B, T) and ``maxpos`` (B,) absolute, ``row_valid``
     (B, T) bool or None, ``write_rows(i, rows)`` / ``view_rows(i)`` for
@@ -69,11 +72,15 @@ does).  The decoder object has:
     in view order, which is position order on the dense path, ``stored``
     being the width padded with zeros to whole 128-lane tiles; a block
     that caches several raw rows writes and views each by its index;
-    ``attend_rows(i, q_lat, q_rope, rank)``: latent decode attention of one
-    query a lane over cache tensor ``i``, up-projections absorbed, (B, H,
-    rank); as with ``attend_heads`` the engine chooses its form: lowered
-    for a TPU it reads each lane's filled pages where they lie, elsewhere
-    ``mla_decode_attention`` over the view), and
+    ``attend_rows(i, q_lat, q_rope, rank, name=None)``: latent decode
+    attention of one query a lane over cache tensor ``i``, up-projections
+    absorbed, (B, H, rank); as with ``attend_heads`` the engine chooses its
+    form: lowered for a TPU it reads each lane's filled pages where they
+    lie (under the kernel name ``name``), elsewhere
+    ``mla_decode_attention`` over the view; raw rows may live in a group
+    that keeps a WINDOW (``MotifDecoder``): a view's row j then stands at
+    ``cache.k_start + j`` and ``attend_rows`` shows each lane its last
+    ``cache.window`` rows), and
     for a
     model with heads ``write_heads`` / ``view_heads`` with the two masks
     ``valid_scores`` / ``valid_keys`` of the gathered view, and
@@ -100,7 +107,8 @@ does).  The decoder object has:
     itself carries that group's pool through its loop with
     ``cache.carry(name)`` / ``cache.restore(name, arrays)``
     (``MellumDecoder``: its traced unit is one period of sliding layers and
-    a full one, ``n_layer`` the periods).
+    a full one, ``n_layer`` the periods; ``MotifDecoder``: ONE block, the
+    whole held stage, each run of layers of one kind a scan of its own).
 ``final_norm(params, x)``, ``logits(params, xe (N, E)) -> (N, vocab) f32``
 
 What serves a model whose cache is not ``(keys, values)`` (by its stated

@@ -316,18 +316,27 @@ class _LayerCache:
         return pool[self.l, self.gtables.reshape(-1)] \
             .reshape(B, K * pool.shape[2], pool.shape[3])
 
-    def attend_rows(self, i, q_lat, q_rope, rank):
+    def attend_rows(self, i, q_lat, q_rope, rank, name=None):
         """Latent (MLA) decode attention of one query a lane over cache
         tensor ``i``, the up-projections absorbed by the caller: q_lat
         (B, H, rank), q_rope (B, H, Dr) -> (B, H, rank).  As
         ``attend_heads``, the engine chooses the form: where the program
         states ``lengths``, the latent fills whole lanes and it is lowered
         for a TPU, the paged kernel reads each lane's filled pages where
-        they lie; everywhere else ``mla_decode_attention`` over the gathered
-        view."""
+        they lie (``name``: what it is called in the compiled program, its
+        own default where None); everywhere else ``mla_decode_attention``
+        over the gathered view.  In a group that keeps a window each lane
+        sees its last ``window`` rows, of a view that begins at
+        ``k_start``."""
         def over_view(q_lat, q_rope):
-            return mla_decode_attention(q_lat, q_rope, self.view_rows(i),
-                                        self.maxpos + 1, rank)
+            view = self.view_rows(i)
+            n_keys = self.maxpos + 1
+            if self._state.base is not None:
+                n_keys = n_keys - self._state.base
+            return mla_decode_attention(
+                q_lat, q_rope, view, n_keys, rank,
+                starts=None if self.window is None
+                else jnp.maximum(n_keys - self.window, 0))
 
         if self.lengths is None \
                 or not latent_reads_in_place(self.pools[i].shape, rank):
@@ -336,7 +345,8 @@ class _LayerCache:
         def over_pages(q_lat, q_rope):
             return paged_latent_decode_attention(
                 q_lat, q_rope, self.pools[i], self.l, self.gtables,
-                self.lengths, latent_rank=rank, interpret=False)
+                self.lengths, latent_rank=rank, starts=self._state.starts,
+                interpret=False, **({} if name is None else {"name": name}))
 
         return jax.lax.platform_dependent(q_lat, q_rope, tpu=over_pages,
                                           default=over_view)

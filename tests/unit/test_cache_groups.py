@@ -284,3 +284,140 @@ def test_shards_refuse_two_groups_by_name(toy):
     mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
     with pytest.raises(UnsupportedForModel, match="shards"):
         _engine(toy, shards=2, mesh=mesh)
+
+
+# ---------------------------------------------------------------------------
+# a window group of RAW ROWS (latent rows under a window: models/motif.py)
+# ---------------------------------------------------------------------------
+ROWS_TOY = {
+    "name": "toy-rows", "architecture": "motif", "attention_cls": "gdla",
+    "diff_v2": True, "elementwise_attn_output_gate": True,
+    "headwise_attn_output_gate": False, "hidden_act": "poly_norm",
+    "mhc_enabled": True, "score_before_experts": False,
+    "interleave_moe_layer_step": 1, "sliding_window_pattern": "interleave",
+    "rope_scaling": {"apply_yarn_scaling": False}, "swa_rope_theta": 10000,
+    "rope_theta": 10000, "tie_word_embeddings": False, "k_ratio": 1,
+    "polynorm_output_scale_per_layer": {}, "vocab_size": 97,
+    "hidden_size": 32, "num_hidden_layers": 5, "layers_held": [1, 4, 5, 6, 7],
+    "num_attention_heads": 10, "num_key_value_heads": 2,
+    "num_noise_heads": 2, "head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 8, "q_lora_rank": 16, "kv_lora_rank": 16,
+    "intermediate_size": 48, "moe_intermediate_size": 16, "num_experts": 16,
+    "experts_top_k": 2, "num_shared_experts": 1, "n_dense_first_layers": 2,
+    "first_expert_held": 0, "num_experts_held": 16, "route_norm": True,
+    "route_scale": 2, "score_func": "sigmoid", "sliding_window": WINDOW,
+    "sliding_window_period": 4, "mhc_expansion_rate": 4,
+    "mhc_sinkhorn_iters": 20, "rms_norm_eps": 1e-5,
+    "polynorm_output_scale": 0.5, "polynorm_bias_clamp": 0.5,
+    "hidden_clamp": 1000000, "max_position_embeddings": 256,
+    "assumed": {"compute_dtype": "float32", "initializer_range": 0.2,
+                "mhc_alpha_init": 0.2}}
+
+
+@pytest.fixture(scope="module")
+def rows_arch():
+    arch = cells.load_module(os.path.join(
+        BENCH_DIR, "architectures", "motif.py"), "bench_arch_motif_groups")
+    # the reference's blocks at a toy's size (a module of its own)
+    arch._ROWS, arch._Q_ROWS, arch._KEY_BUCKET, arch._TILE_ROWS, \
+        arch._HEAD_ROWS = 32, 16, 64, 8, 8
+    return arch
+
+
+@pytest.fixture(scope="module")
+def rows_toy(rows_arch):
+    model = rows_arch.build_model(ROWS_TOY, {"moe_tile_rows": 8,
+                                             "moe_tile_rows_decode": 8})
+    return model, rows_arch.init_params(model, 6)
+
+
+def _gap(arch, params, tokens, n):
+    rows = np.arange(n - 1, len(tokens) - 1)
+    logits = np.asarray(arch.reference_logits(
+        arch.reference_weights(params, ROWS_TOY), ROWS_TOY, tokens[None],
+        rows)[0])
+    return (logits.max(-1)
+            - logits[np.arange(len(rows)), tokens[rows + 1]]).max()
+
+
+def test_a_window_group_of_raw_rows_has_one_padded_tensor_a_group(rows_toy):
+    cfg = rows_toy[0].config
+    assert kv_cache.cache_kind(cfg) == kv_cache.RAW_ROWS
+    assert cache_groups(cfg) == (CacheGroup("full", 1, None),
+                                 CacheGroup("window", 4, WINDOW))
+    # one raw row a token (16 | 8 stored as 128: whole lanes), no values
+    assert pool_shapes(cfg, 9, PAGE, False, 0)[:2] == ((1, 9, PAGE, 128),
+                                                       None)
+    assert pool_shapes(cfg, 7, PAGE, False, 1)[:2] == ((4, 7, PAGE, 128),
+                                                       None)
+    assert serving.group_table_widths(cfg, 24, PAGE, CHUNK) \
+        == [(24, 24), (3, 5)]
+    real = cfg.__class__()                  # the published sizes
+    assert cache_groups(real) == (CacheGroup("full", 13, None),
+                                  CacheGroup("window", 40, 128))
+    assert pool_shapes(real, 177, 64, False, 1)[:2] == ((40, 177, 64, 640),
+                                                        None)
+
+
+def test_rows_window_group_allocates_slides_and_frees_as_any_other(rows_toy):
+    pool = _pool(rows_toy, blocks=(12, 6))
+    assert len(pool.all_arrays) == 2        # one tensor a group
+    assert pool.alloc(1, 0, 4 * PAGE)
+    full = pool.table_row(1, 8).tolist()
+    assert pool.table_row(1, 5, group=1).tolist()[:4] == [1, 2, 3, 4]
+    assert pool.release_expired(1, 4 * PAGE) == 2
+    assert pool.table_base(1, 1) == 2 * PAGE
+    assert pool.table_row(1, 5, group=1).tolist() == [3, 4, 0, 0, 0]
+    assert pool.table_row(1, 8).tolist() == full
+    assert _free_counts(pool) == [7, 3]
+    assert not pool.alloc(2, 0, 4 * PAGE)   # the window group cannot cover
+    assert _free_counts(pool) == [7, 3]
+    pool.free(1)
+    assert _free_counts(pool) == [11, 5]
+
+
+def test_rows_window_pages_are_reused_and_both_requests_hold_to_reference(
+        rows_arch, rows_toy):
+    """Raw latent rows in a window group: the first request decodes while
+    its window pages expire and go back, the second's chunks are given
+    them, and both serve what the reference computes."""
+    engine = _engine(rows_toy)
+    engine.warmup()
+    window = engine.pool._further[0]
+    first = engine.submit(_prompt(30, 1), max_new_tokens=40)
+    held = set()
+    while not engine.scheduler.running:
+        engine.step()
+        held |= set(window.blocks.get(first, ()))
+    for _ in range(12):
+        engine.step()
+        held |= set(window.blocks.get(first, ()))
+    second = engine.submit(_prompt(45, 2), max_new_tokens=12)
+    reused = set()
+    while engine.scheduler.has_work():
+        engine.step()
+        reused |= set(window.blocks.get(second, ())) & held
+        held |= set(window.blocks.get(first, ()))
+    assert reused and engine.pool.window_frees > 0
+    for rid, n in ((first, 30), (second, 45)):
+        assert _gap(rows_arch, rows_toy[1], np.asarray(engine.result(rid)),
+                    n) <= 1e-4
+    assert all(g["blocks_in_use"] == 0 for g in engine.pool.group_stats())
+
+
+def test_rows_window_group_survives_preemption_and_resume(rows_arch,
+                                                          rows_toy):
+    """A full group too small for two long lanes: one is preempted, both
+    groups' pages go back, it is resumed (its prompt and what it had
+    generated prefilled again into fresh pages of both groups) and still
+    serves what the reference computes."""
+    engine = _engine(rows_toy, kv_blocks=1 + 14)
+    prompts = [_prompt(n, s) for n, s in ((10, 3), (12, 4))]
+    rids = [engine.submit(p, max_new_tokens=30) for p in prompts]
+    engine.serve()
+    assert all(engine.results[r]["status"] == "finished" for r in rids)
+    assert engine.metrics.evictions > 0
+    for rid, p in zip(rids, prompts):
+        assert _gap(rows_arch, rows_toy[1], np.asarray(engine.result(rid)),
+                    len(p)) <= 1e-4
+    assert all(g["blocks_in_use"] == 0 for g in engine.pool.group_stats())

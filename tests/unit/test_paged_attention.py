@@ -365,3 +365,107 @@ def test_the_program_names_the_latent_kernel():
                                  latent_rank=128, interpret=False))(*case)
     assert LATENT_KERNEL_NAME == "paged_latent_decode_attn"
     assert f"name={LATENT_KERNEL_NAME}" in str(program)
+
+
+# ---------------------------------------------------------------------------
+# a window over pages of latent rows: a lane sees rows start .. length - 1
+# ---------------------------------------------------------------------------
+# "m3": motif-3-beta-ep8's row (512 | 64 stored as 640), pages and 80 heads
+LATENT["m3"] = (80, 512, 64, 640, 64, 8)
+
+
+def _latent_over_view_from(q_lat, q_rope, pool, tables, lengths, starts):
+    """What ``_LayerCache.attend_rows`` runs off the TPU in a group that
+    keeps a window."""
+    B, W = tables.shape
+    view = pool[LAYER, tables.reshape(-1)].reshape(B, -1, pool.shape[3])
+    return np.asarray(mla_decode_attention(
+        q_lat, q_rope, view, lengths, q_lat.shape[-1], starts=starts),
+        np.float32)
+
+
+@pytest.mark.parametrize("width,window,length", [
+    pytest.param(width, window, length, id=f"{width}-w{window}-{length}")
+    for width, window, lengths in (
+        ("toy", 6, [1, 5, 6, 7, 130, ROWS]),
+        ("toy", 17, [3, 16, 17, 18, 255]),      # a window of several pages
+        ("r128", 40, [1, 40, 41, 200, ROWS]),
+        ("m3", 128, [1, 128, 129, 200, 512]))
+    for length in lengths])
+def test_latent_window_equals_mla_decode_attention_from_the_start(
+        width, window, length):
+    """``starts``: the lane's first visible row; the kernel copies only the
+    pages that hold rows ``start .. length - 1`` and masks the rest of its
+    first page.  Beside idle lanes and lanes whose window is not yet
+    full."""
+    rng = np.random.default_rng(length)
+    rows = _rows(width)
+    lengths = np.asarray(
+        [length, 0, rows + 1 - length, 0, int(rng.integers(1, rows))])
+    starts = jnp.asarray(np.maximum(lengths - window, 0), jnp.int32)
+    case = _latent_case(rng, width, lengths)
+    got = _latent_kernel(*case, starts=starts)
+    want = _latent_over_view_from(*case, starts)
+    live = lengths > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+    assert not got[~live].any()
+    # and it is a window: the rows below the start change nothing
+    q_lat, q_rope, pool, tables, lens = case
+    W, bs = tables.shape[1], pool.shape[2]
+    row = np.arange(W * bs)[None, :] < np.asarray(starts)[:, None]  # (B, rows)
+    poisoned = np.array(pool)
+    for b in range(len(lengths)):
+        for page in range(W):
+            below = row[b, page * bs:(page + 1) * bs]
+            poisoned[LAYER, int(tables[b, page]), below] = np.nan
+    again = _latent_kernel(q_lat, q_rope, jnp.asarray(poisoned), tables,
+                           lens, starts=starts)
+    np.testing.assert_array_equal(again[live], got[live])
+
+
+def test_latent_window_kernel_carries_the_name_it_is_given():
+    case = _latent_case(np.random.default_rng(0), "r128", [30])
+    program = jax.make_jaxpr(lambda q_lat, q_rope, pool, tables, lens:
+                             paged_latent_decode_attention(
+                                 q_lat, q_rope, pool, LAYER, tables, lens,
+                                 latent_rank=128, starts=lens - 8,
+                                 interpret=False, name="gdla_window"))(*case)
+    assert "name=gdla_window" in str(program)
+    assert f"name={LATENT_KERNEL_NAME}" not in str(program)
+
+
+# ---------------------------------------------------------------------------
+# the rectangle kernel: shared rotary key + grouped query heads + window
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("q_start,k_start,window", [
+    (0, 0, 7), (40, 24, 7), (40, 24, 20), (100, 64, 33), (100, 0, None)])
+def test_rect_kernel_with_shared_key_grouped_heads_and_window_at_once(
+        q_start, k_start, window):
+    """Five query heads a key/value head, the rotary key shared by all, a
+    window and a view that begins at ``k_start``, in ONE call, against the
+    ``jax.numpy`` core (what a chunk of Motif's sliding layers asks)."""
+    from deepspeed_tpu.ops.transformer.rect_attention import \
+        rect_flash_attention
+
+    rng = np.random.default_rng(q_start + (window or 0))
+    Hkv, G, C, S, D, Ds, Dv = 2, 5, 24, 160, 16, 8, 16
+    H = Hkv * G
+    q, qs = (jnp.asarray(rng.standard_normal((H, C, n)) * n ** -0.5,
+                         jnp.float32) for n in (D, Ds))
+    k = jnp.asarray(rng.standard_normal((Hkv, S, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((Hkv, S, Dv)), jnp.float32)
+    ks = jnp.asarray(rng.standard_normal((S, Ds)), jnp.float32)
+    got = rect_flash_attention(
+        q, k, v, q_start, qs, ks, k_start=k_start, window=window,
+        block_q=8, block_k=32, interpret=True)
+    qpos = q_start + np.arange(C)[:, None]
+    kpos = k_start + np.arange(S)[None, :]
+    seen = kpos <= qpos
+    if window is not None:
+        seen &= kpos > qpos - window
+    s = jnp.einsum("jgcd,jsd->jgcs", q.reshape(Hkv, G, C, D), k) \
+        + jnp.einsum("jgcd,sd->jgcs", qs.reshape(Hkv, G, C, Ds), ks)
+    p = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+    want = jnp.einsum("jgcs,jsv->jgcv", p, v).reshape(H, C, Dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)

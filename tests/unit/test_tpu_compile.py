@@ -546,6 +546,86 @@ def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
         assert not made, f"decode gathers every lane's pages: {made}"
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
+        program, v5e):
+    """Motif's sliding and full layers cache RAW LATENT ROWS in two groups:
+    at the published widths and the cell's sizes (48 slots x 776 pages of
+    64, chunks of 2,048; one dense and four routed layers) both groups'
+    scatters update the donated pools in place, no program relays a pool
+    tensor, and a kernel is ONE operation a RUN of layers of one kind: the
+    window's attention twice (the dense layer, the scan of three routed
+    sliding layers), the full layer's once, under the names the model
+    gives them; decode reads both groups' pages where they lie (the
+    window's from each lane's first visible row)."""
+    import re
+
+    from deepspeed_tpu.models.motif import MotifConfig, MotifModel
+    from deepspeed_tpu.serving import engine as serving
+    from deepspeed_tpu.serving.kv_cache import pool_shapes
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs, C = 48, 776, 64, 2048
+    cfg = MotifConfig(num_hidden_layers=5, layers_held=(1, 4, 5, 6, 7),
+                      experts_held=(0, 48), vocab_size=27520,
+                      pallas_interpret=False)
+    model = MotifModel(cfg)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree_util.tree_map(
+        lambda l: struct(l.shape, l.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    blocks = serving.default_pool_blocks(cfg, 1, S, W, bs, C)
+    widths = serving.group_table_widths(cfg, W, bs, C)
+    assert blocks == [1 + S * W, 1 + S * 3 + 32] \
+        and widths == [(W, W), (3, 35)]
+    shapes = [pool_shapes(cfg, n, bs, False, g)[0]
+              for g, n in enumerate(blocks)]
+    assert shapes == [(1, blocks[0], bs, 640), (4, blocks[1], bs, 640)]
+    tensors = [struct(shape, cfg.dtype) for shape in shapes]
+    if program == "decode":
+        jitted = serving._make_decode_step(cfg, W, bs, False, 0.0, 0, 0.0,
+                                           None, "data")
+        streams = [((struct((S, W), jnp.int32), struct((S, 3), jnp.int32)),
+                    (None, struct((S,), jnp.int32)))] + [
+            struct((S,), dtype) for dtype in
+            (jnp.int32, jnp.int32, jnp.bool_, jnp.int32, jnp.float32)]
+    else:
+        jitted = serving._make_prefill_chunk(cfg, C, W, bs, False, False,
+                                             0.0, 0, 0.0, None, "data")
+        streams = [((struct((1, W), jnp.int32), struct((1, 35), jnp.int32)),
+                    (None, struct((1,), jnp.int32))),
+                   struct((C,), jnp.int32), struct((), jnp.int32),
+                   struct((1,), jnp.int32), struct((), jnp.int32)]
+    compiled = jitted.lower(params, *tensors, *streams).compile()
+    text = compiled.as_text()
+    for shape in shapes:
+        dims = ",".join(str(d) for d in shape)
+        relays = [line.strip()[:120] for line in text.splitlines()
+                  if re.search(rf"= \w+\[{dims}\]\S* copy\(", line)]
+        assert not relays, f"{program} relays a pool: {relays}"
+    assert hc.aliased_outputs(text) >= {0, 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
+    calls = [re.match(r"\s*%(\S+?)(?:\.\d+)? = ", line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    kind = "decode" if program == "decode" else "prefill"
+    attn = "gdla_paged_decode_attn" if program == "decode" \
+        else "gdla_prefill_attn"
+    # a chunk that is not a prompt's last has no use for the last layer's
+    # feed-forward: its experts' matmuls are not in the program
+    assert sorted(calls) == sorted(
+        [f"{attn}_full"] + [f"{attn}_window"] * 2
+        + [f"moe_grouped_matmul_{kind}_{call}" for call in ("up", "down")]
+        * (2 if program == "decode" else 1)), calls
+    if program == "decode":
+        view = re.compile(rf"= \w+\[{S},({W * bs}|{3 * bs}),")
+        made = [line.strip()[:120] for line in text.splitlines()
+                if view.search(line)]
+        assert not made, f"decode gathers every lane's pages: {made}"
+
+
 def test_decode_program_keeps_the_view_where_pages_are_not_whole_tiles(v5e):
     """gpt2-xl's row of 25 heads x 64 is 12.5 lanes wide: Mosaic would
     refuse to slice it, so the engine, which sees the pool's shape, builds

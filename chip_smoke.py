@@ -41,6 +41,8 @@ from deepspeed_tpu.ops.sparse_attention import (FixedSparsityConfig,
 from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 from deepspeed_tpu.ops.transformer.functional import (
     scaled_dot_product_attention)
+from deepspeed_tpu.ops.transformer.mhc_mix import (fold_phi, mhc_post_mix,
+                                                   mhc_pre_mix)
 from deepspeed_tpu.ops.transformer.paged_attention import \
     paged_decode_attention
 from deepspeed_tpu.serving import (CompilationCounter, FleetRouter,
@@ -53,6 +55,11 @@ from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 # reference rounds its probabilities to bf16 (8 significand bits, 2^-8 per
 # rounding) where the kernels keep them in f32, so a few roundings apart.
 KERNEL_TOL = 3e-2
+# The residual mixes' gates and H_res are f32 from end to end: f32's
+# rounding through a 16,384-term product and twenty Sinkhorn iterations.
+# One bf16 rounding of Phi (which XLA's excess precision once made of the
+# three-piece split, on the chip only) reads a hundred times this.
+MIX_TOL = 1e-5
 # <out, cot> == <v, dL/dv> holds exactly for attention-with-dropout when
 # forward and backward drew the same keep mask (out is linear in v)
 DROPOUT_IDENTITY_TOL = 2e-2
@@ -205,12 +212,70 @@ def _paged_decode(rng, lanes, n_head, head_dim, block, pages, layers=2):
     return {"out": round(err, 5)}
 
 
+def _mhc_mixes(rng, rows, n, width, iters=20, eps=1e-5):
+    """The two kernels of the residual mixes (bf16 streams and weights, as
+    served) against the equations in float64 on the host: the gates and
+    H_res to f32's rounding, what is written in bf16 to a bf16 spacing."""
+    f64 = np.float64
+    S = 2 * n + n * n
+    bf16 = lambda a: jnp.asarray(a, jnp.bfloat16)            # noqa: E731
+    X = bf16(1.5 * rng.standard_normal((rows, n * width)))
+    y = bf16(rng.standard_normal((rows, width)))
+    norm = bf16(1 + 0.1 * rng.standard_normal(n * width))
+    phi = bf16(rng.standard_normal((n * width, S)) / (n * width) ** 0.5)
+    alpha, beta = bf16([0.7, 0.9, 1.1]), bf16(0.3 * rng.standard_normal(S))
+
+    @jax.jit
+    def kernels(X, y, norm, phi, alpha, beta):
+        folded, consts = fold_phi(norm, phi, alpha, beta, n)
+        u, h_post, h_res, err = mhc_pre_mix(X, folded, consts, n=n,
+                                            iters=iters, eps=eps)
+        return u, h_post, h_res, err, mhc_post_mix(X, y, h_post, h_res,
+                                                   clamp=1e6)
+
+    u, h_post, h_res, err, out = (np.asarray(a, f64) for a in kernels(
+        X, y, norm, phi, alpha, beta))
+    X, y, norm, phi, alpha, beta = (np.asarray(a, f64) for a in (
+        X, y, norm, phi, alpha, beta))
+    abc = X / np.sqrt((X * X).mean(-1, keepdims=True) + eps) * norm @ phi
+    sigmoid = lambda z: 1 / (1 + np.exp(-z))                 # noqa: E731
+    pre = sigmoid(alpha[0] * abc[:, :n] + beta[:n])
+    post = 2 * sigmoid(alpha[1] * abc[:, n:2 * n] + beta[n:2 * n])
+    res = np.exp((alpha[2] * abc[:, 2 * n:] + beta[2 * n:])
+                 .reshape(rows, n, n))
+    for _ in range(iters):
+        res = res / res.sum(-1, keepdims=True)
+        res = res / res.sum(-2, keepdims=True)
+    streams = X.reshape(rows, n, width)
+    seen = {"h_post": np.abs(h_post - post).max(),
+            "h_res": np.abs(h_res - res.reshape(rows, -1)).max(),
+            "u": _rel_err(u, np.einsum("si,sie->se", pre, streams)),
+            "out": _rel_err(out, (np.einsum("sij,sje->sie", res, streams)
+                                  + post[:, :, None] * y[:, None])
+                            .reshape(rows, -1))}
+    check(max(seen["h_post"], seen["h_res"]) <= MIX_TOL,
+          f"mhc mixes: the gates or H_res leave float64's by {seen}")
+    check(max(seen["u"], seen["out"]) <= 2.0 ** -7,
+          f"mhc mixes: u or the streams leave float64's by {seen}")
+    left = np.maximum(np.abs(res.sum(2) - 1).max(1),
+                      np.abs(res.sum(1) - 1).max(1))
+    check(np.abs(err - left).max() <= MIX_TOL,
+          f"mhc mixes: the kernel counts {err.max()} left by Sinkhorn, "
+          f"float64 {left.max()}")
+    return {name: float(f"{value:.3g}") for name, value in seen.items()}
+
+
 def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
                   bias_shape=(8, 16, 512, 64),
                   sparse_shape=(2, 12, 4096, 64), sparse_block=64,
-                  paged_shape=(28, 16, 64, 16, 64)):
+                  paged_shape=(28, 16, 64, 16, 64),
+                  mhc_shape=(2048, 4, 4096)):
     rng = np.random.default_rng(seed)
     seen = {}
+
+    # Motif's residual mixes: rows, streams, width of a stream (a chunk of
+    # the agent-turns cell)
+    seen["mhc_mixes"] = _mhc_mixes(rng, *mhc_shape)
 
     # serving decode over the paged pool: lanes, heads, head size, rows a
     # page, pages a lane (the chat cell's)
@@ -297,7 +362,8 @@ def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
                        "flash_key_bias": list(bias_shape),
                        "block_sparse": list(sparse_shape)
                        + [f"block {sparse_block}"],
-                       "paged_decode_attn": list(paged_shape)}}
+                       "paged_decode_attn": list(paged_shape),
+                       "mhc_mixes": list(mhc_shape)}}
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,9 @@ settle a reading, ``benchmark/configs/motif-3-beta-ep8.json`` names it under
   and writes ``X = H_res X + H_post (x) F(RMSNorm(u))``, the three mixes
   computed from the token's own streams, ``H_res`` (n x n) made doubly
   stochastic by ``mhc_sinkhorn_iters`` Sinkhorn iterations:
-  :func:`mhc_pre`, :func:`mhc_post`, f32 inside.
+  :func:`mhc_pre`, :func:`mhc_post`, each ONE Pallas kernel over blocks of
+  rows of the streams (``ops/transformer/mhc_mix.py``: f32 inside, no f32
+  copy of X in HBM); the equations stand in the benchmark's reference.
 - Attention (GDLA): LATENT attention (``models/latent_attention.py``, the
   family's one function) whose ``kv_b`` up-projects the latent to
   ``num_key_value_heads`` key/value heads that the ``num_attention_heads``
@@ -54,6 +56,8 @@ from deepspeed_tpu.models.latent_attention import (_rms_norm,
                                                    latent_attention)
 from deepspeed_tpu.moe.dropless import STAT_NAMES, dropless_moe
 from deepspeed_tpu.moe.grouped_matmul import KERNEL_NAME
+from deepspeed_tpu.ops.transformer.mhc_mix import (fold_phi, mhc_post_mix,
+                                                   mhc_pre_mix)
 
 # what the two attention kernels are called in the compiled program and the
 # device trace, with the cache group's name behind
@@ -216,85 +220,32 @@ def _poly_ffn(cfg, x, gate_up, down, poly):
     return h.astype(x.dtype) @ down
 
 
-def sinkhorn(logits, iters):
-    """(n, n, N) f32 scores, the tokens LAST (on the lanes) -> exp of them
-    made doubly stochastic: ``iters`` times rows divided by their sums, then
-    columns by theirs; and (N,) the largest |row or column sum - 1| left.
-    The sums are written out term by term, so the whole chain is
-    elementwise."""
-    n = logits.shape[0]
-    m = jnp.exp(logits - jnp.max(logits, axis=(0, 1), keepdims=True))
-
-    def rows(m):
-        return sum(m[:, j:j + 1] for j in range(n))         # (n, 1, N)
-
-    def cols(m):
-        return sum(m[i:i + 1] for i in range(n))            # (1, n, N)
-
-    def iteration(_, m):
-        m = m / rows(m)
-        return m / cols(m)
-
-    # a rolled loop, four iterations a trip: unrolled whole, the twenty
-    # iterations of every sublayer were most of the time it takes to compile
-    # a program; one a trip, a trip's fixed cost on the chip (~35 us) was
-    # most of a decode program's mixes
-    m = jax.lax.fori_loop(0, iters, iteration, m,
-                          unroll=max(1, min(4, iters)))
-    err = jnp.maximum(jnp.max(jnp.abs(rows(m) - 1.0), axis=(0, 1)),
-                      jnp.max(jnp.abs(cols(m) - 1.0), axis=(0, 1)))
-    return m, err
-
-
 def mhc_pre(cfg, p, X):
     """The three mixes of one sublayer from the token's own streams, and
     what the sublayer reads.  X (N, n E), the streams side by side; p:
     ``norm`` (n E,), ``phi`` (n E, 2 n + n n), ``beta`` (2 n + n n,),
     ``alpha`` (3,).  Returns u (N, E) in X's dtype, H_post (N, n) f32,
     H_res (N, n n) f32 (row i, column j at ``i n + j``) and the Sinkhorn
-    error (N,).  f32 inside; the norm's weight is folded into ``phi`` and
-    its rsqrt taken out of the product, so the normed streams are never
-    written; a stream is a slice of whole lanes of X, never a (N, n, E)
-    view (whose n rows a token would be padded to a tile's sixteen)."""
+    error (N,): ONE kernel over blocks of rows of X
+    (``ops/transformer/mhc_mix.py``), f32 inside, the norm's weight folded
+    into ``phi`` and its rsqrt taken out of the product."""
+    n = cfg.mhc_expansion_rate
     with jax.named_scope("mhc_pre"):
-        n = cfg.mhc_expansion_rate
-        N, E = X.shape[0], X.shape[1] // n
-        x32 = X.astype(jnp.float32)
-        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
-                            + cfg.rms_norm_eps)
-        phi = p["norm"].astype(jnp.float32)[:, None] \
-            * p["phi"].astype(jnp.float32)
-        abc = jnp.dot(x32, phi, precision=jax.lax.Precision.HIGHEST) * inv
-        alpha = p["alpha"].astype(jnp.float32)
-        beta = p["beta"].astype(jnp.float32)
-        h_pre = jax.nn.sigmoid(alpha[0] * abc[:, :n] + beta[:n])
-        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * abc[:, n:2 * n]
-                                      + beta[n:2 * n])
-        # Sinkhorn with the tokens on the lanes, its result back beside them
-        scores = (alpha[2] * abc[:, 2 * n:] + beta[2 * n:]).T \
-            .reshape(n, n, N)
-        h_res, err = sinkhorn(scores, cfg.mhc_sinkhorn_iters)
-        u = sum(h_pre[:, i:i + 1] * x32[:, i * E:(i + 1) * E]
-                for i in range(n))
-        return u.astype(X.dtype), h_post, h_res.reshape(n * n, N).T, err
+        folded, consts = fold_phi(p["norm"], p["phi"], p["alpha"],
+                                  p["beta"], n)
+        return mhc_pre_mix(X, folded, consts, n=n,
+                           iters=cfg.mhc_sinkhorn_iters,
+                           eps=cfg.rms_norm_eps,
+                           interpret=cfg.pallas_interpret)
 
 
 def mhc_post(cfg, X, y, h_post, h_res):
     """``X = H_res X + H_post (x) y``, clipped at ``hidden_clamp``.  X
     (N, n E), y (N, E), H_post (N, n), H_res (N, n n) -> (N, n E) in X's
-    dtype; f32 inside, the products written out stream by stream (n x n
-    scalars a token are no matmul)."""
+    dtype: the second kernel of ``ops/transformer/mhc_mix.py``."""
     with jax.named_scope("mhc_post"):
-        n = cfg.mhc_expansion_rate
-        E = y.shape[1]
-        x32, y32 = X.astype(jnp.float32), y.astype(jnp.float32)
-        streams = [x32[:, j * E:(j + 1) * E] for j in range(n)]
-        out = [sum(h_res[:, i * n + j:i * n + j + 1] * streams[j]
-                   for j in range(n)) + h_post[:, i:i + 1] * y32
-               for i in range(n)]
-        out = jnp.clip(jnp.concatenate(out, axis=1), -cfg.hidden_clamp,
-                       cfg.hidden_clamp)
-        return out.astype(X.dtype)
+        return mhc_post_mix(X, y, h_post, h_res, clamp=cfg.hidden_clamp,
+                            interpret=cfg.pallas_interpret)
 
 
 def _rope_cos_sin(cfg, positions):

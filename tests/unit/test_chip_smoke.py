@@ -32,9 +32,10 @@ SERVE_TINY = dict(n_requests=4, prompt_range=(5, 20), new_tokens=4,
 def test_kernels_phase_interpret_mode():
     out = chip_smoke.phase_kernels(
         causal_shape=(1, 1, 256, 64), bias_shape=(2, 2, 128, 64),
-        sparse_shape=(2, 2, 256, 64), paged_shape=(4, 2, 8, 4, 8))
+        sparse_shape=(2, 2, 256, 64), paged_shape=(4, 2, 8, 4, 8),
+        mhc_shape=(40, 4, 32))
     assert set(out["rel_err"]) == {
-        "paged_decode_attn", "flash_causal", "flash_dropout",
+        "mhc_mixes", "paged_decode_attn", "flash_causal", "flash_dropout",
         "flash_key_bias", "block_sparse", "block_sparse_key_bias"}
 
 

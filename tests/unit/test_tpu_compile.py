@@ -546,26 +546,21 @@ def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
         assert not made, f"decode gathers every lane's pages: {made}"
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill2048"])
-def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
-        program, v5e):
-    """Motif's sliding and full layers cache RAW LATENT ROWS in two groups:
-    at the published widths and the cell's sizes (48 slots x 776 pages of
-    64, chunks of 2,048; one dense and four routed layers) both groups'
-    scatters update the donated pools in place, no program relays a pool
-    tensor, and a kernel is ONE operation a RUN of layers of one kind: the
-    window's attention twice (the dense layer, the scan of three routed
-    sliding layers), the full layer's once, under the names the model
-    gives them; decode reads both groups' pages where they lie (the
-    window's from each lane's first visible row)."""
-    import re
+_MOTIF_CELL = (48, 776, 64, 2048)      # slots, pages a lane, page, chunk
+_motif_programs = {}
 
+
+def _motif_program(program, v5e):
+    """Motif's decode or chunk program at the published widths and the
+    cell's sizes (one dense and four routed layers), compiled for the
+    described chip once a session: ``(compiled, its text, pool shapes)``."""
+    if program in _motif_programs:
+        return _motif_programs[program]
     from deepspeed_tpu.models.motif import MotifConfig, MotifModel
     from deepspeed_tpu.serving import engine as serving
     from deepspeed_tpu.serving.kv_cache import pool_shapes
-    from tools.graftlint import hlo_contracts as hc
 
-    S, W, bs, C = 48, 776, 64, 2048
+    S, W, bs, C = _MOTIF_CELL
     cfg = MotifConfig(num_hidden_layers=5, layers_held=(1, 4, 5, 6, 7),
                       experts_held=(0, 48), vocab_size=27520,
                       pallas_interpret=False)
@@ -600,7 +595,36 @@ def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
                    struct((C,), jnp.int32), struct((), jnp.int32),
                    struct((1,), jnp.int32), struct((), jnp.int32)]
     compiled = jitted.lower(params, *tensors, *streams).compile()
-    text = compiled.as_text()
+    _motif_programs[program] = compiled, compiled.as_text(), shapes
+    return _motif_programs[program]
+
+
+def _custom_calls(text):
+    """The names of a compiled program's Mosaic calls, a call site each."""
+    import re
+
+    return sorted(re.match(r"\s*%(\S+?)(?:\.\d+)? = ", line).group(1)
+                  for line in text.splitlines() if "tpu_custom_call" in line)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
+        program, v5e):
+    """Motif's sliding and full layers cache RAW LATENT ROWS in two groups:
+    at the published widths and the cell's sizes (48 slots x 776 pages of
+    64, chunks of 2,048; one dense and four routed layers) both groups'
+    scatters update the donated pools in place, no program relays a pool
+    tensor, and a kernel is ONE operation a RUN of layers of one kind: the
+    window's attention twice (the dense layer, the scan of three routed
+    sliding layers), the full layer's once, under the names the model
+    gives them; decode reads both groups' pages where they lie (the
+    window's from each lane's first visible row)."""
+    import re
+
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs, _ = _MOTIF_CELL
+    compiled, text, shapes = _motif_program(program, v5e)
     for shape in shapes:
         dims = ",".join(str(d) for d in shape)
         relays = [line.strip()[:120] for line in text.splitlines()
@@ -608,22 +632,52 @@ def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
         assert not relays, f"{program} relays a pool: {relays}"
     assert hc.aliased_outputs(text) >= {0, 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
-    calls = [re.match(r"\s*%(\S+?)(?:\.\d+)? = ", line).group(1)
-             for line in text.splitlines() if "tpu_custom_call" in line]
     kind = "decode" if program == "decode" else "prefill"
     attn = "gdla_paged_decode_attn" if program == "decode" \
         else "gdla_prefill_attn"
     # a chunk that is not a prompt's last has no use for the last layer's
     # feed-forward: its experts' matmuls are not in the program
-    assert sorted(calls) == sorted(
+    assert [call for call in _custom_calls(text)
+            if not call.startswith("mhc_")] == sorted(
         [f"{attn}_full"] + [f"{attn}_window"] * 2
         + [f"moe_grouped_matmul_{kind}_{call}" for call in ("up", "down")]
-        * (2 if program == "decode" else 1)), calls
+        * (2 if program == "decode" else 1))
     if program == "decode":
         view = re.compile(rf"= \w+\[{S},({W * bs}|{3 * bs}),")
         made = [line.strip()[:120] for line in text.splitlines()
                 if view.search(line)]
         assert not made, f"decode gathers every lane's pages: {made}"
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def test_the_residual_mixes_are_two_kernels_and_no_f32_copy_of_the_streams(
+        program, v5e):
+    """Every sublayer of Motif's programs mixes its four streams through
+    ``mhc_pre_mix`` and ``mhc_post_mix`` (a call site a traced sublayer:
+    the dense layer's two, the scanned run's two, the full layer's two),
+    and NO operation of either program writes the streams' shape in
+    float32: the copy the ``jax.numpy`` mixes wrote and read back (134 MB
+    at 2,048 tokens, twice a sublayer) is what the kernels remove."""
+    import re
+
+    S, _, _, C = _MOTIF_CELL
+    _, text, _ = _motif_program(program, v5e)
+    mixes = [call for call in _custom_calls(text) if call.startswith("mhc_")]
+    # the last sublayer of a chunk that is not a prompt's last: its error
+    # is counted, its feed-forward and the streams it would leave are not
+    assert mixes == ["mhc_post_mix"] * (6 if program == "decode" else 5) \
+        + ["mhc_pre_mix"] * 6, mixes
+    # what an operation WRITES (inside a fusion XLA may widen a bf16 sum
+    # in registers: the engine's own add over the streams does)
+    streams = re.compile(
+        rf"= (\(.*)?f32\[{S if program == 'decode' else C},16384\]")
+    wide, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused and streams.search(line.split(" metadata=")[0]):
+            wide.append(line.strip()[:120])
+    assert not wide, f"{program} writes the streams in f32: {wide}"
 
 
 def test_decode_program_keeps_the_view_where_pages_are_not_whole_tiles(v5e):

@@ -45,6 +45,7 @@ from deepspeed_tpu.ops.transformer.mhc_mix import (fold_phi, mhc_post_mix,
                                                    mhc_pre_mix)
 from deepspeed_tpu.ops.transformer.paged_attention import \
     paged_decode_attention
+from deepspeed_tpu.ops.transformer.rect_attention import rect_flash_attention
 from deepspeed_tpu.serving import (CompilationCounter, FleetRouter,
                                    InferenceEngine)
 from deepspeed_tpu.serving.engine import _pool_view
@@ -212,6 +213,48 @@ def _paged_decode(rng, lanes, n_head, head_dim, block, pages, layers=2):
     return {"out": round(err, 5)}
 
 
+def _window_prefill(rng, H, Hkv, C, S, D, Ds, window, ahead):
+    """A sliding layer's chunk (``rect_flash_attention`` with a window: the
+    band kernel), bf16 as served, against the definition in float64 on the
+    host: H query heads over Hkv key heads, C queries whose first stands
+    ``ahead`` rows into a view of S rows, Ds further columns of scores whose
+    keys all heads share (0: none), and a NaN in every row of the view
+    that no query sees."""
+    f64 = np.float64
+    bf16 = lambda *shape: jnp.asarray(                       # noqa: E731
+        rng.standard_normal(shape), jnp.bfloat16)
+    q, k, v = bf16(H, C, D) * D ** -0.5, bf16(Hkv, S, D), bf16(Hkv, S, D)
+    shared = (bf16(H, C, Ds) * D ** -0.5, bf16(S, Ds)) if Ds else ()
+    q_start = 6144
+    k_start = q_start - ahead
+    kpos, qpos = k_start + np.arange(S), q_start + np.arange(C)
+    dead = ((kpos > qpos[-1]) | (kpos <= qpos[0] - window))[:, None]
+    k, v = (jnp.where(dead, jnp.nan, t) for t in (k, v))
+    if Ds:
+        shared = (shared[0], jnp.where(dead, jnp.nan, shared[1]))
+    out = np.asarray(rect_flash_attention(
+        q, k, v, jnp.int32(q_start), *shared, k_start=jnp.int32(k_start),
+        window=window), f64)
+    check(np.isfinite(out).all(), "window prefill: non-finite result")
+    seen = (kpos[None] <= qpos[:, None]) \
+        & (kpos[None] > qpos[:, None] - window)
+    G = H // Hkv
+    live = lambda t: np.where(dead, 0.0, np.asarray(t, f64))  # noqa: E731
+    worst = 0.0
+    for h in range(Hkv):
+        heads = slice(h * G, (h + 1) * G)
+        s = np.asarray(q[heads], f64) @ live(k[h]).T
+        if Ds:
+            s += np.asarray(shared[0][heads], f64) @ live(shared[1]).T
+        s = np.where(seen[None], s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ live(v[h])
+        worst = max(worst, _rel_err(out[heads], want))
+    check(worst <= KERNEL_TOL,
+          f"window prefill: the band leaves float64's by {worst}")
+    return {"out": round(worst, 5)}
+
+
 def _mhc_mixes(rng, rows, n, width, iters=20, eps=1e-5):
     """The two kernels of the residual mixes (bf16 streams and weights, as
     served) against the equations in float64 on the host: the gates and
@@ -269,9 +312,17 @@ def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
                   bias_shape=(8, 16, 512, 64),
                   sparse_shape=(2, 12, 4096, 64), sparse_block=64,
                   paged_shape=(28, 16, 64, 16, 64),
-                  mhc_shape=(2048, 4, 4096)):
+                  mhc_shape=(2048, 4, 4096),
+                  window_shapes=((80, 16, 2048, 2240, 128, 64, 128, 128),
+                                 (32, 4, 2048, 3136, 128, 0, 1024, 1024))):
     rng = np.random.default_rng(seed)
     seen = {}
+
+    # a sliding layer's chunk, Motif's and Mellum's: query and key heads,
+    # queries, rows of the view, head size, shared columns, window, and how
+    # far into the view the first query stands
+    for shape in window_shapes:
+        seen[f"window_prefill_w{shape[6]}"] = _window_prefill(rng, *shape)
 
     # Motif's residual mixes: rows, streams, width of a stream (a chunk of
     # the agent-turns cell)
@@ -363,7 +414,8 @@ def phase_kernels(seed=0, *, causal_shape=(8, 16, 1024, 64),
                        "block_sparse": list(sparse_shape)
                        + [f"block {sparse_block}"],
                        "paged_decode_attn": list(paged_shape),
-                       "mhc_mixes": list(mhc_shape)}}
+                       "mhc_mixes": list(mhc_shape),
+                       "window_prefill": [list(s) for s in window_shapes]}}
 
 
 # ---------------------------------------------------------------------------

@@ -32,12 +32,24 @@ Grouped-query heads: ``k`` and ``v`` may carry fewer heads than ``q``
 the key blocks' index map, and no key is repeated in memory.  ``k_start``
 (a traced scalar beside ``q_start``) says that key j stands at absolute
 position ``k_start + j``: the view of a cache group that keeps a window
-begins at the oldest page the lane still holds.  ``window`` (static) bounds
-what a query sees from below: the keys at positions ``p - window + 1 .. p``
-of a query at ``p``; key blocks wholly below the first query of a query
-block's window are neither fetched nor computed, the edge block is masked
-and its value rows below that window zeroed.  Without the three the traced
-kernel is what it was (the latent models' instance, their cells' yardstick).
+begins at the oldest page the lane still holds.  Without the two the
+traced kernel is what it was (the latent models' instance, their cells'
+yardstick).
+
+``window`` (static) bounds what a query sees from below: the keys at
+positions ``p - window + 1 .. p`` of a query at ``p``.  Such a call walks a
+BAND, not the rectangle, in a body of its own (``_band_kernel``): a block
+of ``block_q`` queries sees ``block_q + window - 1`` keys, so one grid step
+= (key head, query block) takes the ``G`` query heads of the key head as
+``G * block_q`` rows against that band of the head's view, in one pass:
+scores, mask, softmax and the value product, no key grid, no running
+maximum, no rescale.  The view of a window group is short by construction
+(the window, a chunk and a page), so a head's view stands whole in VMEM,
+fetched once a head, and each step cuts its band from it at a whole tile of
+rows; what the band holds beside the block's windows is masked, and its
+value rows that no query of the block sees are zeroed as above.  The
+rectangle's tile held eight windows of 128 (sixteen times the pairs that
+count, through the online softmax): ``_blocks`` says what each costs.
 
 ``mla_decode_attention`` is the decode side of latent (MLA) pages: one
 query a lane against the gathered latent rows, with the up-projections
@@ -56,6 +68,9 @@ from deepspeed_tpu.ops.transformer.flash_attention import \
 KERNEL_NAME = "mla_prefill_attn"
 NEG_INF = -1e30
 _MIN_ROWS = 128
+_LANES = 128
+_BAND_ALIGN = 16     # rows of a packed bf16 tile: where a band may begin
+_VIEW_BYTES = 8 << 20   # of a window's view in VMEM, beside a step's scores
 
 
 def _dot(a, b, dims):
@@ -64,7 +79,7 @@ def _dot(a, b, dims):
 
 
 def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
-            shared, offset=False, window=None):
+            shared, offset=False):
     if shared:
         ks_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -89,10 +104,6 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
     else:
         q_last = jnp.minimum(q_start_ref[0] + queries, keys) - 1
         k_lo = ki * block_k                   # position of the first key
-    if window is not None:
-        # the lowest key the block's first and last query see
-        lo_first = jnp.minimum(q_lo, q_last) - (window - 1)
-        lo_last = jnp.minimum(q_lo + block_q - 1, q_last) - (window - 1)
 
     def accumulate(masked):
         k, v = k_ref[0], v_ref[0]
@@ -106,22 +117,10 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
             # must not reach the value product
             sees = jnp.minimum(q_lo + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0), q_last) - k_lo
-            if window is None:
-                s = jnp.where(jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1) <= sees, s, NEG_INF)
-                row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-                v = jnp.where(row <= q_last - k_lo, v, 0)
-            else:
-                # and none below its window; a row that sees nothing of
-                # this block is wiped by the rescale of the block in which
-                # it first sees a key (its own position, at the latest)
-                col = jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where((col <= sees) & (col > sees - window), s,
-                              NEG_INF)
-                row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-                v = jnp.where((row <= q_last - k_lo)
-                              & (row >= lo_first - k_lo), v, 0)
+            s = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1) <= sees, s, NEG_INF)
+            row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+            v = jnp.where(row <= q_last - k_lo, v, 0)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -135,20 +134,10 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
     # common one of a long context) needs no mask; one that no query of
     # the block may see is not computed
     whole = k_lo + block_k - 1 <= jnp.minimum(q_lo, q_last)
-    if window is None:
-        pl.when(whole)(functools.partial(accumulate, False))
-        pl.when(jnp.logical_not(whole) & (
-            k_lo <= jnp.minimum(q_lo + block_q - 1, q_last)))(
-                functools.partial(accumulate, True))
-    else:
-        # nor is a block wholly below the first query's window computed,
-        # and only one at or above the last query's needs no mask
-        whole = whole & (k_lo >= lo_last)
-        pl.when(whole)(functools.partial(accumulate, False))
-        pl.when(jnp.logical_not(whole)
-                & (k_lo <= jnp.minimum(q_lo + block_q - 1, q_last))
-                & (k_lo + block_k - 1 >= lo_first))(
-                    functools.partial(accumulate, True))
+    pl.when(whole)(functools.partial(accumulate, False))
+    pl.when(jnp.logical_not(whole) & (
+        k_lo <= jnp.minimum(q_lo + block_q - 1, q_last)))(
+            functools.partial(accumulate, True))
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
@@ -156,7 +145,53 @@ def _kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
         o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
 
 
-def _blocks(C, S, block_q=None, block_k=None):
+def _band_kernel(q_start_ref, q_ref, k_ref, v_ref, *refs, queries, keys,
+                 window, band, shared, offset):
+    """One grid step = (key head, query block): the G query heads of the
+    key head as G * block_q rows against the ``band`` rows of the head's
+    view (whole in VMEM) that hold the block's windows.  One pass: no key
+    grid, no running state."""
+    if shared:
+        ks_ref, o_ref = refs
+    else:
+        (o_ref,) = refs
+    G, block_q = q_ref.shape[1:3]
+    q_lo = q_start_ref[0] + pl.program_id(1) * block_q
+    k0 = q_start_ref[1] if offset else 0      # key j stands at k0 + j
+    # the last position anybody sees, as in the rectangle's body
+    q_last = jnp.minimum(q_start_ref[0] + queries, k0 + keys) - 1
+    # the lowest position the block's first query sees
+    lo_first = jnp.minimum(q_lo, q_last) - (window - 1)
+    # the band begins at a whole tile of rows at or below it, inside the view
+    at = pl.multiple_of(jnp.minimum(
+        jnp.maximum(lo_first - k0, 0) // _BAND_ALIGN * _BAND_ALIGN,
+        k_ref.shape[1] - band), _BAND_ALIGN)
+    k = k_ref[0, pl.ds(at, band), :]
+    v = v_ref[0, pl.ds(at, band), :]
+    if shared:      # [k | k_shared]: ONE product over D + Ds
+        k = jnp.concatenate([k, ks_ref[pl.ds(at, band), :]], axis=1)
+    s = _dot(q_ref[0].reshape(G * block_q, -1), k, ((1,), (1,)))
+    # one mask for the G heads: a query sees the keys up to its own
+    # position, none below its window and none past `q_last`
+    k_lo = k0 + at                            # position of the band's row 0
+    sees = jnp.minimum(q_lo + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0), q_last) - k_lo
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_q, band), 1)
+    s = jnp.where((col <= sees) & (col > sees - window),
+                  s.reshape(G, block_q, band), NEG_INF)
+    # the value rows no query of the block sees hold anything (a page given
+    # back, a row never written): 0 * NaN = NaN, so they must not reach
+    # the value product
+    row = jax.lax.broadcasted_iota(jnp.int32, (band, 1), 0)
+    v = jnp.where((row <= q_last - k_lo) & (row >= lo_first - k_lo), v, 0)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    # every query sees its own position: the sum is never zero
+    out = _dot(p.reshape(G * block_q, band).astype(v.dtype), v,
+               ((1,), (0,))).reshape(G, block_q, -1)
+    o_ref[0] = (out / jnp.sum(p, axis=-1, keepdims=True)).astype(o_ref.dtype)
+
+
+def _blocks(C, S, block_q=None, block_k=None, window=None, G=1):
     """(rows, block_q, block_k): the C queries padded to ``rows`` (whole
     tiles of 128) and walked ``block_q`` at a time, the keys ``block_k`` a
     grid step.  No block is cut to a divisor of S: the last may be ragged.
@@ -168,12 +203,44 @@ def _blocks(C, S, block_q=None, block_k=None):
     and their exponentials 2 MB in bf16, k, v and the shared keys 0.25 MB
     a block each (lanes padded to 128) twice over, q and the result 0.25
     MB twice over, the running state 1.5 MB: ~10 MB, under the chip's
-    default scoped limit of 16."""
+    default scoped limit of 16.
+
+    With a ``window``, ``block_k`` is the BAND: the rows of the view one
+    step takes for ``block_q`` queries of each of the ``G`` query heads of
+    a key head, ``block_q + window - 1`` and the up to 15 rows that a start
+    at a whole tile of rows adds, in whole lanes.  A step's cost beside its
+    pairs is paid per step and the band's share that no query of the block
+    sees grows with ``block_q``, so the blocks are small: 128 queries, and
+    halved while the step's scores (``G * block_q`` rows of a band) would
+    outgrow the rectangle's tile of 1024 x 1024.  Measured on the v5e, a
+    call alone with the concatenation of the query's two parts (0.2 ms),
+    bf16, C 2,048: 80 heads over 16 key heads (128 | 64 / 128), window 128,
+    view 2,240 rows: ``block_q`` 64 / 128 / 256 = bands of 256 / 384 / 512
+    = 0.67 / 0.61 / 0.65 ms (863 / 1,865 / 4,291 scheduled bundles a
+    step), the rectangle's (1024, 1024) walk 2.14 ms; in the chunk program
+    0.38 ms a call against 1.77.  32 heads over 4 (128 / 128), window
+    1,024, view 3,136 rows: ``block_q`` 64 / 128 = bands of 1,152 / 1,280
+    = 0.32 / 0.34 ms (256 does not fit VMEM), the rectangle's walk 0.64 ms
+    where the view begins 1,024 rows before the chunk and 0.89 where 1,087;
+    in the chunk program 0.30 ms a call against 0.50.
+    In VMEM at 80 over 16: the head's view 0.57 MB each of k, v and the
+    shared keys twice over, the scores 1 MB in f32 and their exponentials,
+    q and the result 0.5 MB twice over: ~7 MB."""
     rows = -(-C // _MIN_ROWS) * _MIN_ROWS
+    if window is not None:
+        bq = min(block_q or _MIN_ROWS, rows)
+        while rows % bq or (not block_q and bq > _BAND_ALIGN
+                            and G * bq * _band(bq, window) > 1024 * 1024):
+            bq //= 2
+        return rows, bq, _band(bq, window)
     bq = min(block_q or 1024, rows)
     while rows % bq:
         bq //= 2
     return rows, bq, min(block_k or 1024, S)
+
+
+def _band(bq, window):
+    return -(-(bq + window - 1 + _BAND_ALIGN - 1) // _LANES) * _LANES
 
 
 @functools.partial(jax.jit,
@@ -195,9 +262,10 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
     ``[q | q_shared] . [k | k_shared]``, one product, the shared keys put
     beside each head's in VMEM and never copied per head in HBM.  Returns
     (H, C, Dv) in q's dtype.  Rows of k, v and k_shared past the last
-    query's position may hold anything: blocks wholly past it are never
-    read, and in the last block read the scores past it are masked and the
-    value rows zeroed."""
+    query's position (and, under a ``window``, below the first query's
+    window) may hold anything: blocks wholly past it are never read, and
+    in the last block read the scores past it are masked and the value
+    rows zeroed."""
     H, C, D = q.shape
     Hkv, S, Dv = v.shape
     shared = q_shared is not None
@@ -207,31 +275,32 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
     offset = k_start is not None
     if interpret is None:
         interpret = _interpret_default()
-    rows, bq, bk = _blocks(C, S, block_q, block_k)
+    rows, bq, bk = _blocks(C, S, block_q, block_k, window, G)
     if shared:
         Ds = q_shared.shape[-1]
         assert q_shared.shape == (H, C, Ds) and k_shared.shape == (S, Ds)
         q = jnp.concatenate([q, q_shared], axis=-1)     # C rows: cheap
     if rows != C:
         q = jnp.pad(q, ((0, 0), (0, rows - C), (0, 0)))
+    starts = jnp.stack([jnp.asarray(q_start, jnp.int32),
+                        jnp.asarray(k_start, jnp.int32)]) if offset \
+        else jnp.asarray(q_start, jnp.int32).reshape(1)
+    if window is not None:
+        return _band_attention(starts, q, k, v, k_shared, C, bq, bk, window,
+                               interpret, name)[:, :C]
 
     def q_map(h, qi, ki, qs):
         return h, qi, 0
 
     def kv_map(h, qi, ki, qs):
         # a block that no query of block qi may see is not fetched: the
-        # index stays at the last one needed (and, under a window, at the
-        # first)
-        if not offset and window is None and G == 1:
+        # index stays at the last one needed
+        if not offset and G == 1:
             return h, jnp.minimum(
                 ki, (qs[0] + jnp.minimum((qi + 1) * bq, C) - 1) // bk), 0
         k0 = qs[1] if offset else 0
         last = (qs[0] + jnp.minimum((qi + 1) * bq, C) - 1 - k0) // bk
         at = jnp.minimum(ki, last)
-        if window is not None:
-            first = jnp.maximum(
-                (qs[0] + qi * bq - (window - 1) - k0) // bk, 0)
-            at = jnp.maximum(at, jnp.minimum(first, last))
         return h // G, at, 0
 
     in_specs = [pl.BlockSpec((1, bq, q.shape[-1]), q_map),
@@ -244,7 +313,7 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
         operands.append(k_shared)
     out = pl.pallas_call(
         functools.partial(_kernel, queries=C, keys=S, shared=shared,
-                          offset=offset, window=window),
+                          offset=offset),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(H, rows // bq, pl.cdiv(S, bk)),
@@ -258,10 +327,64 @@ def rect_flash_attention(q, k, v, q_start, q_shared=None, k_shared=None, *,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(jnp.stack([jnp.asarray(q_start, jnp.int32),
-                 jnp.asarray(k_start, jnp.int32)]) if offset
-      else jnp.asarray(q_start, jnp.int32).reshape(1), *operands)
+    )(starts, *operands)
     return out[:, :C]
+
+
+def _band_attention(starts, q, k, v, k_shared, C, bq, band, window,
+                    interpret, name):
+    """The call of a ``window``: q (H, rows, D [+ Ds]) padded to whole
+    blocks, ``starts`` the scalars of the rectangle's call.  A key head's
+    view stands whole in VMEM (its block index moves with the head alone,
+    so it is fetched once a head, the shared keys once a call) and each
+    step cuts its band from it."""
+    H, rows, Dq = q.shape
+    Hkv, S, Dv = v.shape
+    G = H // Hkv
+    shared = k_shared is not None
+    # the view in whole tiles of rows, and no shorter than one band
+    Sp = max(band, -(-S // _BAND_ALIGN) * _BAND_ALIGN)
+    held = 2 * Sp * q.dtype.itemsize * sum(
+        -(-t.shape[-1] // _LANES) * _LANES
+        for t in (k, v, k_shared) if t is not None)
+    assert held <= _VIEW_BYTES, \
+        f"a window's view stands whole in VMEM, twice over: {held} bytes " \
+        f"for {S} rows; a window group's view is the window, a chunk and " \
+        f"a page"
+    if Sp != S:
+        k, v = (jnp.pad(t, ((0, 0), (0, Sp - S), (0, 0))) for t in (k, v))
+        if shared:
+            k_shared = jnp.pad(k_shared, ((0, Sp - S), (0, 0)))
+
+    def q_map(h, qi, qs):
+        return h, 0, qi, 0
+
+    def view_map(h, qi, qs):
+        return h, 0, 0
+
+    in_specs = [pl.BlockSpec((1, G, bq, Dq), q_map),
+                pl.BlockSpec((1, Sp, k.shape[-1]), view_map),
+                pl.BlockSpec((1, Sp, Dv), view_map)]
+    operands = [q.reshape(Hkv, G, rows, Dq), k, v]
+    if shared:
+        in_specs.append(pl.BlockSpec(k_shared.shape, lambda h, qi, qs: (0, 0)))
+        operands.append(k_shared)
+    out = pl.pallas_call(
+        functools.partial(_band_kernel, queries=C, keys=S, window=window,
+                          band=band, shared=shared,
+                          offset=starts.shape[0] == 2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Hkv, rows // bq),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, G, bq, Dv), q_map)),
+        out_shape=jax.ShapeDtypeStruct((Hkv, G, rows, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name=name,
+    )(starts, *operands)
+    return out.reshape(H, rows, Dv)
 
 
 def mla_decode_attention(q_lat, q_rope, latent, n_keys, latent_rank,

@@ -33,8 +33,11 @@ def test_kernels_phase_interpret_mode():
     out = chip_smoke.phase_kernels(
         causal_shape=(1, 1, 256, 64), bias_shape=(2, 2, 128, 64),
         sparse_shape=(2, 2, 256, 64), paged_shape=(4, 2, 8, 4, 8),
-        mhc_shape=(40, 4, 32))
+        mhc_shape=(40, 4, 32),
+        window_shapes=((10, 2, 200, 384, 16, 8, 64, 70),
+                       (8, 1, 136, 256, 16, 0, 96, 0)))
     assert set(out["rel_err"]) == {
+        "window_prefill_w64", "window_prefill_w96",
         "mhc_mixes", "paged_decode_attn", "flash_causal", "flash_dropout",
         "flash_key_bias", "block_sparse", "block_sparse_key_bias"}
 

@@ -109,17 +109,40 @@ def test_paged_decode_window_copies_only_its_pages():
 # ---------------------------------------------------------------------------
 # rectangle (chunked prefill)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("G", [1, 8])
-@pytest.mark.parametrize("window", [None, 128])
-@pytest.mark.parametrize("k_start,q_start,C,S", [
+_SMALL = [
     (0, 0, 96, 96),             # the first chunk, smaller than a tile
     (0, 200, 100, 300),         # ragged last key block
     (64, 300, 128, 400),        # the view begins at the oldest page held
     (192, 500, 72, 384),        # ... and the chunk is padded past its end
-])
-def test_rect_groups_offset_and_window(G, window, k_start, q_start, C, S):
+]
+# (G, window, k_start, q_start, C, S, block_q, block_k)
+RECT_CASES = [(G, window, *shape, 64, 96) for shape in _SMALL
+              for window in (None, 128) for G in (1, 8)] + [
+    # the band at the cells' shapes, the kernel's own blocks: a chunk of
+    # 2,048 whose view begins at the oldest page the window still holds
+    (5, 128, 6016, 6144, 2048, 2240, None, None),
+    (5, 128, 5953, 6144, 2048, 2240, None, None),   # ... 191 rows before
+    (8, 128, 6016, 6144, 2048, 2240, None, None),
+    (8, 1024, 5120, 6144, 2048, 3136, None, None),
+    (5, 1024, 5057, 6144, 2048, 3136, None, None),
+    # the first chunk: the first queries' windows begin before position 0
+    (5, 128, 0, 0, 2048, 2240, None, None),
+    (8, 1024, 0, 64, 2048, 3136, None, None),
+    # the chunk runs past the view's end, and the view's rows are no
+    # whole tiles; a chunk that is no whole block of queries
+    (5, 128, 6016, 6144, 2048, 1500, None, None),
+    (8, 1024, 5120, 6144, 2000, 3136, 64, None),
+    # a view shorter than one band
+    (5, 128, 0, 0, 200, 208, None, None),
+]
+
+
+@pytest.mark.parametrize("G,window,k_start,q_start,C,S,block_q,block_k",
+                         RECT_CASES)
+def test_rect_groups_offset_and_window(G, window, k_start, q_start, C, S,
+                                       block_q, block_k):
     rng = np.random.default_rng(11 * G + (window or 0) + q_start)
-    Hkv = 2
+    Hkv = 2 if C * S < 2 ** 20 else 1
     H = G * Hkv
     q = rng.standard_normal((H, C, D)).astype(np.float32) * D ** -0.5
     k = rng.standard_normal((Hkv, S, D)).astype(np.float32)
@@ -137,8 +160,10 @@ def test_rect_groups_offset_and_window(G, window, k_start, q_start, C, S):
     out = np.asarray(rect_flash_attention(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), q_start,
         k_start=k_start if k_start or window else None, window=window,
-        block_q=64, block_k=96, interpret=True))
+        block_q=block_q, block_k=block_k, interpret=True))
     assert np.isfinite(out).all()
+    # a query past the view's end sees what the last one inside it sees
+    qpos = np.minimum(qpos, kpos.max())
     seen = kpos[None, :] <= qpos[:, None]
     if window is not None:
         seen &= kpos[None, :] > qpos[:, None] - window
@@ -149,8 +174,10 @@ def test_rect_groups_offset_and_window(G, window, k_start, q_start, C, S):
 
 
 def test_rect_skips_blocks_below_the_window():
-    """Key blocks wholly below the first query's window are neither fetched
-    nor computed: what they hold, NaN included, changes no bit."""
+    """What lies below the first query's window or past the last query
+    reaches nothing: what those rows hold, NaN and infinity included,
+    changes no bit, whether they lie outside every step's band or in the
+    slack a band's start at a whole tile takes in."""
     rng = np.random.default_rng(5)
     H, C, S, window = 4, 128, 1024, 128
     q = jnp.asarray(rng.standard_normal((H, C, D)), jnp.float32)
@@ -160,8 +187,9 @@ def test_rect_skips_blocks_below_the_window():
                 interpret=True)
     ref = np.asarray(rect_flash_attention(q, jnp.asarray(k), jnp.asarray(v),
                                           800, **args))
-    k[:, :640] = np.nan          # 800 - 127 = 673: blocks 0..4 lie below
-    v[:, :640] = np.inf
+    dead = (np.arange(S) < 800 - 127) | (np.arange(S) > 800 + C - 1)
+    k[:, dead] = np.nan
+    v[:, dead] = np.inf
     out = np.asarray(rect_flash_attention(q, jnp.asarray(k), jnp.asarray(v),
                                           800, **args))
     np.testing.assert_array_equal(out, ref)

@@ -456,25 +456,41 @@ def test_two_raw_rows_a_block_are_updated_in_the_donated_pool(cell, program,
         assert not made, f"decode gathers every lane's pages: {made}"
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill2048"])
-def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
-    """Mellum's sliding and full layers cache in TWO groups
-    (``kv_cache.cache_groups``), each with its own pool tensors: at the
-    published widths and the cell's sizes (32 slots x 518 pages of 64,
-    chunks of 2,048; the cell's two periods, one traced period)
-    both groups' scatters update the donated pools in place, no program
-    relays a pool tensor or holds a layer of one on its own, none copies a
-    stack of weights, and under the scan a kernel is ONE operation a KIND
-    of layer: the sliding layers are a loop of their own inside the
-    period."""
-    import re
+def _pallas_grids(jaxpr):
+    """``{kernel name: [(grid, the VMEM limit it asks for or None), a call
+    site each]}`` of every ``pallas_call`` of a traced program, the loops'
+    and the jits' bodies included."""
+    from jax._src import core
 
+    grids = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            asked = eqn.params["compiler_params"]["mosaic_tpu"]
+            grids.setdefault(eqn.params["name"], []).append(
+                (tuple(eqn.params["grid_mapping"].grid),
+                 asked.vmem_limit_bytes))
+        for inner in core.jaxprs_in_params(eqn.params):
+            for name, found in _pallas_grids(inner).items():
+                grids.setdefault(name, []).extend(found)
+    return grids
+
+
+_MELLUM_CELL = (32, 518, 64, 2048)     # slots, pages a lane, page, chunk
+_mellum_programs = {}
+
+
+def _mellum_program(program, v5e):
+    """Mellum's decode or chunk program at the published widths and the
+    cell's sizes (two periods of three sliding layers and a full one),
+    compiled for the described chip once a session: ``(compiled, its text,
+    pool shapes, the grids of its kernels, params)``."""
+    if program in _mellum_programs:
+        return _mellum_programs[program]
     from deepspeed_tpu.models.mellum import MellumConfig, MellumModel
     from deepspeed_tpu.serving import engine as serving
     from deepspeed_tpu.serving.kv_cache import pool_shapes
-    from tools.graftlint import hlo_contracts as hc
 
-    S, W, bs, C = 32, 518, 64, 2048
+    S, W, bs, C = _MELLUM_CELL
     cfg = MellumConfig(num_hidden_layers=8, pallas_interpret=False)
     model = MellumModel(cfg)
 
@@ -507,8 +523,28 @@ def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
                     (None, struct((1,), jnp.int32))),
                    struct((C,), jnp.int32), struct((), jnp.int32),
                    struct((1,), jnp.int32), struct((), jnp.int32)]
-    compiled = jitted.lower(params, *tensors, *streams).compile()
-    text = compiled.as_text()
+    traced = jitted.trace(params, *tensors, *streams)
+    compiled = traced.lower().compile()
+    _mellum_programs[program] = (compiled, compiled.as_text(), shapes,
+                                 _pallas_grids(traced.jaxpr), params)
+    return _mellum_programs[program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
+    """Mellum's sliding and full layers cache in TWO groups
+    (``kv_cache.cache_groups``), each with its own pool tensors: at the
+    published widths and the cell's sizes (32 slots x 518 pages of 64,
+    chunks of 2,048; the cell's two periods, one traced period)
+    both groups' scatters update the donated pools in place, no program
+    relays a pool tensor or holds a layer of one on its own, none copies a
+    stack of weights, and under the scan a kernel is ONE operation a KIND
+    of layer: the sliding layers are a loop of their own inside the
+    period."""
+    from tools.graftlint import hlo_contracts as hc
+
+    S, W, bs, _ = _MELLUM_CELL
+    compiled, text, shapes, _, params = _mellum_program(program, v5e)
     for pair in shapes:
         dims = ",".join(str(d) for d in pair[0])
         relays = [line.strip()[:120] for line in text.splitlines()
@@ -528,15 +564,13 @@ def test_two_cache_groups_are_updated_in_the_donated_pools(program, v5e):
                      for dims in stacks)]
     assert not copied, f"{program} copies a stack of weights: {copied}"
     # one operation a kernel and KIND of layer, under its stable name
-    calls = [re.match(r"\s*%(\S+?)(?:\.\d+)? = ", line).group(1)
-             for line in text.splitlines() if "tpu_custom_call" in line]
     kind = "decode" if program == "decode" else "prefill"
     attn = "gqa_paged_decode_attn" if program == "decode" \
         else "gqa_prefill_attn"
-    assert sorted(calls) == sorted(
+    assert _custom_calls(text) == sorted(
         [f"{attn}_full", f"{attn}_window"]
         + [f"moe_grouped_matmul_{kind}_{call}" for call in ("up", "down")]
-        * 2), calls
+        * 2)
     if program == "decode":
         # both groups' pages are read where they lie: no view of every
         # lane's pages is gathered (2.2 GB a full layer)
@@ -553,7 +587,8 @@ _motif_programs = {}
 def _motif_program(program, v5e):
     """Motif's decode or chunk program at the published widths and the
     cell's sizes (one dense and four routed layers), compiled for the
-    described chip once a session: ``(compiled, its text, pool shapes)``."""
+    described chip once a session: ``(compiled, its text, pool shapes, the
+    grids of its kernels)``."""
     if program in _motif_programs:
         return _motif_programs[program]
     from deepspeed_tpu.models.motif import MotifConfig, MotifModel
@@ -594,8 +629,10 @@ def _motif_program(program, v5e):
                     (None, struct((1,), jnp.int32))),
                    struct((C,), jnp.int32), struct((), jnp.int32),
                    struct((1,), jnp.int32), struct((), jnp.int32)]
-    compiled = jitted.lower(params, *tensors, *streams).compile()
-    _motif_programs[program] = compiled, compiled.as_text(), shapes
+    traced = jitted.trace(params, *tensors, *streams)
+    compiled = traced.lower().compile()
+    _motif_programs[program] = (compiled, compiled.as_text(), shapes,
+                                _pallas_grids(traced.jaxpr))
     return _motif_programs[program]
 
 
@@ -624,7 +661,7 @@ def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
     from tools.graftlint import hlo_contracts as hc
 
     S, W, bs, _ = _MOTIF_CELL
-    compiled, text, shapes = _motif_program(program, v5e)
+    compiled, text, shapes, _ = _motif_program(program, v5e)
     for shape in shapes:
         dims = ",".join(str(d) for d in shape)
         relays = [line.strip()[:120] for line in text.splitlines()
@@ -649,6 +686,27 @@ def test_raw_rows_in_a_window_group_are_updated_in_the_donated_pools(
         assert not made, f"decode gathers every lane's pages: {made}"
 
 
+@pytest.mark.parametrize("model", ["motif", "mellum"])
+def test_a_window_prefill_call_walks_a_band(model, v5e):
+    """The chunk program of a model with sliding layers, at the cell's
+    sizes (a chunk of 2,048): a window's rectangle call is ONE grid step a
+    (key head, query block), no key dimension, no running state, under the
+    name its roofline reader finds it by; the full layer's keeps its key
+    grid; and both fit the chip's default scoped VMEM of 16 MB (neither
+    asks for more, and the compile for the described chip passed)."""
+    program, attn, key_heads, block_q, sites = {
+        "motif": (_motif_program, "gdla_prefill_attn", 16, 128, 2),
+        "mellum": (_mellum_program, "gqa_prefill_attn", 4, 64, 1)}[model]
+    _, text, _, grids = program("prefill2048", v5e)[:4]
+    calls = _custom_calls(text)
+    assert calls.count(f"{attn}_window") == sites \
+        and calls.count(f"{attn}_full") == 1, calls
+    assert grids[f"{attn}_window"] == [((key_heads, 2048 // block_q), None)] \
+        * sites
+    ((full, asked),) = grids[f"{attn}_full"]
+    assert len(full) == 3 and full[2] > 1 and asked is None, full
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill2048"])
 def test_the_residual_mixes_are_two_kernels_and_no_f32_copy_of_the_streams(
         program, v5e):
@@ -661,7 +719,7 @@ def test_the_residual_mixes_are_two_kernels_and_no_f32_copy_of_the_streams(
     import re
 
     S, _, _, C = _MOTIF_CELL
-    _, text, _ = _motif_program(program, v5e)
+    _, text, _, _ = _motif_program(program, v5e)
     mixes = [call for call in _custom_calls(text) if call.startswith("mhc_")]
     # the last sublayer of a chunk that is not a prompt's last: its error
     # is counted, its feed-forward and the streams it would leave are not

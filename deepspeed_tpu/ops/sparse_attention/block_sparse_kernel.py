@@ -29,14 +29,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from deepspeed_tpu.ops.transformer.flash_attention import (
+    NEG_INF, _interpret_default)
+
 # scalar memory per TensorCore on TPU v4 and later (jax's own
 # pallas/mosaic/tpu_info.py table); the scalar-prefetched LUT lives there
 SMEM_BYTES = 1 << 20
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _check_lut_fits_smem(which, block, *luts):

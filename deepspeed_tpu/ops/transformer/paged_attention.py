@@ -1,44 +1,50 @@
-"""Decode attention over the serving engine's paged KV pool, read in place.
+"""Decode attention over the serving engine's paged pool, read in place.
 
-One query a lane attends the keys and values of ITS pages where they lie in
-the pool (``kv_cache.pool_shapes``: ``(L, NB, bs, H*D)``, one token's H
-heads of D side by side in a row).  The pool stays in HBM; the kernel copies
-to VMEM only the pages a lane has filled (``ceil(length / bs)`` of its page
+ONE WALK (:func:`_walk`, one grid step a lane).  One query a lane attends
+the cached rows of ITS pages where they lie in the pool ``(L, NB, bs, row)``
+(``kv_cache.pool_shapes``).  The pool stays in HBM; the kernel copies to
+VMEM only the pages a lane has filled (``ceil(length / bs)`` of its page
 table, none for an idle lane), ``pages_per_step`` at a time into one of two
-buffers while it computes on the other, and starts the next lane's first
-pages under the current lane's last.  A page is one contiguous
-``(bs, H*D)`` tile, so one copy brings every head.
-
-Rows are never relaid by head.  The per-head dot products come off the MXU
-from a block-diagonal query: ``Qbd (H, H*D)`` holds ``q_h`` in columns
-``h*D .. h*D + D - 1`` of row ``h`` and zeros elsewhere, so
-``Qbd . K^T (H, S)`` is every head's scores over S cached rows and
-``P (H, S) . V (S, H*D)`` holds head h's output in the same columns of row
-``h``: H times the needed operations, still far under the time the bytes
-take.  Scores and the running max and sum are f32 (online softmax), the
-probabilities meet the values in the values' dtype with f32 accumulation.
+buffers while it computes on the other, and starts the next live lane's
+first pages under the current lane's last.  A page is one contiguous
+``(bs, row)`` tile, so one copy brings every row whole.  Scores and the
+running max and sum are f32 (online softmax), the probabilities meet the
+values in the values' dtype with f32 accumulation.
 
 Isolation: a row at or past a lane's length (the stale tail of its last
 page, rows of the buffer no copy filled) scores -1e30 whatever it holds and
 has its VALUES zeroed before the product, so NaN or inf there changes no
 output (``0 * NaN`` would).
 
-Grouped-query heads (``n_head`` a multiple of the heads a row holds): the
-``G`` query heads that share key head ``j`` are ``G`` rows of the
-block-diagonal query with ``q_h`` in the columns of head ``j = h // G``
-(``(32, 512)`` against rows of 512 for 32 query heads over 4 key heads of
-128; made outside the kernel, 32 KB a lane), so no key is repeated in memory
-or in VMEM, and the scale is the head's ``D^-0.5``.  A window (``starts``):
-a lane sees rows ``start .. length - 1`` only, copies only the pages that
-hold them, masks the rows of its first page below ``start`` and zeroes their
-values.  Without either the traced kernel is what it was (GPT-2's instance,
-its cells' yardstick).
+A window (``starts``), the walk's one static choice: a lane sees rows
+``start .. length - 1`` only, copies only the pages that hold them, masks
+the rows of its first page below ``start`` and zeroes their values.  Without
+it the traced kernel is what it was (GPT-2's instance, its cells' yardstick).
 
-``paged_latent_decode_attention`` is the same walk over pages of raw latent
-(MLA) rows, ``(L, NB, bs, stored)``: every head reads the SAME row, which is
-key and value at once, so there is one pool, one buffer a step, and the
-query arrives whole (``[q . W_uk | q_rope | zeros]`` a head), no
-block-diagonal; ``starts`` is the same window over it.
+THREE VARIATIONS of what a cached row is, each a query for the score product
+and a cut of the accumulator, fixed when the kernel is traced:
+
+- Keys and values with heads (``paged_decode_attention``; GPT-2): two pools
+  of rows of ``H*D``, one token's H heads of D side by side, never relaid by
+  head.  The per-head dot products come off the MXU from a block-diagonal
+  query, made in the kernel from the one row it is given: ``Qbd (H, H*D)``
+  holds ``q_h`` in columns ``h*D .. h*D + D - 1`` of row ``h`` and zeros
+  elsewhere, so ``Qbd . K^T (H, S)`` is every head's scores over S cached
+  rows and ``P (H, S) . V (S, H*D)`` holds head h's output in the same
+  columns of row ``h`` (the cut sums these blocks into one row): H times
+  the needed operations, still far under the time the bytes take.
+- Grouped-query heads (the same entry point, ``n_head`` a multiple of the
+  heads a row holds; Mellum): the ``G`` query heads that share key head
+  ``j = h // G`` are ``G`` rows of the block-diagonal query with ``q_h`` in
+  head ``j``'s columns (``(32, 512)`` against rows of 512 for 32 query heads
+  over 4 key heads of 128; made OUTSIDE the kernel, 32 KB a lane), so no key
+  is repeated in memory or in VMEM, the scale is the head's ``D^-0.5``, and
+  the cut is ``(H, D)``: the one block of each row that is not zero.
+- Raw latent (MLA) rows (``paged_latent_decode_attention``; ``mistral4``,
+  LongCat, Motif): ONE pool ``(L, NB, bs, stored)`` whose row every head
+  reads, key and value at once, so there is one buffer a step, the query
+  arrives whole (``[q . W_uk | q_rope | zeros]`` a head, scale folded in)
+  and the cut is the accumulator's leading ``rank`` columns.
 """
 import functools
 
@@ -47,12 +53,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.transformer.flash_attention import \
-    _interpret_default
+from deepspeed_tpu.ops.transformer.flash_attention import (
+    NEG_INF, _interpret_default)
 
 KERNEL_NAME = "paged_decode_attn"
 LATENT_KERNEL_NAME = "paged_latent_decode_attn"
-NEG_INF = -1e30
 _LANES = 128
 _STEP_BYTES = 512 * 1024
 _LATENT_STEP_BYTES = 1024 * 1024
@@ -81,19 +86,62 @@ def latent_pages_per_step(pool_shape, itemsize, table_width):
                       _LATENT_STEP_BYTES // (bs * stored * itemsize)))
 
 
-def _kernel(layer_ref, tables_ref, lengths_ref, next_ref, *refs,
-            n_head, pages, table_width, scale, grouped=False,
-            windowed=False):
+def _diagonal_query(q_ref, o_ref, *, n_head, D, grouped):
+    """Rows of heads of ``D`` side by side: the block-diagonal query
+    ``(H, HD)``, and the cut of the accumulator ``(H, HD)``, where head h's
+    output is the diagonal block of row h."""
+    HD = q_ref.shape[-1]
+    head = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 1)
+    if grouped:
+        # arrives block-diagonal: q_h in key head h // G's columns of row h
+        group = n_head // (HD // D)
+        own = jnp.logical_and(col >= head // group * D,
+                              col < (head // group + 1) * D)
+        q_bd = q_ref[0]
+    else:
+        own = jnp.logical_and(col >= head * D, col < (head + 1) * D)
+        # (the select runs in 32 bits: the mask has that layout)
+        q_bd = jnp.where(own, jnp.broadcast_to(
+            q_ref[0].astype(jnp.float32), (n_head, HD)), 0.0) \
+            .astype(q_ref.dtype)
+
+    def cut(acc_scr, l_scr):
+        out = jnp.where(own, acc_scr[:] / l_scr[:, 0:1], 0.0)
+        if grouped:     # (H, D): the one block of its row that is not zero
+            return sum(out[:, j * D:(j + 1) * D] for j in range(HD // D))
+        return jnp.sum(out, axis=0, keepdims=True)
+
+    return q_bd, cut
+
+
+def _whole_query(q_ref, o_ref):
+    """Latent rows: the query as it arrives ``(H, stored)``, and the cut of
+    the accumulator ``(H, stored)``, its leading ``rank`` columns."""
+    rank = o_ref.shape[-1]
+    return q_ref[0], lambda acc_scr, l_scr: acc_scr[:, :rank] / l_scr[:, 0:1]
+
+
+def _walk(layer_ref, tables_ref, lengths_ref, next_ref,    # prefetched
+          *refs, pages, scale, windowed, query):
+    """One lane's walk over its pages.  After the prefetched scalars: the
+    query's block, a pool a kind of cached row (keys and values, or latent
+    rows that are both), the output's block, a two-slot buffer a pool, the
+    copies' semaphores, the slot the lane starts in (carried from lane to
+    lane), the online softmax's ``m / l / acc``.  ``scale``: of the scores
+    (None: folded into the query); ``query``: one of the variations."""
     if windowed:        # prefetched too: the first row a lane sees
         starts_ref, *refs = refs
-    (q_ref, k_hbm, v_hbm, o_ref,
-     k_buf, v_buf, sems, slot_ref, m_scr, l_scr, acc_scr) = refs
+    q_ref, *pool_refs, sems, slot_ref, m_scr, l_scr, acc_scr = refs
+    n = len(pool_refs) // 2             # pools: in HBM, then a buffer each
+    hbms, o_ref, bufs = pool_refs[:n], pool_refs[n], pool_refs[n + 1:]
+    values = bufs[-1]                   # of latent rows, the keys too
     b = pl.program_id(0)
     n_lanes = pl.num_programs(0)
-    bs = k_buf.shape[1] // pages
+    bs = values.shape[1] // pages
     S = pages * bs                      # cached rows a step
-    HD = k_buf.shape[-1]                # a cached row: its heads side by side
-    D = o_ref.shape[-1] if grouped else HD // n_head
+    n_head, row_width = acc_scr.shape
+    table_width = tables_ref.shape[0] // lengths_ref.shape[0]
     layer = layer_ref[0]
     length = lengths_ref[b]
 
@@ -105,30 +153,23 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref, *refs,
         return c * pages
 
     def page_copies(lane, c, slot, act):
-        """Start or wait for the copies of step ``c`` of ``lane``: the
-        pages of that step the lane has filled, keys and values.  (A
-        rolled loop: unrolled, sixteen pages at three sites were most of
-        the time it takes to trace and lower the kernel.)"""
+        """``act`` ("start" or "wait" for) the copies of step ``c`` of
+        ``lane``: the pages of that step the lane has filled, from every
+        pool.  (A rolled loop: unrolled, sixteen pages at three sites were
+        most of the time it takes to trace and lower the kernel.)"""
         first = first_page(lane, c)
         filled = (lengths_ref[lane] + bs - 1) // bs - first
 
         def page(i, carry):
             src = tables_ref[lane * table_width + first + i]
             rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            for j, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                            (v_hbm, v_buf))):
-                act(pltpu.make_async_copy(hbm.at[layer, src],
-                                          buf.at[slot, rows],
-                                          sems.at[j, slot]))
+            for j, (hbm, buf) in enumerate(zip(hbms, bufs)):
+                sem = sems.at[j, slot] if n > 1 else sems.at[slot]
+                getattr(pltpu.make_async_copy(
+                    hbm.at[layer, src], buf.at[slot, rows], sem), act)()
             return carry
 
         jax.lax.fori_loop(0, jnp.clip(filled, 0, pages), page, 0)
-
-    def start(copy):
-        copy.start()
-
-    def wait(copy):
-        copy.wait()
 
     @pl.when(length == 0)
     def _idle():
@@ -146,25 +187,11 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref, *refs,
         @pl.when(b == next_ref[n_lanes])        # the first live lane
         def _first():
             slot_ref[0] = 0
-            page_copies(b, 0, 0, start)
+            page_copies(b, 0, 0, "start")
 
         slot0 = slot_ref[0]
         following = next_ref[b]                 # next live lane, or n_lanes
-        head = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (n_head, HD), 1)
-        if grouped:
-            # the query arrives block-diagonal: q_h in key head h // G's
-            # columns of row h
-            group = n_head // (HD // D)
-            own = jnp.logical_and(col >= head // group * D,
-                                  col < (head // group + 1) * D)
-            q_bd = q_ref[0]
-        else:
-            own = jnp.logical_and(col >= head * D, col < (head + 1) * D)
-            # (the select runs in 32 bits: the mask has that layout)
-            q_bd = jnp.where(own, jnp.broadcast_to(
-                q_ref[0].astype(jnp.float32), (n_head, HD)), 0.0) \
-                .astype(q_ref.dtype)
+        q, cut = query(q_ref, o_ref)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -175,68 +202,105 @@ def _kernel(layer_ref, tables_ref, lengths_ref, next_ref, *refs,
 
             @pl.when(jnp.logical_not(last))
             def _():
-                page_copies(b, c + 1, 1 - slot, start)
+                page_copies(b, c + 1, 1 - slot, "start")
 
             @pl.when(jnp.logical_and(last, following < n_lanes))
             def _():
-                page_copies(following, 0, 1 - slot, start)
+                page_copies(following, 0, 1 - slot, "start")
 
-            page_copies(b, c, slot, wait)
+            page_copies(b, c, slot, "wait")
+            # rows no query may see: past the length, below a window's start
             if windowed:
                 row0 = first_page(b, c) * bs    # the step's first row
-
-                # rows no query may see: past the length, below the start
-                @pl.when(jnp.logical_or(row0 + S > length, c == 0))
-                def _():
-                    row = row0 + jax.lax.broadcasted_iota(
-                        jnp.int32, (S, HD), 0)
-                    v_buf[slot] = jnp.where(
-                        jnp.logical_and(row < length, row >= first_row),
-                        v_buf[slot], jnp.zeros((), v_buf.dtype))
+                ragged = jnp.logical_or(row0 + S > length, c == 0)
             else:
-                @pl.when((c + 1) * S > length)      # rows no query may see
-                def _():
-                    row = c * S + jax.lax.broadcasted_iota(
-                        jnp.int32, (S, HD), 0)
-                    v_buf[slot] = jnp.where(row < length, v_buf[slot],
-                                            jnp.zeros((), v_buf.dtype))
+                ragged = (c + 1) * S > length
 
-            k, v = k_buf[slot], v_buf[slot]
+            def seen(shape, axis):      # the step's rows along ``axis``
+                at = (row0 if windowed else c * S) \
+                    + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+                if windowed:
+                    return jnp.logical_and(at < length, at >= first_row)
+                return at < length
+
+            @pl.when(ragged)
+            def _():
+                values[slot] = jnp.where(seen((S, row_width), 0), values[slot],
+                                         jnp.zeros((), values.dtype))
+
+            rows = [buf[slot] for buf in bufs]
+            k, v = rows[0], rows[-1]            # a latent row is both
             s = jax.lax.dot_general(
-                q_bd, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale      # (H, S)
-            if windowed:
-                pos = row0 + jax.lax.broadcasted_iota(
-                    jnp.int32, (n_head, S), 1)
-                s = jnp.where(jnp.logical_and(pos < length,
-                                              pos >= first_row), s, NEG_INF)
-            else:
-                pos = c * S + jax.lax.broadcasted_iota(
-                    jnp.int32, (n_head, S), 1)
-                s = jnp.where(pos < length, s, NEG_INF)
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)              # (H, S)
+            if scale is not None:
+                s = s * scale
+            s = jnp.where(seen((n_head, S), 1), s, NEG_INF)
             m_prev = m_scr[:, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
-            l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1,
-                                                    keepdims=True)
+            l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
             acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)              # (H, H*D)
+                preferred_element_type=jnp.float32)         # (H, row)
             m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
             l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
             return carry
 
         jax.lax.fori_loop(0, steps, step, 0)
         slot_ref[0] = (slot0 + steps) % 2
-        # head h's output is the diagonal block of row h
-        out = jnp.where(own, acc_scr[:] / l_scr[:, 0:1], 0.0)
-        if grouped:     # (H, D): the one block of its row that is not zero
-            o_ref[0] = sum(out[:, j * D:(j + 1) * D]
-                           for j in range(HD // D)).astype(o_ref.dtype)
-        else:
-            o_ref[0] = jnp.sum(out, axis=0, keepdims=True) \
-                .astype(o_ref.dtype)
+        o_ref[0] = cut(acc_scr, l_scr).astype(o_ref.dtype)
+
+
+def _prefetched(layer, tables, lengths, starts):
+    """The scalars the walk prefetches: the layer, the page tables flat, the
+    lengths, the next live lane after each (B: none; in [B] the first one)
+    and, under a window, each lane's first row clipped into its rows."""
+    B, = lengths.shape
+    lengths = lengths.astype(jnp.int32)
+    lane = jnp.arange(B, dtype=jnp.int32)
+    live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
+    following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
+                                 live_from[:1]])
+    prefetched = [jnp.asarray(layer, jnp.int32).reshape(1),
+                  tables.astype(jnp.int32).reshape(-1), lengths, following]
+    if starts is not None:
+        prefetched.append(jnp.clip(starts.astype(jnp.int32), 0,
+                                   jnp.maximum(lengths - 1, 0)))
+    return prefetched
+
+
+def _walk_call(prefetched, q, pools, out_block, *, n_head, pages, scale,
+               query, interpret, name):
+    """:func:`_walk` over ``pools`` (left in HBM), one grid step a lane: q
+    (B, ...) and the output (B, *out_block) go a lane at a time."""
+    B = q.shape[0]
+    _, _, bs, row = pools[0].shape
+
+    def a_lane(block):
+        return pl.BlockSpec((1, *block), lambda b, *_: (b, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_walk, pages=pages, scale=scale, query=query,
+                          windowed=len(prefetched) == 5),   # starts too
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetched), grid=(B,),
+            in_specs=[a_lane(q.shape[1:])]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=a_lane(out_block),
+            scratch_shapes=[pltpu.VMEM((2, pages * bs, row), pool.dtype)
+                            for pool in pools] + [
+                pltpu.SemaphoreType.DMA((2, 2) if len(pools) > 1 else (2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((n_head, _LANES), jnp.float32),
+                pltpu.VMEM((n_head, _LANES), jnp.float32),
+                pltpu.VMEM((n_head, row), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, *out_block), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name=name,
+    )(*prefetched, q, *pools)
 
 
 @functools.partial(jax.jit, static_argnames=("n_head", "pages_per_step",
@@ -263,7 +327,6 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
         and v_pool.shape == k_pool.shape, \
         (q.shape, n_head, k_pool.shape, v_pool.shape)
     grouped = row != HD
-    windowed = starts is not None
     assert tables.shape == (B, W) and lengths.shape == (B,)
     if interpret is None:
         interpret = _interpret_default()
@@ -275,17 +338,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     # with every page of 28 lanes filled
     pages = pages_per_step or max(1, min(
         W, _STEP_BYTES // (bs * row * k_pool.dtype.itemsize)))
-    lengths = lengths.astype(jnp.int32)
-    lane = jnp.arange(B, dtype=jnp.int32)
-    # the next live lane after each (B: none), and in [B] the first one
-    live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
-    following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
-                                 live_from[:1]])
-    prefetched = [jnp.asarray(layer, jnp.int32).reshape(1),
-                  tables.astype(jnp.int32).reshape(-1), lengths, following]
-    if windowed:
-        prefetched.append(jnp.clip(starts.astype(jnp.int32), 0,
-                                   jnp.maximum(lengths - 1, 0)))
+    prefetched = _prefetched(layer, tables, lengths, starts)
     if grouped:
         # block-diagonal here, not in the kernel: q_h tiled over the key
         # heads' columns and kept in those of head h // G
@@ -294,168 +347,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
             == (jnp.arange(n_head) // G)[:, None]
         q_in = jnp.where(mine, jnp.tile(q.reshape(B, n_head, D),
                                         (1, 1, row // D)), 0)
-        q_spec = pl.BlockSpec((1, n_head, row), lambda b, *_: (b, 0, 0))
-        out_spec = pl.BlockSpec((1, n_head, D), lambda b, *_: (b, 0, 0))
-        out_shape = (B, n_head, D)
     else:
         q_in = q.reshape(B, 1, HD)
-        q_spec = out_spec = pl.BlockSpec((1, 1, HD), lambda b, *_: (b, 0, 0))
-        out_shape = (B, 1, HD)
-    kernel = functools.partial(
-        _kernel, n_head=n_head, pages=pages, table_width=W,
-        scale=D ** -0.5, grouped=grouped, windowed=windowed)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetched),
-            grid=(B,),
-            in_specs=[q_spec,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=out_spec,
-            scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, row), k_pool.dtype),
-                pltpu.VMEM((2, pages * bs, row), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((n_head, _LANES), jnp.float32),
-                pltpu.VMEM((n_head, _LANES), jnp.float32),
-                pltpu.VMEM((n_head, row), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name=name,
-    )(*prefetched, q_in, k_pool, v_pool)
-    return out.reshape(B, HD)
-
-
-def _latent_kernel(layer_ref, tables_ref, lengths_ref, next_ref,  # prefetched
-                   *refs, pages, table_width, windowed=False):
-    if windowed:        # prefetched too: the first row a lane sees
-        starts_ref, *refs = refs
-    q_ref, hbm, o_ref, buf, sems, slot_ref, m_scr, l_scr, acc_scr = refs
-    b = pl.program_id(0)
-    n_lanes = pl.num_programs(0)
-    bs = buf.shape[1] // pages
-    S = pages * bs                      # cached rows a step
-    H, stored = q_ref.shape[1:]
-    rank = o_ref.shape[-1]
-    layer = layer_ref[0]
-    length = lengths_ref[b]
-
-    def first_page(lane, c):
-        """The first page of step ``c`` of ``lane``: a windowed lane's
-        steps begin at the page that holds its first row."""
-        if windowed:
-            return starts_ref[lane] // bs + c * pages
-        return c * pages
-
-    def page_copies(lane, c, slot, act):
-        """Start or wait for the copies of step ``c`` of ``lane``: the
-        pages of that step the lane has filled."""
-        first = first_page(lane, c)
-        filled = (lengths_ref[lane] + bs - 1) // bs - first
-
-        def page(i, carry):
-            src = tables_ref[lane * table_width + first + i]
-            rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            act(pltpu.make_async_copy(hbm.at[layer, src],
-                                      buf.at[slot, rows], sems.at[slot]))
-            return carry
-
-        jax.lax.fori_loop(0, jnp.clip(filled, 0, pages), page, 0)
-
-    def start(copy):
-        copy.start()
-
-    def wait(copy):
-        copy.wait()
-
-    @pl.when(length == 0)
-    def _idle():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(length > 0)
-    def _attend():
-        if windowed:
-            first_row = starts_ref[b]
-            steps = ((length + bs - 1) // bs - first_row // bs
-                     + pages - 1) // pages
-        else:
-            steps = (length + S - 1) // S
-
-        @pl.when(b == next_ref[n_lanes])        # the first live lane
-        def _first():
-            slot_ref[0] = 0
-            page_copies(b, 0, 0, start)
-
-        slot0 = slot_ref[0]
-        following = next_ref[b]                 # next live lane, or n_lanes
-        q = q_ref[0]                            # (H, stored)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-        def step(c, carry):
-            slot = (slot0 + c) % 2
-            last = c + 1 == steps
-
-            @pl.when(jnp.logical_not(last))
-            def _():
-                page_copies(b, c + 1, 1 - slot, start)
-
-            @pl.when(jnp.logical_and(last, following < n_lanes))
-            def _():
-                page_copies(following, 0, 1 - slot, start)
-
-            page_copies(b, c, slot, wait)
-            if windowed:
-                row0 = first_page(b, c) * bs    # the step's first row
-
-                # rows no query may see: past the length, below the start
-                @pl.when(jnp.logical_or(row0 + S > length, c == 0))
-                def _():
-                    row = row0 + jax.lax.broadcasted_iota(
-                        jnp.int32, (S, stored), 0)
-                    buf[slot] = jnp.where(
-                        jnp.logical_and(row < length, row >= first_row),
-                        buf[slot], jnp.zeros((), buf.dtype))
-            else:
-                @pl.when((c + 1) * S > length)      # rows no query may see
-                def _():
-                    row = c * S + jax.lax.broadcasted_iota(
-                        jnp.int32, (S, stored), 0)
-                    buf[slot] = jnp.where(row < length, buf[slot],
-                                          jnp.zeros((), buf.dtype))
-
-            rows = buf[slot]                    # keys and values at once
-            s = jax.lax.dot_general(
-                q, rows, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)              # (H, S)
-            if windowed:
-                pos = row0 + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
-                s = jnp.where(jnp.logical_and(pos < length,
-                                              pos >= first_row), s, NEG_INF)
-            else:
-                pos = c * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
-                s = jnp.where(pos < length, s, NEG_INF)
-            m_prev = m_scr[:, 0:1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1,
-                                                    keepdims=True)
-            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-                p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # (H, stored)
-            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-            return carry
-
-        jax.lax.fori_loop(0, steps, step, 0)
-        slot_ref[0] = (slot0 + steps) % 2
-        o_ref[0] = (acc_scr[:, :rank] / l_scr[:, 0:1]).astype(o_ref.dtype)
+    return _walk_call(
+        prefetched, q_in, (k_pool, v_pool),
+        (n_head, D) if grouped else (1, HD), n_head=n_head, pages=pages,
+        scale=D ** -0.5,
+        query=functools.partial(_diagonal_query, n_head=n_head, D=D,
+                                grouped=grouped),
+        interpret=interpret, name=name).reshape(B, HD)
 
 
 @functools.partial(jax.jit, static_argnames=("latent_rank", "pages_per_step",
@@ -493,40 +393,8 @@ def paged_latent_decode_attention(q_lat, q_rope, pool, layer, tables, lengths,
     # query is padded, not the rows cut
     q = jnp.concatenate([q_lat, q_rope], axis=-1)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, stored - q.shape[-1])))
-    pages = pages_per_step or latent_pages_per_step(
-        pool.shape, pool.dtype.itemsize, W)
-    lengths = lengths.astype(jnp.int32)
-    lane = jnp.arange(B, dtype=jnp.int32)
-    # the next live lane after each (B: none), and in [B] the first one
-    live_from = jax.lax.cummin(jnp.where(lengths > 0, lane, B), reverse=True)
-    following = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32),
-                                 live_from[:1]])
-    prefetched = [jnp.asarray(layer, jnp.int32).reshape(1),
-                  tables.astype(jnp.int32).reshape(-1), lengths, following]
-    windowed = starts is not None
-    if windowed:
-        prefetched.append(jnp.clip(starts.astype(jnp.int32), 0,
-                                   jnp.maximum(lengths - 1, 0)))
-    kernel = functools.partial(_latent_kernel, pages=pages, table_width=W,
-                               windowed=windowed)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetched),
-            grid=(B,),
-            in_specs=[pl.BlockSpec((1, H, stored), lambda b, *_: (b, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, H, R), lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, stored), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((H, _LANES), jnp.float32),
-                pltpu.VMEM((H, _LANES), jnp.float32),
-                pltpu.VMEM((H, stored), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, H, R), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name=name,
-    )(*prefetched, q, pool)
+    return _walk_call(
+        _prefetched(layer, tables, lengths, starts), q, (pool,), (H, R),
+        n_head=H, scale=None, query=_whole_query, interpret=interpret,
+        name=name, pages=pages_per_step or latent_pages_per_step(
+            pool.shape, pool.dtype.itemsize, W))

@@ -62,11 +62,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.transformer.flash_attention import \
-    _interpret_default
+from deepspeed_tpu.ops.transformer.flash_attention import (
+    NEG_INF, _interpret_default)
 
 KERNEL_NAME = "mla_prefill_attn"
-NEG_INF = -1e30
 _MIN_ROWS = 128
 _LANES = 128
 _BAND_ALIGN = 16     # rows of a packed bf16 tile: where a band may begin

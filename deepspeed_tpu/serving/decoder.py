@@ -111,14 +111,16 @@ does).  The decoder object has:
     whole held stage, each run of layers of one kind a scan of its own).
 ``final_norm(params, x)``, ``logits(params, xe (N, E)) -> (N, vocab) f32``
 
-What serves a model whose cache is not ``(keys, values)`` (by its stated
-kind), whose blocks
-report counters or which caches in several groups: the dense decode program
-and the chunked prefill
-programs.  The other variants (``speculative``, ``sparse_context``,
-``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off) know one
-group of one kind and refuse
-it by name with :class:`UnsupportedForModel`.
+The engine builds its programs in three factories (``engine.py``:
+``_make_decode_step``, ``_make_prefill_chunk``, ``_make_spec_verify``), all
+over the one pass ``_forward_groups``.  What serves a model whose cache is
+not ``(keys, values)`` (by its stated kind), whose blocks report counters or
+which caches in several groups: the first two, dense (the decode program
+and the chunked prefill programs).  The other variants (``speculative``,
+the third factory; ``sparse_context``, the first two with a sparse policy's
+widths; ``quantize_kv``, ``prefix_cache``, ``shards``, the fleet hand-off)
+know one group of one kind and refuse it by name with
+:class:`UnsupportedForModel`.
 """
 import functools
 

@@ -352,17 +352,6 @@ class _LayerCache:
                                           default=over_view)
 
 
-def _paged_forward(params, dec, pools, tables, pos, maxpos, blk, off, x,
-                   quantized, sparse=None, allowed=None, row_valid=None):
-    """:func:`_forward_groups` of a model of ONE cache group that keeps
-    everything: what every variant beside the dense programs calls.
-    Returns the final-normed x, the pools and the counters."""
-    x, (pools,), stats = _forward_groups(
-        params, dec, (_Group(pools, tables, blk, off),), pos, maxpos, x,
-        quantized, sparse, allowed, row_valid)
-    return x, pools, stats
-
-
 def _forward_groups(params, dec, groups, pos, maxpos, x, quantized,
                     sparse=None, allowed=None, row_valid=None):
     """Shared transformer pass of decode and chunked prefill: per layer
@@ -587,7 +576,7 @@ def _shard_wrap(core, name, mesh, axis_name, n_pool, in_streams,
 
 @functools.lru_cache(maxsize=64)
 def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
-                      mesh, axis_name):
+                      mesh, axis_name, K=None):
     """ONE fixed-shape decode program over every (local) slot lane.
 
     ``poison`` is a per-lane additive fault-injection stream (0.0 in
@@ -595,12 +584,22 @@ def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
     into one lane to model a numeric blow-up, and the per-lane
     ``finite`` output (non-finite logits detector) rides the same
     batched fetch as the sampled tokens — per-request quarantine costs
-    zero extra host syncs and zero recompiles."""
+    zero extra host syncs and zero recompiles.
+
+    ``K`` (a sparse policy's fixed gather width, serving/sparse_context.py;
+    None: dense): the program takes two more streams after ``tables``,
+    ``stables`` / ``sbase`` (S, K), and the KV gather reads that K-page
+    active table instead of the full W-page one.  K is STATIC, so this is
+    still one fixed-shape program inside the zero-recompile pin; the host
+    refreshes ``stables``/``sbase`` per step with the same
+    no-mutation-before-fetch discipline as ``_pos``/``_tok``.  The single
+    decode query needs no per-query ``allowed`` mask: its active row IS
+    exactly its own policy set (lut row of its query block)."""
     dec, specs = decoder_for(cfg), cache_groups(cfg)
 
     def run(params, *args):
         pools, n_pool = _split_groups(cfg, args, quantized)
-        tables, pos, tok, active, seeds, poison = args[n_pool:]
+        tables, *sparse, pos, tok, active, seeds, poison = args[n_pool:]
         # a model of several cache groups: a table and a base a group
         tables, bases = tables if len(specs) > 1 \
             else ((tables,), (None,))
@@ -617,16 +616,18 @@ def _make_decode_step(cfg, W, bs, quantized, temperature, top_k, top_p,
                                  spec.name))
         x, pools, stats = _forward_groups(
             params, dec, groups, pos, pos, x, quantized,
-            row_valid=active[:, None])
+            sparse=tuple(sparse) or None, row_valid=active[:, None])
         logits = dec.logits(params, x[:, 0])
         finite = jnp.isfinite(logits).all(axis=-1)
         nxt = _pick_next(logits, seeds, pos, temperature, top_k, top_p)
         nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
         return (*_held_groups(pools), *_stats_out(stats), nxt, finite)
 
-    return _shard_wrap(run, "decode_step", mesh, axis_name,
+    return _shard_wrap(run, "decode_step" if K is None
+                       else "sparse_decode_step", mesh, axis_name,
                        _n_pool_args(cfg, quantized),
-                       in_streams=(True,) * 6, n_out_streams=2)
+                       in_streams=(True,) * (6 if K is None else 8),
+                       n_out_streams=2)
 
 
 @functools.lru_cache(maxsize=64)
@@ -667,9 +668,10 @@ def _make_spec_verify(cfg, K, W, bs, quantized, mesh, axis_name):
             TRASH_BLOCK)
         off = posns % bs
         maxpos = pos + nvalid - 1                              # (S,)
-        x, pools, _ = _paged_forward(params, dec, pools, tables, posns,
-                                     maxpos, blk.reshape(-1),
-                                     off.reshape(-1), x, quantized)
+        x, (pools,), _ = _forward_groups(
+            params, dec, (_Group(pools, tables, blk.reshape(-1),
+                                 off.reshape(-1)),), posns, maxpos, x,
+            quantized)
         logits = dec.logits(params,
                             x.reshape(S * T, -1)).reshape(S, T, -1)
         finite = jnp.where(valid_q, jnp.isfinite(logits).all(-1),
@@ -685,17 +687,29 @@ def _make_spec_verify(cfg, K, W, bs, quantized, mesh, axis_name):
 
 @functools.lru_cache(maxsize=256)
 def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
-                        top_k, top_p, mesh, axis_name):
+                        top_k, top_p, mesh, axis_name, K=None, win=None,
+                        g=None):
     """One prefill chunk of (padded) length C for ONE sequence.  Under
     sharding every shard executes the chunk against its LOCAL pool with
     its own table row / n_valid — non-owner shards get n_valid == 0, so
     their writes all land in the trash block and their (finite) outputs
-    are ignored by the host."""
+    are ignored by the host.
+
+    ``K, win, g`` (a sparse policy, serving/sparse_context.py; None:
+    dense): the program takes two more streams after ``table_rows``,
+    ``stab_rows`` / ``sbase_rows`` (1, K), the gather row and each of its
+    pages' first position.  That row is the UNION of the chunk queries'
+    active sets (globals + one contiguous window run — fixed width K per
+    bucket, see ``SparseContext.prefill_K``), so an early query's gather
+    would include blocks below its OWN window; the trace-constant policy
+    layout masks those per (query, key-block) pair inside the jit.
+    Non-owner shards get all-sentinel sparse rows."""
     dec, specs = decoder_for(cfg), cache_groups(cfg)
 
     def run(params, *args):
         pools, n_pool = _split_groups(cfg, args, quantized)
-        table_rows, tokens, start, n_valids, seed = args[n_pool:]
+        table_rows, *sparse_rows, tokens, start, n_valids, seed = \
+            args[n_pool:]
         # a model of several cache groups: a table and a base a group
         table_rows, bases = table_rows if len(specs) > 1 \
             else ((table_rows,), (None,))
@@ -713,9 +727,19 @@ def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
             groups.append(_Group(held, row[None], blk, off, base,
                                  spec.window, spec.name))
         maxpos = (start + n_valid - 1)[None]             # (1,)
+        sparse = allowed = None
+        if K is not None:
+            srow, sbase = (rows[0] for rows in sparse_rows)
+            layout = jnp.asarray(_policy_layout(win, g, W) > 0)
+            qb = jnp.minimum(posns // bs, W - 1)               # (C,)
+            view_pos = sbase[:, None] + jnp.arange(bs)[None, :]
+            sblk = jnp.minimum(view_pos // bs, W - 1)          # (K, bs)
+            allowed = layout[qb[:, None, None], sblk[None]] \
+                .reshape(C, K * bs)[None]                      # (1, C, K*bs)
+            sparse = (srow[None], sbase[None])
         x, pools, stats = _forward_groups(
-            params, dec, groups, posns, maxpos, x, quantized,
-            row_valid=valid_i[None] if dec.stat_names else None)
+            params, dec, groups, posns, maxpos, x, quantized, sparse,
+            allowed, row_valid=valid_i[None] if dec.stat_names else None)
         out = (*_held_groups(pools), *_stats_out(stats))
         if not final:
             return out
@@ -727,99 +751,12 @@ def _make_prefill_chunk(cfg, C, W, bs, quantized, final, temperature,
                          temperature, top_k, top_p)
         return (*out, nxt, finite)
 
-    return _shard_wrap(run, f"prefill_chunk{C}" + ("_final" if final else ""),
-                       mesh, axis_name, _n_pool_args(cfg, quantized),
-                       in_streams=(True, False, False, True, False),
-                       n_out_streams=2 if final else 0)
-
-
-@functools.lru_cache(maxsize=64)
-def _make_sparse_decode_step(cfg, W, K, bs, quantized, temperature, top_k,
-                             top_p, mesh, axis_name):
-    """Sparse-policy decode: identical to :func:`_make_decode_step`
-    except the KV gather reads the K-page active table instead of the
-    full W-page table.  K is STATIC (the policy's fixed gather width),
-    so this is still one fixed-shape program inside the zero-recompile
-    pin; the host refreshes ``stables``/``sbase`` per step with the same
-    no-mutation-before-fetch discipline as ``_pos``/``_tok``.  The
-    single decode query needs no per-query ``allowed`` mask: its active
-    row IS exactly its own policy set (lut row of its query block)."""
-    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
-
-    def run(params, *args):
-        pools = _pools_of(args, n_rows, quantized)
-        tables, stables, sbase, pos, tok, active, seeds, poison = args[-8:]
-        S = tok.shape[0]
-        x = dec.embed(params, tok, pos)[:, None, :]              # (S, 1, E)
-        x = x + poison.astype(dec.dtype)[:, None, None]
-        blk = jnp.where(active, tables[jnp.arange(S), pos // bs],
-                        TRASH_BLOCK)
-        off = pos % bs
-        x, pools, _ = _paged_forward(params, dec, pools, tables, pos, pos,
-                                     blk, off, x, quantized,
-                                     sparse=(stables, sbase))
-        logits = dec.logits(params, x[:, 0])
-        finite = jnp.isfinite(logits).all(axis=-1)
-        nxt = _pick_next(logits, seeds, pos, temperature, top_k, top_p)
-        nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-        return (*_held(pools), nxt, finite)
-
-    return _shard_wrap(run, "sparse_decode_step", mesh, axis_name,
-                       _n_pool(n_rows, quantized),
-                       in_streams=(True,) * 8, n_out_streams=2)
-
-
-@functools.lru_cache(maxsize=256)
-def _make_sparse_prefill_chunk(cfg, C, W, K, bs, win, g, quantized, final,
-                               temperature, top_k, top_p, mesh, axis_name):
-    """Sparse-policy prefill chunk: the gather row is the UNION of the
-    chunk queries' active sets (globals + one contiguous window run —
-    fixed width K per bucket, see ``SparseContext.prefill_K``), so an
-    early query's gather would include blocks below its OWN window; the
-    trace-constant policy layout masks those per (query, key-block)
-    pair inside the jit.  Same shard semantics as the dense chunk:
-    non-owner shards get n_valid == 0 and all-sentinel sparse rows."""
-    dec, n_rows = decoder_for(cfg), len(cache_rows(cfg))
-
-    def run(params, *args):
-        pools = _pools_of(args, n_rows, quantized)
-        table_rows, stab_rows, sbase_rows, tokens, start, n_valids, seed = \
-            args[-7:]
-        row = table_rows[0]
-        srow = stab_rows[0]
-        sbase = sbase_rows[0]
-        n_valid = n_valids[0]
-        posns = start + jnp.arange(C)                      # (C,)
-        x = dec.embed(params, tokens, posns)[None]         # (1, C, E)
-        valid_i = jnp.arange(C) < n_valid
-        blk = jnp.where(valid_i, row[posns // bs], TRASH_BLOCK)
-        off = posns % bs
-        maxpos = (start + n_valid - 1)[None]             # (1,)
-        layout = jnp.asarray(_policy_layout(win, g, W) > 0)
-        qb = jnp.minimum(posns // bs, W - 1)               # (C,)
-        view_pos = sbase[:, None] + jnp.arange(bs)[None, :]
-        sblk = jnp.minimum(view_pos // bs, W - 1)          # (K, bs)
-        allow = layout[qb[:, None, None], sblk[None]] \
-            .reshape(C, K * bs)[None]                      # (1, C, K*bs)
-        x, pools, _ = _paged_forward(
-            params, dec, pools, row[None], posns, maxpos, blk, off, x,
-            quantized, sparse=(srow[None], sbase[None]), allowed=allow)
-        out = _held(pools)
-        if not final:
-            return out
-        xe = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                          keepdims=False)
-        logits = dec.logits(params, xe[None])
-        finite = jnp.isfinite(logits).all(axis=-1)       # (1,)
-        nxt = _pick_next(logits, seed[None], (start + n_valid - 1)[None],
-                         temperature, top_k, top_p)
-        return (*out, nxt, finite)
-
-    return _shard_wrap(run, f"sparse_prefill_chunk{C}"
-                       + ("_final" if final else ""), mesh, axis_name,
-                       _n_pool(n_rows, quantized),
-                       in_streams=(True, True, True, False, False, True,
-                                   False),
+    name = f"prefill_chunk{C}" + ("_final" if final else "")
+    streams = (True, False, False, True, False)
+    if K is not None:       # the two sparse rows follow the table's
+        name, streams = "sparse_" + name, (True, True, True) + streams[1:]
+    return _shard_wrap(run, name, mesh, axis_name,
+                       _n_pool_args(cfg, quantized), in_streams=streams,
                        n_out_streams=2 if final else 0)
 
 
@@ -984,13 +921,10 @@ class InferenceEngine:
                                     np.int32)
             self._sbase = np.full((S, self.sparse.K),
                                   int(self.sparse.sentinel), np.int32)
-            self._decode = _make_sparse_decode_step(
-                cfg, self.W, self.sparse.K, self.bs, self.pool.quantized,
-                self.temperature, self.top_k, self.top_p, mesh, axis_name)
-        else:
-            self._decode = _make_decode_step(
-                cfg, self.W, self.bs, self.pool.quantized,
-                self.temperature, self.top_k, self.top_p, mesh, axis_name)
+        self._decode = _make_decode_step(
+            cfg, self.W, self.bs, self.pool.quantized, self.temperature,
+            self.top_k, self.top_p, mesh, axis_name,
+            **({} if self.sparse is None else {"K": self.sparse.K}))
         # a program's one name is its jit's (_shard_wrap)
         self._decode_name = self._decode.__name__
 
@@ -1891,13 +1825,10 @@ class InferenceEngine:
         """Full argument tuple of the armed decode program (dense or
         sparse) — shared by dispatch, program registration, telemetry
         shape capture and :meth:`decode_hlo`."""
-        if self.sparse is not None:
-            return (self.params, *self.pool.tensors.arrays, self._tables,
-                    self._stables, self._sbase, self._pos, self._tok,
-                    self._active, self._seeds, self._poison)
         tables = self._tables if not self._gtables else (
             (self._tables, *self._gtables), (None, *self._gbases))
-        return (self.params, *self.pool.all_arrays, tables,
+        sparse = () if self.sparse is None else (self._stables, self._sbase)
+        return (self.params, *self.pool.all_arrays, tables, *sparse,
                 self._pos, self._tok, self._active, self._seeds,
                 self._poison)
 
@@ -2285,33 +2216,24 @@ class InferenceEngine:
         tok_pad = np.zeros(bucket, np.int32)
         tok_pad[:n] = toks[start:start + n]
         rows, nv = self._prefill_args(req, n)
+        policy, sparse_rows, kind = {}, (), "prefill"
         if self.sparse is not None:
             K_pf = self.sparse.prefill_K(bucket)
-            fn = _make_sparse_prefill_chunk(
-                self.cfg, bucket, self.W, K_pf, self.bs, self.sparse.win,
-                self.sparse.g, self.pool.quantized, final,
-                self.temperature, self.top_k, self.top_p, self.mesh,
-                self.axis_name)
+            policy = {"K": K_pf, "win": self.sparse.win, "g": self.sparse.g}
             srows = np.full((self.shards, K_pf), TRASH_BLOCK, np.int32)
             sbases = np.full((self.shards, K_pf),
                              int(self.sparse.sentinel), np.int32)
             srows[req.shard], sbases[req.shard] = \
                 self.sparse.prefill_active_row(rows[req.shard], start, n,
                                                bucket)
-            pf_args = (self.params, *self.pool.tensors.arrays, rows,
-                       srows, sbases, tok_pad, np.int32(start), nv,
-                       np.int32(req.seed))
-            group = "serving:sparse_prefill_final" if final \
-                else "serving:sparse_prefill"
-        else:
-            fn = _make_prefill_chunk(
-                self.cfg, bucket, self.W, self.bs, self.pool.quantized,
-                final, self.temperature, self.top_k, self.top_p,
-                self.mesh, self.axis_name)
-            pf_args = (self.params, *self.pool.all_arrays, rows,
-                       tok_pad, np.int32(start), nv, np.int32(req.seed))
-            group = "serving:prefill_final" if final \
-                else "serving:prefill"
+            sparse_rows, kind = (srows, sbases), "sparse_prefill"
+        fn = _make_prefill_chunk(
+            self.cfg, bucket, self.W, self.bs, self.pool.quantized, final,
+            self.temperature, self.top_k, self.top_p, self.mesh,
+            self.axis_name, **policy)
+        pf_args = (self.params, *self.pool.all_arrays, rows, *sparse_rows,
+                   tok_pad, np.int32(start), nv, np.int32(req.seed))
+        group = f"serving:{kind}_final" if final else f"serving:{kind}"
         pf_name = fn.__name__       # [sparse_]prefill_chunk<bucket>[_final]
         # bucketed prefill programs at the same schedule slot must post
         # identical collective sequences (uniform_group) — a divergence

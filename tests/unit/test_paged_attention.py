@@ -4,7 +4,7 @@ interpret mode on the CPU, against what every other platform runs: the shared
 
 - it IS that attention, to f32 rounding, at every length around a page's and
   a step's edge, through shuffled page tables and beside idle lanes;
-- the isolation promise of ``_paged_forward``: no row past a lane's length
+- the isolation promise of ``_forward_groups``: no row past a lane's length
   (its last page's tail, its unfilled pages, the trash block, a stranger's
   page) reaches its output, whatever that row holds;
 - lanes that share pages read the same rows;
@@ -390,6 +390,9 @@ def _latent_over_view_from(q_lat, q_rope, pool, tables, lengths, starts):
         ("toy", 6, [1, 5, 6, 7, 130, ROWS]),
         ("toy", 17, [3, 16, 17, 18, 255]),      # a window of several pages
         ("r128", 40, [1, 40, 41, 200, ROWS]),
+        # a window inside ONE page: the lane's first page is its last, rows
+        # masked and zeroed on both sides of it
+        ("r128", 8, [30, 32, 33]),
         ("m3", 128, [1, 128, 129, 200, 512]))
     for length in lengths])
 def test_latent_window_equals_mla_decode_attention_from_the_start(

@@ -59,10 +59,11 @@ def _chunk_logits(engine, tokens):
     @jax.jit
     def run(params, *arrays):
         x = dec.embed(params, jnp.asarray(tokens), posns)[None]
-        x, _, _ = serving._paged_forward(
-            params, dec, serving._pools_of(arrays, 2, quantized), row[None],
-            posns, jnp.asarray([C - 1]), row[posns // bs], posns % bs, x,
-            quantized)
+        x, _, _ = serving._forward_groups(
+            params, dec, (serving._Group(
+                serving._pools_of(arrays, 2, quantized), row[None],
+                row[posns // bs], posns % bs),), posns,
+            jnp.asarray([C - 1]), x, quantized)
         return dec.logits(params, x[0])
 
     return np.asarray(run(engine.params, *engine.pool.tensors.arrays))
